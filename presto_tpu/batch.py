@@ -506,7 +506,9 @@ def _concat_columns(cols: Sequence[Column],
 def concat_batches(batches: Sequence[Batch]) -> Batch:
     """Concatenate compacted batches (dictionary columns are re-coded into a
     shared dictionary — the DictionaryBlock 'compact' analogue)."""
-    batches = [b.compact().to_numpy() for b in batches if b.num_rows > 0]
+    # host first, then drop the padding: compact() on a device array is an
+    # eager slice, one XLA program per distinct (capacity, rows) pair
+    batches = [b.to_numpy().compact() for b in batches if b.num_rows > 0]
     if not batches:
         raise ValueError("concat of zero rows needs a schema; use empty_batch")
     first = batches[0]

@@ -112,9 +112,8 @@ class DynamicFilterOperator(Operator):
         """One jitted mask+compact program per (capacity, filter shape),
         shared GLOBALLY across queries: the bounds and IN-set tables are
         passed as arguments, never baked in as constants, so a new
-        query's dynamic-filter values reuse the compiled program (eager
-        per-batch dispatch and retraces dominate on remote-attached
-        devices)."""
+        query's dynamic-filter values reuse the compiled program (no
+        eager per-batch dispatch, no retrace)."""
         import jax
 
         cap = batch.capacity

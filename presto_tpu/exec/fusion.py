@@ -602,6 +602,7 @@ class FusedSegmentOperator(Operator):
         # host-coalescing path state
         self._acc: List[List[tuple]] = []          # per-flush batch parts
         self._acc_rows = 0
+        self._full_cap = 0     # bucket of a full flush, once one happened
         self._targets: Optional[List[Optional[Dictionary]]] = None
         self._col_types: Optional[List[T.Type]] = None
 
@@ -728,9 +729,28 @@ class FusedSegmentOperator(Operator):
                                self._targets[ci]))
         self._acc = []
         self._acc_rows = 0
-        self.ctx.memory.set_bytes(0)
         batch = Batch(tuple(cols), rows)
-        return batch.pad_rows(next_bucket(rows, self._min_capacity))
+        cap = next_bucket(rows, self._min_capacity)
+        if rows >= self._coalesce:
+            # flush EXACTLY coalesce_rows and carry the rest, and pad this
+            # operator's later (tail) flushes to the same bucket: one
+            # program shape per segment instead of one per overshoot /
+            # tail bucket.  Which bucket a tail lands in follows how
+            # splits and exchange pages were dealt to this driver, so a
+            # WARM TPC-H Q3 at SF1 met tail buckets (and compiled
+            # programs) its cold run had not seen (PR 25).
+            if rows > self._coalesce:
+                cut = self._coalesce
+                self._acc = [[(c.values[cut:],
+                               None if c.valid is None else c.valid[cut:])
+                              for c in batch.columns]]
+                self._acc_rows = rows - cut
+                batch = batch.head(cut)
+            self._full_cap = cap = next_bucket(self._coalesce,
+                                               self._min_capacity)
+        self.ctx.memory.set_bytes(
+            sum(v.nbytes for p in self._acc for v, _ in p))
+        return batch.pad_rows(max(cap, self._full_cap))
 
     # -- dispatch --------------------------------------------------------
     def _df_snapshot(self):

@@ -701,8 +701,8 @@ class LookupJoinOperator(Operator):
             probe_pairs, build_pairs, src.sorted_ids, src.perm, mins,
             strides, maxs, src.pages, n, bstats, s=s)
         # expansion joins already synced the exact total in phase 1; only
-        # semi/anti need to read the selected count (host round-trips are
-        # ~1s each on remote-attached devices)
+        # semi/anti need to read the selected count (every host read is
+        # a device sync)
         total = etotal if join_type not in ("semi", "anti") else int(count)
         cols = []
         probe_cols = [batch.columns[i] for i in range(batch.num_columns)]

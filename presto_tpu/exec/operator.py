@@ -23,9 +23,11 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from presto_tpu import types as T
 from presto_tpu.batch import Batch, Column, next_bucket
 from presto_tpu.exec.context import OperatorContext
+from presto_tpu.kernelcache import (
+    cache_get, cache_put, new_cache, timed_first_call,
+)
 
 
 class Operator:
@@ -121,107 +123,104 @@ def pad_batch(batch: Batch, min_capacity: int = 1024) -> Batch:
 def device_concat(batches: Sequence[Batch], min_capacity: int = 1024) -> Batch:
     """Concatenate batches into one padded device Batch.
 
-    Fast path: when every dictionary column shares one dictionary object
-    across batches (connector-interned dictionaries) and nothing is
-    nested, the concat runs as ONE cached jitted device program —
-    downloading every batch to the host first costs a device read per
-    column per batch, which dominates aggregation finish on
-    remote-attached TPUs.  Otherwise dictionary columns are re-coded
-    into a shared dictionary host-side (cheap: dictionary sizes << row
-    counts)."""
+    Device-resident inputs stay on the device: a single batch already at
+    its capacity bucket is returned as it is, anything else is appended
+    into the output bucket by ONE cached program per (output bucket,
+    input bucket) pair (`_append_kernel`).  A program over all inputs at
+    once would be keyed by the ordered tuple of their capacities, which
+    follows how batches happened to coalesce: on TPC-H Q3 at SF1 that
+    compiled a new program in warm runs (PR 25).  Host inputs (exchange
+    pages), nested columns and dictionaries that differ between inputs
+    take the host concat (dictionary columns are re-coded into a shared
+    dictionary there) and are staged once."""
     from presto_tpu.batch import concat_batches
 
     live = [b for b in batches if b.num_rows > 0]
     if not live:
         return None
-    if len(live) == 1:
-        return pad_batch(live[0].compact(), min_capacity)
-    fast = _device_concat_fast(live, min_capacity)
-    if fast is not None:
-        return fast
-    # host-side concat handles dictionary merging; arrays may be device or
-    # numpy — normalize host-side, then stage once.
-    merged = concat_batches([b.to_numpy() for b in live])
-    return pad_batch(merged, min_capacity)
+    out = _device_append(live, min_capacity)
+    if out is not None:
+        return out
+    return pad_batch(concat_batches(live), min_capacity)
 
 
-from presto_tpu.kernelcache import new_cache as _new_cache
-
-_CONCAT_PROGRAMS = _new_cache("device_concat")
+_APPEND_PROGRAMS = new_cache("device_concat")
 
 
-def _device_concat_fast(live: Sequence[Batch],
-                        min_capacity: int) -> Optional[Batch]:
-    import numpy as np
+def _device_append(live: Sequence[Batch],
+                   min_capacity: int) -> Optional[Batch]:
+    """The device half of device_concat; None when an input needs the
+    host path."""
+    import jax
+    import jax.numpy as jnp
 
-    from presto_tpu.batch import Batch as _B
-    from presto_tpu.batch import Column, next_bucket
-
-    ncols = len(live[0].columns)
+    first = live[0]
+    if not first.columns:
+        return None
     for b in live:
-        for ci, c in enumerate(b.columns):
-            if c.type.is_nested:
+        for c, c0 in zip(b.columns, first.columns):
+            if (c.children or isinstance(c.values, np.ndarray)
+                    or c.dictionary is not c0.dictionary
+                    or c.values.dtype != c0.values.dtype):
                 return None
-            if (c.dictionary is not None
-                    and c.dictionary is not live[0].columns[ci].dictionary):
-                return None
-            if isinstance(c.values, np.ndarray):
-                return None  # host batch: the host path is already cheap
     total = sum(b.num_rows for b in live)
     out_cap = next_bucket(total, min_capacity)
-    # gather indices into the concatenation of the full (padded) arrays;
-    # counts are host ints so this is pure numpy
-    idx = np.zeros(out_cap, np.int32)
-    off = 0
-    base = 0
+    if len(live) == 1 and first.capacity == out_cap:
+        return first
+    has_valid = tuple(any(b.columns[ci].valid is not None for b in live)
+                      for ci in range(len(first.columns)))
+    outs = tuple(
+        (jnp.zeros((out_cap,) + c.values.shape[1:], c.values.dtype),
+         jnp.zeros(out_cap, bool) if hv else None)
+        for c, hv in zip(first.columns, has_valid))
+    offset = 0
     for b in live:
-        idx[off:off + b.num_rows] = base + np.arange(b.num_rows,
-                                                     dtype=np.int32)
-        off += b.num_rows
-        base += b.capacity
-    caps = tuple(b.capacity for b in live)
-    has_valid = tuple(
-        any(b.columns[ci].valid is not None for b in live)
-        for ci in range(ncols))
-    dtypes = tuple(str(live[0].columns[ci].values.dtype)
-                   for ci in range(ncols))
-    key = (caps, out_cap, has_valid, dtypes)
-    from presto_tpu.kernelcache import cache_get, cache_put
+        ins = tuple(
+            (c.values, None if not hv else c.valid if c.valid is not None
+             else np.ones(b.capacity, bool))
+            for c, hv in zip(b.columns, has_valid))
+        key = (out_cap, b.capacity, has_valid,
+               tuple((c.values.dtype.str, c.values.shape[1:])
+                     for c in b.columns))
+        program = cache_get(_APPEND_PROGRAMS, key)
+        if program is None:
+            program = timed_first_call(
+                jax.jit(_append_kernel, donate_argnums=0), None,
+                _APPEND_PROGRAMS)
+            cache_put(_APPEND_PROGRAMS, key, program)
+        outs = program(outs, ins, np.int32(offset), np.int32(b.num_rows))
+        offset += b.num_rows
+    return Batch(tuple(Column(c.type, values, valid, c.dictionary)
+                       for c, (values, valid) in zip(first.columns, outs)),
+                 total)
 
-    fn = cache_get(_CONCAT_PROGRAMS, key)
-    if fn is None:
-        import jax
-        import jax.numpy as jnp
 
-        def kernel(cols_per_batch, valids_per_batch, gather_idx):
-            outs = []
-            for ci2 in range(len(cols_per_batch[0])):
-                cat = jnp.concatenate(
-                    [cb[ci2] for cb in cols_per_batch])
-                out_v = cat[gather_idx]
-                if valids_per_batch[0][ci2] is not None:
-                    vcat = jnp.concatenate(
-                        [vb[ci2] for vb in valids_per_batch])
-                    outs.append((out_v, vcat[gather_idx]))
-                else:
-                    outs.append((out_v, None))
-            return tuple(outs)
+def _append_kernel(outs, ins, offset, num_rows):
+    """Write the first ``num_rows`` rows of every input array into the
+    output array at ``offset``; arrays pair up leaf by leaf.  Gather- and
+    scatter-free: the input (cut to the output's length, every live row
+    is inside it) is rotated to its place within one window of the
+    output, masked in, and the window written back, so the program
+    depends on the two capacities only and never on the row counts."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
 
-        fn = jax.jit(kernel)
-        cache_put(_CONCAT_PROGRAMS, key, fn, cap=128)
-    cols_per_batch = tuple(
-        tuple(b.columns[ci].values for ci in range(ncols)) for b in live)
-    valids_per_batch = tuple(
-        tuple((b.columns[ci].valid if b.columns[ci].valid is not None
-               else np.ones(b.capacity, bool)) if has_valid[ci] else None
-              for ci in range(ncols))
-        for b in live)
-    outs = fn(cols_per_batch, valids_per_batch, idx)
-    cols = tuple(
-        Column(live[0].columns[ci].type, v, valid,
-               live[0].columns[ci].dictionary)
-        for ci, (v, valid) in enumerate(outs))
-    return _B(cols, total)
+    def append(out, x):
+        window = min(x.shape[0], out.shape[0])
+        start = jnp.minimum(offset, out.shape[0] - window)
+        shift = offset - start
+        tail = (0,) * (out.ndim - 1)
+        current = lax.dynamic_slice(out, (start,) + tail,
+                                    (window,) + out.shape[1:])
+        row = jnp.arange(window, dtype=jnp.int32)
+        mine = (row >= shift) & (row < shift + num_rows)
+        mine = mine.reshape((window,) + (1,) * (out.ndim - 1))
+        merged = jnp.where(mine, jnp.roll(x[:window], shift, axis=0),
+                           current)
+        return lax.dynamic_update_slice(out, merged, (start,) + tail)
+
+    return jax.tree_util.tree_map(append, outs, ins)
 
 
 def column_pairs(batch: Batch) -> List[Tuple[object, object]]:

@@ -897,8 +897,7 @@ class LocalQueryRunner:
         """Whole-query XLA execution: the mesh-SQL lowering on a
         single-device mesh compiles the ENTIRE query into one cached
         program — repeat executions are one device dispatch instead of
-        per-operator round-trips (decisive on remote-attached TPUs
-        where each dispatch costs ~0.1-1 s).  Unsupported shapes fall
+        per-operator dispatches.  Unsupported shapes fall
         back to the operator tier."""
         from presto_tpu.parallel.sqlmesh import (
             MeshQueryRunner, MeshUnsupported,

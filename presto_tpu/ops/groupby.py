@@ -54,24 +54,17 @@ from presto_tpu.ops.keys import normalize_keys
 
 def _pallas_enabled() -> bool:
     """Opt-in Pallas path for the direct-groupby reduction
-    (PRESTO_TPU_PALLAS=1).  Measured on v5e: the hand-written kernel is
-    correct (4.5e-9 rel err at 1M rows) but ~7x slower than the XLA
-    einsum in the fused Q1 pipeline — XLA fuses the elementwise prologue
-    (filter mask, expression arithmetic, hi/lo split) into the einsum's
-    operand reads, while a pallas_call is a fusion barrier that forces
-    those operands through HBM.  Kept as the kernel-authoring template
-    (grid accumulation, MXU dots, compensated-f32 pairs) and for shapes
-    where the prologue is trivial."""
+    (PRESTO_TPU_PALLAS=1).  Not measured on the chip against the current
+    einsum path: XLA fuses the elementwise prologue (filter mask,
+    expression arithmetic, hi/lo split) into the einsum's operand reads,
+    while a pallas_call is a fusion barrier that forces those operands
+    through HBM.  Kept as the kernel-authoring template (grid
+    accumulation, MXU dots, compensated-f32 pairs) and for shapes where
+    the prologue is trivial.  Opted in, a kernel failure raises."""
     import os
 
-    if os.environ.get("PRESTO_TPU_PALLAS", "0") != "1":
-        return False
-    try:
-        from presto_tpu.ops import pallas_groupby
+    return os.environ.get("PRESTO_TPU_PALLAS", "0") == "1"
 
-        return pallas_groupby.available()
-    except Exception:  # noqa: BLE001
-        return False
 
 # One aggregation input: (prim, values, valid|None) with prim in
 # {'sum','count','min','max'}; 'count' ignores values.
@@ -325,21 +318,17 @@ def direct_grouped_aggregate(
     if use_matmul:
         hi = m.astype(jnp.float32)
         lo = (m - hi.astype(jnp.float64)).astype(jnp.float32)
-        reduced = None
         if _pallas_enabled():
             # single-pass VMEM-resident Pallas kernel: no [B, G, A]
             # intermediate, compensated-f32 running totals (see
-            # ops/pallas_groupby.py)
-            try:
-                from presto_tpu.ops.pallas_groupby import (
-                    direct_segment_sums_pallas,
-                )
+            # ops/pallas_groupby.py).  Opted in = a kernel failure raises.
+            from presto_tpu.ops.pallas_groupby import (
+                direct_segment_sums_pallas,
+            )
 
-                reduced = direct_segment_sums_pallas(
-                    gid.astype(jnp.int32), hi, lo, n_seg)
-            except Exception:  # noqa: BLE001 - fall back to einsum
-                reduced = None
-        if reduced is None:
+            reduced = direct_segment_sums_pallas(
+                gid.astype(jnp.int32), hi, lo, n_seg)
+        else:
             block = 2048 if cap % 2048 == 0 else 1024
             B = cap // block
             oh = jax.nn.one_hot(gid.reshape(B, block), n_seg,
@@ -533,9 +522,9 @@ def global_aggregate(aggs: Sequence[AggIn], num_rows: jax.Array,
 # The kernels above are pure functions of traced arrays plus static
 # metadata (types, prims, capacities).  Callers in the operator layer run
 # once per finish; without jit every jnp op dispatches eagerly — dozens
-# of device round-trips per aggregation, which dominates on
-# remote-attached TPUs.  These wrappers jit the whole kernel and share
-# the compiled program across queries (AccumulatorCompiler cache role).
+# of device dispatches per aggregation.  These wrappers jit the whole
+# kernel and share the compiled program across queries
+# (AccumulatorCompiler cache role).
 
 from presto_tpu.kernelcache import cache_get, cache_put, new_cache
 

@@ -27,10 +27,6 @@ shape-static, CPU/TPU portable.  The sort-based kernels in
 tier's contract is that state persists on device across batches (the
 GroupByHash accumulate never re-sorts seen rows) and that probe cost is
 O(chain length), not O(log build).
-
-An opt-in Pallas formulation of the probe-insert loop lives in
-``ops/pallas_hash.py`` (interpret-mode CPU path for tests, the same
-kernel-authoring-template role as ``ops/pallas_groupby.py``).
 """
 
 from __future__ import annotations

@@ -16,7 +16,12 @@ import jax
 import jax.numpy as jnp
 
 from presto_tpu import types as T
+from presto_tpu.kernelcache import (
+    cache_get, cache_put, new_cache, timed_first_call,
+)
 from presto_tpu.ops.keys import to_sortable_i64
+
+_SORT_PROGRAMS = new_cache("sort")
 
 # (values, valid|None, type, descending, nulls_first)
 SortKey = Tuple[jax.Array, Optional[jax.Array], T.Type, bool, bool]
@@ -27,12 +32,46 @@ def sort_permutation(keys: Sequence[SortKey], num_rows: jax.Array) -> jax.Array:
     sort to the end.
 
     On TPU this routes to the radix passes (ops/radix.py): XLA's sort
-    lowering compiles in time proportional to N there, the radix program
-    in O(1).  CPU/GPU keep the native sort."""
-    from presto_tpu.ops.radix import radix_sort_permutation, use_radix
+    lowering compiles in time proportional to N there.  CPU/GPU keep the
+    native sort.
 
-    if use_radix():
-        return radix_sort_permutation(keys, num_rows)
+    Always ONE cached jitted program per sort spec (the "sort" kernel
+    cache, compile time attributed there): operators call this
+    eagerly, and the radix passes are dozens of ops with a ``lax.cond``
+    each — dispatched eagerly, every cond recompiles on every call (its
+    branches are fresh closures), which on the chip was 32 XLA compiles
+    and ~5 s per ORDER BY of four rows.  Inside a trace the nested jit
+    inlines."""
+    from presto_tpu.ops.radix import use_radix
+
+    spec = tuple((typ, desc, nulls_first)
+                 for _values, _valid, typ, desc, nulls_first in keys)
+    radix = use_radix()
+    program = cache_get(_SORT_PROGRAMS, (spec, radix))
+    if program is None:
+        program = timed_first_call(jax.jit(_sort_kernel(spec, radix)), None,
+                                   _SORT_PROGRAMS)
+        cache_put(_SORT_PROGRAMS, (spec, radix), program)
+    return program(tuple(values for values, *_ in keys),
+                   tuple(valid for _values, valid, *_ in keys), num_rows)
+
+
+def _sort_kernel(spec, radix: bool):
+    def kernel(values, valids, num_rows):
+        keys = [(v, valid, typ, desc, nulls_first)
+                for v, valid, (typ, desc, nulls_first)
+                in zip(values, valids, spec)]
+        if radix:
+            from presto_tpu.ops.radix import radix_sort_permutation
+
+            return radix_sort_permutation(keys, num_rows)
+        return _lexsort_permutation(keys, num_rows)
+
+    return kernel
+
+
+def _lexsort_permutation(keys: Sequence[SortKey],
+                         num_rows: jax.Array) -> jax.Array:
     cap = keys[0][0].shape[0]
     pad = (jnp.arange(cap) >= num_rows).astype(jnp.int8)
     major = []  # built major-to-minor, reversed for lexsort below

@@ -40,7 +40,10 @@ def segment_ids(key_equal_prev: Array) -> Array:
 def _seg_bounds(seg: Array) -> Tuple[Array, Array, Array, Array]:
     """Per-row (start_idx, end_idx, index_in_seg, seg_count)."""
     n = seg.shape[0]
-    idx = jnp.arange(n)
+    # int32 row indices: the chip emulates int64, and an int64
+    # cummax/cummin (a reduce_window there) takes minutes to compile at
+    # one 64K batch, or crashes the TPU compiler outright
+    idx = jnp.arange(n, dtype=jnp.int32)
     is_start = jnp.concatenate([jnp.ones((1,), jnp.bool_),
                                 seg[1:] != seg[:-1]])
     # start index of this row's segment: running max of start positions

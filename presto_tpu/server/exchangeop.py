@@ -50,8 +50,6 @@ class PartitionedOutputOperator(Operator):
         self.precomputed = precomputed
 
     def add_input(self, batch: Batch) -> None:
-        import jax.numpy as jnp
-
         from presto_tpu.ops.hashing import (
             partition_of, row_hash, value_hash_triple,
         )
@@ -68,13 +66,19 @@ class PartitionedOutputOperator(Operator):
             return
         if self.precomputed:
             parts = np.asarray(parts_col.values)[:batch.num_rows]
-            batch = batch.compact()
         else:
-            batch = batch.compact()
+            # hash at the padded capacity (bucketed shapes, so a bounded
+            # set of programs) and cut the ids on the host
             key_cols = [value_hash_triple(batch.columns[c])
                         for c in self.channels]
             hashes = row_hash(key_cols)
-            parts = np.asarray(partition_of(hashes, self.n))
+            parts = np.asarray(
+                partition_of(hashes, self.n))[:batch.num_rows]
+        # the rows leave through the wire: stage them to the host ONCE
+        # and cut the pages there.  take() on device arrays dispatches one
+        # eager XLA program per distinct (rows, page rows) pair, and row
+        # counts are data (see serde._encode_payload)
+        batch = batch.to_numpy().compact()
         # one stable argsort-by-partition + boundary slicing instead of
         # one np.nonzero pass per partition: a single O(n log n) pass
         # regardless of fan-out, and rows stay in input order within a
@@ -85,7 +89,7 @@ class PartitionedOutputOperator(Operator):
             lo, hi = int(bounds[p]), int(bounds[p + 1])
             if lo == hi:
                 continue
-            sub = batch.take(jnp.asarray(order[lo:hi]))
+            sub = batch.take(order[lo:hi])
             self.buffers.enqueue(p, serialize_batch(sub))
             self.ctx.stats.output_rows += sub.num_rows
 
@@ -108,7 +112,7 @@ class TaskOutputOperator(Operator):
 
     def add_input(self, batch: Batch) -> None:
         self.ctx.stats.input_rows += batch.num_rows
-        self.buffers.enqueue(0, serialize_batch(batch.compact()))
+        self.buffers.enqueue(0, serialize_batch(batch))
         self.ctx.stats.output_rows += batch.num_rows
 
     def finish(self) -> None:
@@ -137,7 +141,7 @@ class RoundRobinOutputOperator(Operator):
     def add_input(self, batch: Batch) -> None:
         self.ctx.stats.input_rows += batch.num_rows
         self.buffers.enqueue(self._next % self.n,
-                             serialize_batch(batch.compact()))
+                             serialize_batch(batch))
         self._next += 1
         self.ctx.stats.output_rows += batch.num_rows
 

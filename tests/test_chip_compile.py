@@ -1,0 +1,258 @@
+"""Compile the main path's kernels for a TPU v5e that is described, not
+attached (on-chip-measurement guide, section 2).
+
+These are compiles, not chip runs: the TPU compiler installed here raises
+what the chip's compiler would raise (64-bit rewrites it cannot do, Pallas
+kernels it refuses, programs that do not fit), at the shapes SF1 produces
+behind ``scan_batch_rows`` = 65536.  Nothing executes, so they say nothing
+about results or times — ``chip_smoke.py`` does that on the chip.
+
+The trace-time branches that ask ``jax.default_backend()`` see the CPU in
+such a compile; the ``tpu_backend`` fixture steers them here, in the test.
+The topology is described inside a module-scoped fixture (only the xdist
+worker that is handed this file loads libtpu), the compilation cache is
+off around every compile (a described-device entry cannot be read back),
+and no child process is started.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from presto_tpu import types as T
+
+ROWS = 65536          # EngineConfig.scan_batch_rows
+# The sort / claim-loop kernels compile in time proportional to their
+# length (tens of seconds to minutes at SF1 sizes, measured here), so they
+# are compiled at a quarter batch against reduced tables: the lowering the
+# chip's compiler accepts or refuses is the same.
+SMALL = 16384
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """Take the branches the chip takes (ops/keys.py f32 DOUBLE ordering,
+    ops/groupby.py MXU einsum, ops/radix.py radix sort)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.fixture
+def chip(one_chip, no_compile_cache, tpu_backend):
+    """compile(fn, *specs) for one described v5e chip; spec(shape, dtype)
+    builds the argument shapes placed on it."""
+
+    class Chip:
+        @staticmethod
+        def spec(shape, dtype):
+            shape = (shape,) if isinstance(shape, int) else shape
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        @staticmethod
+        def compile(fn, *specs):
+            compiled = jax.jit(fn).lower(*specs).compile()
+            assert compiled.memory_analysis() is not None
+            return compiled
+
+    return Chip
+
+
+def test_hashtable_probe_insert(chip):
+    """The claim loop of the device hash tables: int64 words, scatter-min
+    claims, 16K rows into 256K slots."""
+    from presto_tpu.ops.hashtable import probe_insert
+
+    cap = 1 << 18
+    chip.compile(
+        lambda kw, live, tw, tp, tu: probe_insert([kw], live, (tw,), tp, tu),
+        chip.spec(SMALL, jnp.int64), chip.spec(SMALL, bool),
+        chip.spec(cap, jnp.int64), chip.spec(cap, jnp.uint8),
+        chip.spec(cap, bool))
+
+
+def test_pages_hash_build_and_probe(chip):
+    """The hash-table join (exec/joinop.py takes it on the chip up to
+    device_join_probe_max_build_rows = 128K): build 16K rows, probe 16K."""
+    from presto_tpu.ops.hashtable import pages_hash_build, pages_hash_probe
+
+    n_build, cap = SMALL, 2 * SMALL
+    chip.compile(
+        lambda k, v, n: pages_hash_build([(k, v, T.BIGINT)], n, cap),
+        chip.spec(n_build, jnp.int64), chip.spec(n_build, bool),
+        chip.spec((), jnp.int64))
+    table = jax.eval_shape(
+        lambda k, v, n: pages_hash_build([(k, v, T.BIGINT)], n, cap)[:5],
+        jax.ShapeDtypeStruct((n_build,), jnp.int64),
+        jax.ShapeDtypeStruct((n_build,), bool),
+        jax.ShapeDtypeStruct((), jnp.int64))
+    table = jax.tree.map(lambda s: chip.spec(s.shape, s.dtype), table)
+    chip.compile(
+        lambda t, k, v, n: pages_hash_probe(t, [(k, v, T.BIGINT)], n),
+        table, chip.spec(SMALL, jnp.int64), chip.spec(SMALL, bool),
+        chip.spec((), jnp.int64))
+
+
+def test_radix_argsort(chip):
+    """Radix sort replaces XLA sort on the chip (ops/radix.use_radix):
+    one 64K batch of int64 keys."""
+    from presto_tpu.ops.radix import radix_argsort_i64, use_radix
+
+    assert use_radix()
+    chip.compile(lambda w: radix_argsort_i64([w]),
+                 chip.spec(ROWS, jnp.int64))
+
+
+def test_join_build_index_and_probe_counts(chip):
+    """The sorted join tier: build_index (radix on the chip) over a 16K
+    build side, probe_counts of a 16K probe batch."""
+    from presto_tpu.ops.join import build_index, probe_counts
+
+    chip.compile(build_index, chip.spec(SMALL, jnp.int64))
+    chip.compile(probe_counts, chip.spec(SMALL, jnp.int64),
+                 chip.spec(SMALL, jnp.int32), chip.spec(SMALL, jnp.int64))
+
+
+def _q1_aggs(values, valid=None):
+    return [("sum", values, valid), ("sum", values, valid),
+            ("count", None, None)]
+
+
+def test_direct_groupby_mxu(chip):
+    """TPC-H Q1's shape: two dictionary keys (3 x 2 codes), DOUBLE sums —
+    the blocked one-hot einsum with the hi/lo f32 split."""
+    from presto_tpu.ops.groupby import direct_grouped_aggregate
+
+    compiled = chip.compile(
+        lambda k0, k1, v, n: direct_grouped_aggregate(
+            [(k0, None), (k1, None)], [3, 2], _q1_aggs(v), n),
+        chip.spec(ROWS, jnp.int32), chip.spec(ROWS, jnp.int32),
+        chip.spec(ROWS, jnp.float64), chip.spec((), jnp.int64))
+    # the MXU branch, not the scatter: a convolution/dot is in the program
+    text = compiled.as_text()
+    assert "convolution" in text or " dot(" in text
+
+
+def test_segment_pre_reduce_direct_and_sorted(chip):
+    """The fused scan segment's partial aggregation (exec/fusion.py): the
+    direct path over dictionary keys (one 64K batch) and the sort path
+    over a BIGINT key (Q3's l_orderkey, 16K rows)."""
+    from presto_tpu.ops.groupby import segment_pre_reduce
+
+    f64 = np.dtype("float64")
+    chip.compile(
+        lambda k0, k1, v, lm, n: segment_pre_reduce(
+            [(k0, None, T.VARCHAR), (k1, None, T.VARCHAR)], _q1_aggs(v),
+            [f64, f64, np.dtype("int64")], n, lm, [3, 2], ROWS),
+        chip.spec(ROWS, jnp.int32), chip.spec(ROWS, jnp.int32),
+        chip.spec(ROWS, jnp.float64), chip.spec(ROWS, bool),
+        chip.spec((), jnp.int64))
+    chip.compile(
+        lambda k, v, lm, n: segment_pre_reduce(
+            [(k, None, T.BIGINT)], _q1_aggs(v),
+            [f64, f64, np.dtype("int64")], n, lm, None, SMALL),
+        chip.spec(SMALL, jnp.int64), chip.spec(SMALL, jnp.float64),
+        chip.spec(SMALL, bool), chip.spec((), jnp.int64))
+
+
+def test_hash_groupby_update(chip):
+    """The device-resident GroupByHash accumulate: a 16K batch into a
+    256K-slot table, BIGINT key, DOUBLE sum + count."""
+    from presto_tpu.ops.hashtable import groupby_init, groupby_update
+
+    cap = 1 << 18
+    state = jax.eval_shape(lambda: groupby_init(
+        cap, 1, [jnp.int64], [False],
+        [("sum", jnp.float64), ("count", None)]))
+    state = jax.tree.map(lambda s: chip.spec(s.shape, s.dtype), state)
+    chip.compile(
+        lambda st, k, v, n: groupby_update(
+            st, [(k, None, T.BIGINT)],
+            [("sum", v, None), ("count", None, None)], n),
+        state, chip.spec(SMALL, jnp.int64), chip.spec(SMALL, jnp.float64),
+        chip.spec((), jnp.int64))
+
+
+def test_double_keys_take_the_f32_ordering(chip):
+    """DOUBLE sort/group keys: the chip cannot bitcast f64 (the X64 rewrite
+    refuses it), so ops/keys.py must take its f32-pattern branch there."""
+    from presto_tpu.ops.keys import to_sortable_i64
+
+    chip.compile(lambda v: to_sortable_i64(jnp, v, T.DOUBLE),
+                 chip.spec(ROWS, jnp.float64))
+
+
+def test_window_segmented_scan_and_rank(chip):
+    """Window functions over one partition-sorted 64K batch: the segmented
+    cumulative DOUBLE sum (associative_scan) and the ranking functions over
+    ops/window._seg_bounds.  With int64 row indices _seg_bounds took 216 s
+    to compile here and W.rank crashed the TPU compiler (PR 25 finding);
+    it indexes in int32 now."""
+    from presto_tpu.ops import window as W
+
+    chip.compile(lambda seg, peer, v: (W._seg_cumsum(seg, v),
+                                       W.rank(seg, peer),
+                                       W.row_number(seg)),
+                 chip.spec(ROWS, jnp.int32), chip.spec(ROWS, jnp.int32),
+                 chip.spec(ROWS, jnp.float64))
+
+
+def test_device_concat_append(chip):
+    """exec/operator.device_concat keeps device batches on the device: one
+    append program per (output bucket, input bucket) pair, here a join's
+    64K-capacity output into a 16K bucket and into a 1M one, over the
+    64-bit column types (BIGINT, DOUBLE with a validity mask)."""
+    from presto_tpu.exec.operator import _append_kernel
+
+    for out_rows in (SMALL, 1 << 20):
+        chip.compile(
+            _append_kernel,
+            ((chip.spec(out_rows, jnp.int64), None),
+             (chip.spec(out_rows, jnp.float64), chip.spec(out_rows, bool))),
+            ((chip.spec(ROWS, jnp.int64), None),
+             (chip.spec(ROWS, jnp.float64), chip.spec(ROWS, bool))),
+            chip.spec((), jnp.int32), chip.spec((), jnp.int32))
+
+
+def test_pallas_direct_segment_sums(chip):
+    """The opt-in (PRESTO_TPU_PALLAS=1) VMEM-resident group-by kernel at
+    Q1's width: it must be a real Mosaic kernel, not interpret mode."""
+    from presto_tpu.ops.pallas_groupby import direct_segment_sums_pallas
+
+    compiled = chip.compile(
+        lambda g, hi, lo: direct_segment_sums_pallas(g, hi, lo, 8),
+        chip.spec(ROWS, jnp.int32), chip.spec((ROWS, 13), jnp.float32),
+        chip.spec((ROWS, 13), jnp.float32))
+    assert "tpu_custom_call" in compiled.as_text()

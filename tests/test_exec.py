@@ -251,3 +251,63 @@ def test_empty_results():
     ])
     execute_pipelines([p])
     assert out.rows() == []  # grouped agg over empty input: no rows
+
+
+def _concat_input(rng, capacity, rows, nullable, dictionary, on_device=True):
+    import jax
+
+    from presto_tpu.batch import Batch, Column
+
+    put = jax.device_put if on_device else (lambda a: a)
+    valid = put(rng.random(capacity) < 0.8) if nullable else None
+    return Batch((
+        Column(T.BIGINT, put(rng.integers(-1 << 40, 1 << 40, capacity))),
+        Column(T.DOUBLE, put(rng.random(capacity)), valid),
+        Column(T.VARCHAR, put(rng.integers(0, 3, capacity).astype(np.int32)),
+               None, dictionary)), rows)
+
+
+@pytest.mark.parametrize("inputs", [
+    [(65536, 600, False)],                      # one sparse join output
+    [(65536, 600, True), (65536, 0, False), (65536, 900, False),
+     (1024, 1024, True), (2048, 1500, False)],  # mixed buckets and masks
+    [(1024, 1000, False)] * 2 + [(4096, 48, True)],  # last lands at the end
+    [(1024, 1024, False)] * 2,                  # exactly fills its bucket
+], ids=["one-sparse", "mixed", "tail-window", "full"])
+def test_device_concat_stays_on_device(inputs):
+    """Device inputs are appended on the device (one program per pair of
+    buckets) and equal the host concat, padding invalid."""
+    from presto_tpu.batch import Dictionary, concat_batches
+    from presto_tpu.exec.operator import device_concat, pad_batch
+
+    rng = np.random.default_rng(7)
+    dictionary = Dictionary(["a", "b", "c"])
+    batches = [_concat_input(rng, *spec, dictionary) for spec in inputs]
+    want = concat_batches(batches)
+    got = device_concat(batches, 1024)
+    n = want.num_rows
+    assert (got.num_rows, got.capacity) == (n, pad_batch(want).capacity)
+    assert got.to_pylist() == want.to_pylist()
+    for g in got.columns:
+        assert not isinstance(g.values, np.ndarray)
+        assert g.valid is None or not np.asarray(g.valid)[n:].any()
+
+
+def test_device_concat_passthrough_and_host_path():
+    """A single device batch at its bucket is returned as it is; a host
+    input or a second dictionary sends the concat through the host."""
+    from presto_tpu.batch import Dictionary
+    from presto_tpu.exec.operator import _APPEND_PROGRAMS, device_concat
+
+    rng = np.random.default_rng(8)
+    d1, d2 = Dictionary(["a", "b", "c"]), Dictionary(["c", "b", "a"])
+    at_bucket = _concat_input(rng, 1024, 7, False, d1)
+    assert device_concat([at_bucket], 1024) is at_bucket
+    programs = len(_APPEND_PROGRAMS)
+    for other in (_concat_input(rng, 1024, 9, False, d1, on_device=False),
+                  _concat_input(rng, 1024, 9, False, d2)):
+        got = device_concat([at_bucket, other], 1024)
+        assert got.num_rows == 16 and got.capacity == 1024
+        assert got.to_pylist()[:7] == at_bucket.to_pylist()
+        assert got.to_pylist()[7:] == other.to_pylist()
+    assert len(_APPEND_PROGRAMS) == programs

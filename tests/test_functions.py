@@ -187,3 +187,35 @@ class TestAggregateExtras:
             "select any_value(n_name) from nation "
             "where n_name = 'KENYA'").rows
         assert rows == [("KENYA",)]
+
+
+DECIMAL_LITERALS_ARE_DOUBLE = pytest.mark.xfail(strict=True, reason=(
+    "decimal literals are typed DOUBLE and folded in IEEE f64: 0.06 + 0.01 "
+    "is one ulp under 0.07 on the CPU, while the chip's DOUBLE (an f32 "
+    "pair) cannot see that ulp and answers as SQL's exact decimal "
+    "arithmetic does (PR 25: TPC-H Q6 at SF1 is 123439380.03 on the chip, "
+    "75348424.71 on the CPU engine).  ROADMAP S5: type decimal literals "
+    "DECIMAL or fold them exactly; these then pass and the marker goes"))
+
+
+class TestDecimalLiterals:
+    """Pins the CPU-versus-chip divergence on a predicate at a decimal
+    boundary until the literal typing is repaired."""
+
+    @DECIMAL_LITERALS_ARE_DOUBLE
+    def test_sum_of_decimal_literals_is_exact(self, runner):
+        assert one(runner, "select 0.06 + 0.01 = 0.07") == (True,)
+
+    @DECIMAL_LITERALS_ARE_DOUBLE
+    def test_q6_bounds_include_the_boundary_discount(self, runner):
+        """TPC-H Q6's BETWEEN 0.06 - 0.01 AND 0.06 + 0.01 means
+        [0.05, 0.07]: rows at l_discount = 0.07 belong to the answer."""
+        where = ("from lineitem where l_shipdate >= date '1994-01-01' "
+                 "and l_shipdate < date '1995-01-01' and l_quantity < 24 "
+                 "and l_discount between ")
+        computed = one(runner, "select count(*), sum(l_extendedprice * "
+                       "l_discount) " + where + "0.06 - 0.01 and 0.06 + 0.01")
+        exact = one(runner, "select count(*), sum(l_extendedprice * "
+                    "l_discount) " + where + "0.05 and 0.07")
+        assert exact[0] > 0
+        assert computed == exact

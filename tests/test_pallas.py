@@ -3,11 +3,9 @@
 On CPU the kernels run through the Pallas interpreter; the real-TPU
 compile path was validated on v5e (see ops/pallas_groupby.py docstring
 for the measured status vs the XLA einsum).  The open-addressing table
-section covers BOTH formulations of the hash tier — the shipping XLA
-claim loop (ops/hashtable.py) and the serial Pallas rendering
-(ops/pallas_hash.py) — against numpy oracles: collision storms, the
-rehash boundary (including the min/max identity carry), null keys, and
-the 1-byte hash-prefix reject."""
+section covers the hash tier's XLA claim loop (ops/hashtable.py) against
+numpy oracles: collision storms, the rehash boundary (including the
+min/max identity carry), null keys, and the 1-byte hash-prefix reject."""
 
 import collections
 
@@ -59,7 +57,7 @@ def test_engine_results_identical_with_pallas_flag(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# open-addressing hash table (ops/hashtable.py + ops/pallas_hash.py)
+# open-addressing hash table (ops/hashtable.py)
 # ---------------------------------------------------------------------------
 
 def _groupby_oracle(keys, valid, vals):
@@ -270,36 +268,3 @@ def test_pages_hash_duplicate_and_missing_probe_keys():
         assert cnt[i] == want, i
         for j in range(cnt[i]):
             assert bk[perm_np[lo[i] + j]] == pk[i]
-
-
-@pytest.mark.skipif(not P.available(), reason="pallas unavailable")
-def test_pallas_insert_matches_claim_loop_group_sets():
-    """The serial Pallas formulation (interpret mode) and the shipping
-    claim loop must agree on the GROUP PARTITION (same-key rows share a
-    slot, distinct keys get distinct slots) under a collision storm."""
-    import jax.numpy as jnp
-
-    from presto_tpu.ops import hashtable as H
-    from presto_tpu.ops import pallas_hash as PH
-
-    rng = np.random.default_rng(5)
-    n = 2048
-    keys = rng.integers(0, 700, n).astype(np.int64)
-    kw = [jnp.asarray(keys)]
-    live = jnp.ones(n, bool)
-    # pallas serial insert
-    twp, tpp, tup = PH.empty_table_i32(2048, 1)
-    slot_p, _, _, _ = PH.pallas_probe_insert(kw, live, twp, tpp, tup,
-                                             interpret=True)
-    # claim loop
-    words = tuple(jnp.zeros(2048, jnp.int64) for _ in range(1))
-    slot_c, _, _, _, ok = H.probe_insert(
-        kw, live, words, jnp.zeros(2048, jnp.uint8),
-        jnp.zeros(2048, bool))
-    assert bool(ok)
-    for slots in (np.asarray(slot_p), np.asarray(slot_c)):
-        m = {}
-        for k, s in zip(keys.tolist(), slots.tolist()):
-            assert 0 <= s < 2048
-            assert m.setdefault(k, s) == s       # same key -> same slot
-        assert len(set(m.values())) == len(m)    # distinct -> distinct
