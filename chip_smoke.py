@@ -7,8 +7,11 @@ generated from the connector's fixed hash streams, Q1 / Q6 / Q3 submitted
 over HTTP through ``presto_tpu.client`` twice each (cold, then warm), every
 result compared with a plain numpy computation over the same generated
 columns that shares no code with the engine.  The warm run of each query
-must compile nothing, by the engine's own ``jit_compiles`` and by XLA's
-backend-compile event; a worker's scan batch must sit on a TPU device.
+must build nothing, by the engine's own ``jit_compiles`` (kernel-cache
+misses) and by XLA's backend-compile event, which JAX raises around
+``compile_or_get_cached``: it fires for a load from the persistent cache
+as well as for a compile, so the cold run's count is of programs built,
+not of cache misses.  A worker's scan batch must sit on a TPU device.
 
 ``--chips 4``: only the collective data plane (``mesh_device_exchange``,
 four co-resident workers on one 4-device mesh), Q1 and Q3 at SF1 against
@@ -213,7 +216,8 @@ def compare(name: str, got: list, want: list) -> float:
 # ---------------------------------------------------------------------------
 
 class XlaCompiles:
-    """Counts XLA backend compiles through jax.monitoring."""
+    """Counts XLA programs built (compiled, or loaded from the
+    persistent cache: the event is the same) through jax.monitoring."""
 
     def __init__(self):
         import jax.monitoring

@@ -33,8 +33,14 @@ from typing import Any, Iterable, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from presto_tpu import types as T
+from presto_tpu.spans import activity
 
 Array = Any  # np.ndarray | jax.Array
+
+
+def _on_device(array: Array) -> bool:
+    """A jax.Array, whose conversion to numpy waits for the device."""
+    return hasattr(array, "block_until_ready")
 
 _UNSET = object()  # sentinel: "keep existing validity" in Column.with_values
 
@@ -249,9 +255,19 @@ class Column:
         kids = tuple(c.pad(capacity) for c in self.children)
         return Column(self.type, values, valid, self.dictionary, kids)
 
+    def on_device(self) -> bool:
+        return (_on_device(self.values) or _on_device(self.valid)
+                or any(c.on_device() for c in self.children))
+
     def to_numpy(self) -> "Column":
+        if self.on_device():
+            with activity("device_wait"):   # blocks until it is computed
+                return self._to_numpy()
+        return self._to_numpy()
+
+    def _to_numpy(self) -> "Column":
         valid = None if self.valid is None else np.asarray(self.valid)
-        kids = tuple(c.to_numpy() for c in self.children)
+        kids = tuple(c._to_numpy() for c in self.children)
         return Column(self.type, np.asarray(self.values), valid,
                       self.dictionary, kids)
 
@@ -356,21 +372,29 @@ class Batch:
         return self.head(self.num_rows)
 
     def to_numpy(self) -> "Batch":
-        return Batch(tuple(c.to_numpy() for c in self.columns), self.num_rows)
+        # one device_wait for the batch, not one per column
+        if any(c.on_device() for c in self.columns):
+            with activity("device_wait"):
+                cols = tuple(c._to_numpy() for c in self.columns)
+        else:
+            cols = tuple(c._to_numpy() for c in self.columns)
+        return Batch(cols, self.num_rows)
 
     def to_device(self) -> "Batch":
         import jax
 
         cols = []
-        for c in self.columns:
-            if c.children:
-                # nested columns stay host-side (offsets bookkeeping);
-                # device compute operates on their flattened children
-                cols.append(c.to_numpy())
-                continue
-            values = jax.device_put(c.values)
-            valid = None if c.valid is None else jax.device_put(c.valid)
-            cols.append(Column(c.type, values, valid, c.dictionary))
+        with activity("stage_h2d"):
+            for c in self.columns:
+                if c.children:
+                    # nested columns stay host-side (offsets bookkeeping);
+                    # device compute operates on their flattened children
+                    cols.append(c.to_numpy())
+                    continue
+                values = jax.device_put(c.values)
+                valid = (None if c.valid is None
+                         else jax.device_put(c.valid))
+                cols.append(Column(c.type, values, valid, c.dictionary))
         return Batch(tuple(cols), self.num_rows)
 
     # -- interop ---------------------------------------------------------

@@ -17,6 +17,7 @@ from presto_tpu import types as T
 from presto_tpu.batch import Batch, Column, next_bucket
 from presto_tpu.exec.context import OperatorContext
 from presto_tpu.exec.operator import Operator, OperatorFactory, device_concat
+from presto_tpu.spans import activity
 
 
 @dataclasses.dataclass(frozen=True)
@@ -370,9 +371,11 @@ class HashAggregationOperator(Operator):
             state2, ng, ok = hash_groupby_update_jit(
                 self._hash_state, key_cols, agg_ins, n)
             self.ctx.stats.jit_dispatches += 1
-            if bool(ok):
+            with activity("device_wait"):
+                placed, groups = bool(ok), int(ng)
+            if placed:
                 self._hash_state = state2
-                self._hash_groups = int(ng)
+                self._hash_groups = groups
                 # proactive rehash past 1/2 fill keeps probe chains
                 # short for the NEXT batch (the rehash() trigger of
                 # MultiChannelGroupByHash.java:286)
@@ -613,7 +616,8 @@ class HashAggregationOperator(Operator):
         while True:
             gi, ng, results = grouped_aggregate_jit(key_cols, agg_ins, n,
                                                     group_cap)
-            num_groups = int(ng)
+            with activity("device_wait"):
+                num_groups = int(ng)
             if num_groups <= group_cap:
                 break
             group_cap = next_bucket(num_groups)

@@ -19,6 +19,7 @@ import time
 from typing import Dict, List, Optional
 
 from presto_tpu.config import DEFAULT, EngineConfig
+from presto_tpu.spans import HostActivity
 
 
 class MemoryReservationError(RuntimeError):
@@ -120,16 +121,19 @@ class OperatorStats:
     finish_wall_ns: int = 0
     # row-pipeline-tier device program accounting (FilterProject,
     # DynamicFilter, FusedSegment): one dispatch per jitted-program
-    # launch, one compile per kernel-cache miss that built a program.
-    # Tests assert pipeline fusion's launch-count reduction on these
-    # instead of eyeballing traces.
+    # launch.  Tests assert pipeline fusion's launch-count reduction on
+    # it instead of eyeballing traces.
     jit_dispatches: int = 0
+    # kernel-cache MISSES of those three operator families, not XLA
+    # programs built: a miss can build one program or, through eager
+    # helpers, dozens, and every other family builds without counting
+    # here.  Superseded by TaskStats.xla_builds; kept because warm-run
+    # checks pin it at 0.
     jit_compiles: int = 0
-    # wall nanoseconds this operator spent BUILDING device programs
-    # (trace + lower + XLA compile, measured around the first dispatch
-    # of each freshly built kernel) — split out of execute wall so
-    # EXPLAIN ANALYZE and the span tree can attribute compile vs
-    # execute per operator (kernelcache.timed_first_call).
+    # wall nanoseconds of those misses: expression compile plus the
+    # WHOLE first call of the fresh kernel (trace, lower, compile or
+    # load, and the run itself; kernelcache.timed_first_call).
+    # Superseded by TaskStats.xla_build_ns + xla_trace_lower_ns.
     jit_compile_ns: int = 0
     # rows folded into in-segment partial-aggregation pre-reduce
     # (exec/fusion.py Fusion II): nonzero proves the scan->agg pipeline
@@ -208,6 +212,17 @@ class TaskStats:
     # (parallel/sqlmesh.py per-shard stats) and folded into synthetic
     # per-shard TaskStats; HTTP-plane tasks report 0
     device_exchange_bytes: int = 0
+    # what the task's host threads did, nanoseconds per kind of
+    # spans.ACTIVITY_KINDS (thread-seconds: feed drivers add up)
+    host_ns: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # what XLA built on this task's threads (jax.monitoring, the
+    # listener in kernelcache.py): programs compiled OR loaded from the
+    # persistent cache, the seconds inside that, the seconds tracing
+    # and lowering before it, and how many of the builds were loads
+    xla_builds: int = 0
+    xla_build_ns: int = 0
+    xla_trace_lower_ns: int = 0
+    xla_cache_hits: int = 0
 
     def add_operator(self, s: OperatorStats) -> None:
         self.wall_ns += s.wall_ns + s.finish_wall_ns
@@ -256,6 +271,11 @@ class StageStats:
     pages_evicted: int = 0
     bytes_evicted: int = 0
     device_exchange_bytes: int = 0
+    host_ns: Dict[str, int] = dataclasses.field(default_factory=dict)
+    xla_builds: int = 0
+    xla_build_ns: int = 0
+    xla_trace_lower_ns: int = 0
+    xla_cache_hits: int = 0
 
     def add_task(self, ts: TaskStats) -> None:
         self.reporting += 1
@@ -278,9 +298,20 @@ class StageStats:
         self.pages_evicted += ts.pages_evicted
         self.bytes_evicted += ts.bytes_evicted
         self.device_exchange_bytes += ts.device_exchange_bytes
+        _add_host_and_xla(self, ts)
 
     def as_dict(self) -> Dict:
         return dataclasses.asdict(self)
+
+
+def _add_host_and_xla(into, other) -> None:
+    """The host-activity and XLA-build accounts, summed one level up."""
+    for kind, ns in other.host_ns.items():
+        into.host_ns[kind] = into.host_ns.get(kind, 0) + ns
+    into.xla_builds += other.xla_builds
+    into.xla_build_ns += other.xla_build_ns
+    into.xla_trace_lower_ns += other.xla_trace_lower_ns
+    into.xla_cache_hits += other.xla_cache_hits
 
 
 @dataclasses.dataclass
@@ -318,6 +349,11 @@ class QueryStats:
     result_cached: int = 0
     result_cache_bytes: int = 0
     stages: int = 0
+    host_ns: Dict[str, int] = dataclasses.field(default_factory=dict)
+    xla_builds: int = 0
+    xla_build_ns: int = 0
+    xla_trace_lower_ns: int = 0
+    xla_cache_hits: int = 0
 
     def add_stage(self, st: StageStats) -> None:
         self.stages += 1
@@ -338,9 +374,22 @@ class QueryStats:
         self.pages_spooled += st.pages_spooled
         self.pages_evicted += st.pages_evicted
         self.device_exchange_bytes += st.device_exchange_bytes
+        _add_host_and_xla(self, st)
 
     def as_dict(self) -> Dict:
         return dataclasses.asdict(self)
+
+
+def host_and_xla_line(stats: Dict) -> str:
+    """EXPLAIN ANALYZE's line for the host-activity and XLA-build
+    accounts of a TaskStats / QueryStats dict."""
+    host = ", ".join(f"{kind} {ns / 1e6:.1f}" for kind, ns in
+                     (stats.get("host_ns") or {}).items() if ns)
+    return (f"host ms: {host or 'nothing recorded'}; xla: "
+            f"{stats.get('xla_builds', 0)} built "
+            f"({stats.get('xla_cache_hits', 0)} loaded) in "
+            f"{stats.get('xla_build_ns', 0) / 1e6:.1f} ms, trace+lower "
+            f"{stats.get('xla_trace_lower_ns', 0) / 1e6:.1f} ms")
 
 
 def hot_operator_lines(ops, top_n: int = 5) -> List[str]:
@@ -389,6 +438,9 @@ class TaskContext:
         self.memory = MemoryContext(query.memory, f"task:{task_id}")
         self.operator_stats: List[OperatorStats] = []
         self.driver_stats: List[DriverStats] = []
+        # what this task's host threads do, and the XLA builds made on
+        # them; every Driver of the task records into it (spans.py)
+        self.activity = HostActivity()
         self.start_time = time.time()
         self._cleanups: List = []
 
@@ -400,6 +452,12 @@ class TaskContext:
         for s in list(self.operator_stats):
             ts.add_operator(s)
         ts.peak_memory_bytes = self.memory.peak
+        ts.host_ns = dict(self.activity.total_ns)
+        xla = self.activity.xla
+        ts.xla_builds = xla["builds"]
+        ts.xla_build_ns = xla["build_ns"]
+        ts.xla_trace_lower_ns = xla["trace_lower_ns"]
+        ts.xla_cache_hits = xla["cache_hits"]
         return ts
 
     def jit_counters(self) -> Dict[str, int]:

@@ -21,6 +21,7 @@ from typing import List, Optional, Sequence
 from presto_tpu.connectors.api import Split
 from presto_tpu.exec.context import OperatorContext, TaskContext
 from presto_tpu.exec.operator import Operator, OperatorFactory, SourceOperator
+from presto_tpu.spans import set_current_activity
 
 
 class Driver:
@@ -62,6 +63,11 @@ class Driver:
                           deadline: Optional[float] = None) -> None:
         # Mirror Driver.close(): operators always release their resources
         # (memory reservations, exchange fetcher threads), success or not.
+        # This thread works for the operators' task until it returns:
+        # spans.activity() and the XLA build account charge that task.
+        previous = set_current_activity(
+            self.operators[0].ctx.task.activity if self.operators
+            else None)
         try:
             for i in range(max_iterations):
                 if self.process():
@@ -82,6 +88,7 @@ class Driver:
                 except Exception:  # noqa: BLE001 - close is best-effort
                     pass
             self._record_driver_stats()
+            set_current_activity(previous)
 
     def _record_driver_stats(self) -> None:
         """Append this run's DriverStats rollup to the TaskContext (the

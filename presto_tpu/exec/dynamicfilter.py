@@ -29,6 +29,8 @@ from presto_tpu import types as T
 from presto_tpu.batch import Batch, Column
 from presto_tpu.exec.context import OperatorContext
 from presto_tpu.exec.operator import Operator, OperatorFactory
+from presto_tpu import kernelcache
+from presto_tpu.spans import activity
 from presto_tpu.kernelcache import (
     cache_get, cache_put, new_cache, record_compile, timed_first_call,
 )
@@ -114,8 +116,6 @@ class DynamicFilterOperator(Operator):
         passed as arguments, never baked in as constants, so a new
         query's dynamic-filter values reuse the compiled program (no
         eager per-batch dispatch, no retrace)."""
-        import jax
-
         cap = batch.capacity
         chans = tuple(ch for ch, _, _, _ in filters)
         has_set = tuple(st is not None for _, _, _, st in filters)
@@ -156,8 +156,9 @@ class DynamicFilterOperator(Operator):
         build_ns = _time.perf_counter_ns() - _t0
         self.ctx.stats.jit_compile_ns += build_ns
         record_compile(_DF_KERNELS, build_ns)
-        jitted = timed_first_call(jax.jit(kernel), self.ctx.stats,
-                                  _DF_KERNELS)
+        jitted = timed_first_call(
+            kernelcache.jit(kernel, "dynamic_filter"), self.ctx.stats,
+            _DF_KERNELS)
         cache_put(_DF_KERNELS, key, jitted)
         return jitted
 
@@ -182,9 +183,11 @@ class DynamicFilterOperator(Operator):
         self.ctx.stats.jit_dispatches += 1
         bounds = tuple((mn, mx) for _, mn, mx, _ in filters)
         tables = tuple(st for _, _, _, st in filters if st is not None)
-        outs, count = kernel(tuple(column_pairs(batch)), batch.num_rows,
-                             bounds, tables)
-        n_keep = int(count)
+        with activity("dispatch"):
+            outs, count = kernel(tuple(column_pairs(batch)),
+                                 batch.num_rows, bounds, tables)
+        with activity("device_wait"):
+            n_keep = int(count)
         self._rows_seen += batch.num_rows
         self._rows_kept += n_keep
         if self._rows_seen >= 4096 and \

@@ -99,10 +99,20 @@ from presto_tpu.exec.operators import (
 )
 from presto_tpu.expr.compile import ExprCompiler, needs_host_path
 from presto_tpu.expr.ir import RowExpression
+from presto_tpu import kernelcache
 from presto_tpu.kernelcache import cache_get, cache_put, new_cache
+from presto_tpu.spans import activity
 
 # compiled segment programs, shared globally across queries/operators
 _SEG_KERNELS = new_cache("fused_segment")
+
+#: the program name of a segment, by what it absorbed (join probes, a
+#: partial aggregation that is not passed through raw): a device trace
+#: then says whether a `while` loop is a probe's or a group-by's
+_SEGMENT_PROGRAM = {(False, False): "fused_segment",
+                    (True, False): "fused_segment_probe",
+                    (False, True): "fused_segment_agg",
+                    (True, True): "fused_segment_probe_agg"}
 
 # learned inner-probe expansion buckets, shared ACROSS queries: keyed by
 # (segment expr key, probe stage index, input capacity), monotonic max.
@@ -620,15 +630,20 @@ class FusedSegmentOperator(Operator):
         if not self._coalesce:
             self._pending = batch
             return
-        self._accumulate(batch)
+        batch = batch.to_numpy()
+        with activity("stage_h2d"):     # the segment stages for its scan
+            self._accumulate(batch)
 
     def get_output(self) -> Optional[Batch]:
         if self._coalesce:
             if self._acc_rows >= self._coalesce or (
                     self._finishing and self._acc_rows > 0):
-                if self._passthrough_ok():
-                    return self._emit(self._flush().compact())
-                return self._emit(self._dispatch(self._flush()))
+                passthrough = self._passthrough_ok()
+                with activity("stage_h2d"):
+                    batch = self._flush()
+                if passthrough:
+                    return self._emit(batch.compact())
+                return self._emit(self._dispatch(batch))
             if self._finishing and self._needs_default_row():
                 return self._emit(self._default_partial_batch())
             return None
@@ -688,7 +703,6 @@ class FusedSegmentOperator(Operator):
 
     # -- host coalescing (scan-adjacent segments) ------------------------
     def _accumulate(self, batch: Batch) -> None:
-        batch = batch.to_numpy()
         n = batch.num_rows
         if self._targets is None:
             # adopt the first batch's dictionaries as the per-operator
@@ -892,9 +906,10 @@ class FusedSegmentOperator(Operator):
                 self.ctx.stats.jit_compiles += 1
             fn, out_meta = entry
             self.ctx.stats.jit_dispatches += 1
-            outs, count, parts, etotals = fn(
-                tuple(column_pairs(batch)), batch.num_rows, df_args,
-                probe_args)
+            with activity("dispatch"):
+                outs, count, parts, etotals = fn(
+                    tuple(column_pairs(batch)), batch.num_rows, df_args,
+                    probe_args)
             # expansion-overflow retry: bump the learned bucket for any
             # inner probe whose exact total exceeded its capacity and
             # re-dispatch (ops/join.py's host-retry policy, in-segment)
@@ -903,7 +918,8 @@ class FusedSegmentOperator(Operator):
                     (k for k in self._probe_idx
                      if self.stages[k].factory.join_type == "inner"),
                     etotals):
-                t = int(total)
+                with activity("device_wait"):
+                    t = int(total)
                 if t > self._out_caps[k]:
                     self._out_caps[k] = next_bucket(t)
                     lk = (self._expr_key, k, batch.capacity)
@@ -914,7 +930,8 @@ class FusedSegmentOperator(Operator):
                 break
         if self.agg_spec is not None and not self._raw_emit:
             self.ctx.stats.prereduce_rows += batch.num_rows
-        n = int(count)
+        with activity("device_wait"):
+            n = int(count)
         self._observe_reduction(batch.num_rows, n)
         if n == 0:
             return None
@@ -942,8 +959,6 @@ class FusedSegmentOperator(Operator):
             self._raw_emit = True
 
     def _compile(self, batch: Batch, df_shapes, probe_metas=()):
-        import jax
-
         # stage-by-stage expression compilation: each stage's dictionary
         # bindings are the previous stage's projection output
         # dictionaries (stage 0 binds the batch's columns)
@@ -1191,7 +1206,9 @@ class FusedSegmentOperator(Operator):
                 parts = partition_of(row_hash(triples), nparts)
             return outs, count, parts, tuple(etotals)
 
-        return jax.jit(kernel), list(final_meta)
+        name = _SEGMENT_PROGRAM[bool(self._probe_idx),
+                                agg is not None and not raw_emit]
+        return kernelcache.jit(kernel, name), list(final_meta)
 
 
 class FusedSegmentOperatorFactory(OperatorFactory):

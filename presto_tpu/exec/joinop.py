@@ -29,8 +29,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from presto_tpu import kernelcache
 from presto_tpu import types as T
 from presto_tpu.batch import Batch, Column, next_bucket
+from presto_tpu.spans import activity
 from presto_tpu.exec.context import OperatorContext
 from presto_tpu.exec.operator import (
     Operator, OperatorFactory, column_pairs, device_concat,
@@ -102,7 +104,7 @@ class SpilledLookupSource:
     mode: str = "spilled"
 
 
-@jax.jit
+@_partial(kernelcache.jit, name="join_build_index")
 def _build_index_single(kv_pair, num_rows):
     """Single-word build: ids + sorted index + the live minimum + a
     span-overflow flag, one XLA program.  Ids are (value - min + 2) so
@@ -134,7 +136,7 @@ def _build_index_single(kv_pair, num_rows):
     return sb, perm, bmin, has_null, span_big
 
 
-@jax.jit
+@_partial(kernelcache.jit, name="join_key_ranges")
 def _key_ranges(pairs, num_rows):
     """Per-key-channel live [min, max] (packed-mode ranges), one program,
     one small host transfer."""
@@ -149,7 +151,7 @@ def _key_ranges(pairs, num_rows):
     return jnp.stack(los), jnp.stack(his)
 
 
-@jax.jit
+@_partial(kernelcache.jit, name="join_build_index")
 def _build_index_packed(pairs, mins, strides, num_rows):
     """Packed multi-key build: mixed-radix ids + sorted index."""
     from presto_tpu.ops import join as J
@@ -189,10 +191,11 @@ def _pages_hash_build_jit(key_pairs, key_types, num_rows, table_cap: int):
                   for i in range(len(key_types))]
             return pages_hash_build(kc, n, table_cap)
 
-        hit = jax.jit(kernel)
+        hit = kernelcache.jit(kernel, "join_build_hash")
         cache_put(_PAGES_BUILD, key, hit)
-    return hit(tuple(v for v, _ in key_pairs),
-               tuple(v for _, v in key_pairs), num_rows)
+    with activity("dispatch"):
+        return hit(tuple(v for v, _ in key_pairs),
+                   tuple(v for _, v in key_pairs), num_rows)
 
 
 class HashBuildOperator(Operator):
@@ -312,18 +315,23 @@ class HashBuildOperator(Operator):
             # one scalar sync guards the id arithmetic: a live key spread
             # >= 2^62 would overflow the (value - min + 2) ids, silently
             # dropping matches — such builds take the canonical path
-            sb, perm, bmin, has_null, span_big = _build_index_single(
-                key_pairs[0], n)
-            if not bool(span_big):
+            with activity("dispatch"):
+                sb, perm, bmin, has_null, span_big = _build_index_single(
+                    key_pairs[0], n)
+            with activity("device_wait"):
+                span_big = bool(span_big)
+            if not span_big:
                 self.f.lookup.set(LookupSource(
                     "single", sb, perm, data, n_build, chans, mins=bmin,
                     has_null_key=has_null))
                 return
         if all(_is_single_word_type(data.columns[c].type) for c in chans):
             # pack multi-channel integer keys using build-side ranges
-            los, his = _key_ranges(key_pairs, n)        # one host sync
-            los = np.asarray(los)
-            his = np.asarray(his)
+            with activity("dispatch"):
+                los, his = _key_ranges(key_pairs, n)
+            with activity("device_wait"):               # one host sync
+                los = np.asarray(los)
+                his = np.asarray(his)
             empty = bool((los > his).any())             # no live rows
             if empty:
                 los = np.zeros_like(los)
@@ -335,8 +343,10 @@ class HashBuildOperator(Operator):
                 span_product *= int(hi - lo + 1)
             if span_product < (1 << 62):
                 strides_a = np.asarray(strides, np.int64)
-                sb, perm, has_null = _build_index_packed(
-                    key_pairs, jnp.asarray(los), jnp.asarray(strides_a), n)
+                with activity("dispatch"):
+                    sb, perm, has_null = _build_index_packed(
+                        key_pairs, jnp.asarray(los),
+                        jnp.asarray(strides_a), n)
                 self.f.lookup.set(LookupSource(
                     "packed", sb, perm, data, n_build, chans,
                     mins=los, strides=strides_a, maxs=his,
@@ -363,11 +373,15 @@ class HashBuildOperator(Operator):
         ktypes = tuple(data.columns[c].type for c in chans)
         (tw, tp, tu, starts, counts, perm, has_null,
          ok) = _pages_hash_build_jit(key_pairs, ktypes, n, table_cap)
-        if not bool(ok):
+        with activity("device_wait"):
+            placed = bool(ok)
+        if not placed:
             (tw, tp, tu, starts, counts, perm, has_null,
              ok) = _pages_hash_build_jit(key_pairs, ktypes, n,
                                          4 * table_cap)
-        if not bool(ok):
+            with activity("device_wait"):
+                placed = bool(ok)
+        if not placed:
             return False
         self.ctx.stats.kernel_tier = "hash"
         self.f.lookup.set(LookupSource(
@@ -467,8 +481,9 @@ def _hash_lo_counts(probe_pairs, pages, key_channels, key_types,
     return pages_hash_probe(pages, kc, num_rows)
 
 
-@_partial(jax.jit, static_argnames=("key_channels", "mode", "join_type",
-                                    "key_types"))
+@_partial(kernelcache.jit, name="join_probe_count",
+          static_argnames=("key_channels", "mode", "join_type",
+                           "key_types"))
 def _probe_expand_total(probe_pairs, sorted_ids, perm, mins, strides,
                         maxs, pages, num_rows, *, key_channels, mode,
                         join_type, key_types=()):
@@ -490,7 +505,7 @@ def _probe_expand_total(probe_pairs, sorted_ids, perm, mins, strides,
     return counts.sum()
 
 
-@_partial(jax.jit, static_argnames=("s",))
+@_partial(kernelcache.jit, name="join_probe", static_argnames=("s",))
 def _stream_probe(probe_pairs, build_pairs, sorted_ids, perm, mins,
                   strides, maxs, pages, num_rows, bstats, *,
                   s: _StreamStatics):
@@ -635,12 +650,16 @@ class LookupJoinOperator(Operator):
         cres = self._residual_compiled(batch, src)
         while True:
             kernel = self._kernel(src, cap, out_cap, cres)
-            outs, count, expand_total = kernel(
-                tuple(column_pairs(batch)), tuple(column_pairs(src.data)), n)
-            total = int(count)
-            if int(expand_total) <= out_cap:
+            with activity("dispatch"):
+                outs, count, expand_total = kernel(
+                    tuple(column_pairs(batch)),
+                    tuple(column_pairs(src.data)), n)
+            with activity("device_wait"):
+                total = int(count)
+                expand_total = int(expand_total)
+            if expand_total <= out_cap:
                 break
-            out_cap = next_bucket(int(expand_total))
+            out_cap = next_bucket(expand_total)
         cols = []
         probe_cols = [batch.columns[i] for i in range(batch.num_columns)]
         if join_type in ("semi", "anti"):
@@ -686,10 +705,13 @@ class LookupJoinOperator(Operator):
         if join_type in ("semi", "anti"):
             out_cap = 0
         else:
-            etotal = int(_probe_expand_total(
-                probe_pairs, src.sorted_ids, src.perm, mins, strides, maxs,
-                src.pages, n, key_channels=kc, mode=src.mode,
-                join_type=join_type, key_types=key_types))
+            with activity("dispatch"):
+                etotal = _probe_expand_total(
+                    probe_pairs, src.sorted_ids, src.perm, mins, strides,
+                    maxs, src.pages, n, key_channels=kc, mode=src.mode,
+                    join_type=join_type, key_types=key_types)
+            with activity("device_wait"):
+                etotal = int(etotal)
             out_cap = next_bucket(max(etotal, 1))
         s = _StreamStatics(src.mode, join_type, kc, out_cap,
                            batch.num_columns, self.f.null_aware,
@@ -697,13 +719,18 @@ class LookupJoinOperator(Operator):
         bstats = (jnp.asarray(src.n_build, jnp.int64),
                   src.has_null_key if src.has_null_key is not None
                   else jnp.zeros((), bool))
-        outs, count, _ = _stream_probe(
-            probe_pairs, build_pairs, src.sorted_ids, src.perm, mins,
-            strides, maxs, src.pages, n, bstats, s=s)
+        with activity("dispatch"):
+            outs, count, _ = _stream_probe(
+                probe_pairs, build_pairs, src.sorted_ids, src.perm, mins,
+                strides, maxs, src.pages, n, bstats, s=s)
         # expansion joins already synced the exact total in phase 1; only
         # semi/anti need to read the selected count (every host read is
         # a device sync)
-        total = etotal if join_type not in ("semi", "anti") else int(count)
+        if join_type in ("semi", "anti"):
+            with activity("device_wait"):
+                total = int(count)
+        else:
+            total = etotal
         cols = []
         probe_cols = [batch.columns[i] for i in range(batch.num_columns)]
         if join_type in ("semi", "anti"):
@@ -811,7 +838,7 @@ class LookupJoinOperator(Operator):
                 outs.append((v[bi], bvalid))
             return tuple(outs), total, total
 
-        jitted = jax.jit(kernel)
+        jitted = kernelcache.jit(kernel, "join_probe_residual")
         self._kernels[key] = jitted
         return jitted
 

@@ -24,10 +24,12 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from presto_tpu.batch import Batch, Column, next_bucket
+from presto_tpu import kernelcache
 from presto_tpu.exec.context import OperatorContext
 from presto_tpu.kernelcache import (
     cache_get, cache_put, new_cache, timed_first_call,
 )
+from presto_tpu.spans import activity
 
 
 class Operator:
@@ -117,7 +119,9 @@ def rebucket(batch: Batch, min_capacity: int = 1024) -> Batch:
 def pad_batch(batch: Batch, min_capacity: int = 1024) -> Batch:
     """Pad to the power-of-two bucket and move to device."""
     cap = next_bucket(batch.num_rows, min_capacity)
-    return batch.pad_rows(cap).to_device()
+    with activity("stage_h2d"):
+        padded = batch.pad_rows(cap)
+    return padded.to_device()
 
 
 def device_concat(batches: Sequence[Batch], min_capacity: int = 1024) -> Batch:
@@ -141,7 +145,9 @@ def device_concat(batches: Sequence[Batch], min_capacity: int = 1024) -> Batch:
     out = _device_append(live, min_capacity)
     if out is not None:
         return out
-    return pad_batch(concat_batches(live), min_capacity)
+    with activity("stage_h2d"):
+        joined = concat_batches(live)
+    return pad_batch(joined, min_capacity)
 
 
 _APPEND_PROGRAMS = new_cache("device_concat")
@@ -151,7 +157,6 @@ def _device_append(live: Sequence[Batch],
                    min_capacity: int) -> Optional[Batch]:
     """The device half of device_concat; None when an input needs the
     host path."""
-    import jax
     import jax.numpy as jnp
 
     first = live[0]
@@ -185,10 +190,13 @@ def _device_append(live: Sequence[Batch],
         program = cache_get(_APPEND_PROGRAMS, key)
         if program is None:
             program = timed_first_call(
-                jax.jit(_append_kernel, donate_argnums=0), None,
+                kernelcache.jit(_append_kernel, "device_append",
+                                donate_argnums=0), None,
                 _APPEND_PROGRAMS)
             cache_put(_APPEND_PROGRAMS, key, program)
-        outs = program(outs, ins, np.int32(offset), np.int32(b.num_rows))
+        with activity("dispatch"):
+            outs = program(outs, ins, np.int32(offset),
+                           np.int32(b.num_rows))
         offset += b.num_rows
     return Batch(tuple(Column(c.type, values, valid, c.dictionary)
                        for c, (values, valid) in zip(first.columns, outs)),

@@ -11,6 +11,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from presto_tpu import kernelcache
 from presto_tpu import types as T
 from presto_tpu.batch import Batch, Column, next_bucket
 from presto_tpu.connectors.api import Connector, Split
@@ -19,6 +20,7 @@ from presto_tpu.exec.operator import (
     Operator, OperatorFactory, SourceOperator, column_pairs, pad_batch,
 )
 from presto_tpu.expr.compile import ExprCompiler
+from presto_tpu.spans import activity
 from presto_tpu.expr.ir import RowExpression
 
 
@@ -55,7 +57,8 @@ class TableScanOperator(SourceOperator):
                 self._iter = iter(self.connector.page_source(
                     split, self.columns, self.batch_rows))
             try:
-                batch = next(self._iter)
+                with activity("generate"):
+                    batch = next(self._iter)
             except StopIteration:
                 self._iter = None
                 continue
@@ -184,8 +187,6 @@ class FilterProjectOperator(Operator):
         self.ctx.stats.input_rows += batch.num_rows
 
     def _kernel_for(self, batch: Batch):
-        import jax
-
         dict_key = dictionary_binding_key(batch.columns)
         key = (self._expr_key, batch.capacity, dict_key)
         hit = _cache_get(_FP_KERNELS, key)
@@ -224,8 +225,8 @@ class FilterProjectOperator(Operator):
         build_ns = _time.perf_counter_ns() - _t0
         self.ctx.stats.jit_compile_ns += build_ns
         _record_compile(_FP_KERNELS, build_ns)
-        entry = (_timed_first_call(jax.jit(kernel), self.ctx.stats,
-                                   _FP_KERNELS), cprojs)
+        entry = (_timed_first_call(kernelcache.jit(kernel, "filter_project"),
+                                   self.ctx.stats, _FP_KERNELS), cprojs)
         _cache_put(_FP_KERNELS, key, entry)
         return entry
 
@@ -277,8 +278,11 @@ class FilterProjectOperator(Operator):
         else:
             jitted, cprojs = self._kernel_for(batch)
             self.ctx.stats.jit_dispatches += 1
-            outs, count = jitted(tuple(column_pairs(batch)), batch.num_rows)
-            n = int(count)
+            with activity("dispatch"):
+                outs, count = jitted(tuple(column_pairs(batch)),
+                                     batch.num_rows)
+            with activity("device_wait"):
+                n = int(count)
             cols = tuple(
                 Column(p.type, v, valid, p.dictionary)
                 for p, (v, valid) in zip(cprojs, outs))

@@ -29,6 +29,7 @@ from presto_tpu.batch import Batch, Column, next_bucket
 from presto_tpu.exec.aggregation import AggChannel
 from presto_tpu.exec.context import OperatorContext
 from presto_tpu.exec.operator import Operator, OperatorFactory
+from presto_tpu.spans import activity
 
 
 class StreamingAggregationOperator(Operator):
@@ -68,7 +69,9 @@ class StreamingAggregationOperator(Operator):
                                 minimum=16)
         gi, ng, results = clustered_aggregate_jit(
             key_triples, agg_ins, jnp.asarray(data.num_rows), group_cap)
-        return key_cols, gi, int(ng), results, group_cap
+        with activity("device_wait"):
+            ng = int(ng)
+        return key_cols, gi, ng, results, group_cap
 
     # -- carry merge (the combine rule per primitive) --------------------
     @staticmethod

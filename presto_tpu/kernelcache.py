@@ -11,6 +11,14 @@ default capacity is configurable through ``EngineConfig
 .kernel_cache_capacity`` (applied by ``execute_pipelines`` at query
 start; caches are process-global so the knob is a process default, not a
 per-query isolation boundary).
+
+Two more things every compiled program passes through here.  ``jit`` is
+the one way a function under ``presto_tpu/`` becomes a jitted program,
+under a name from ``PROGRAM_NAMES``, so a device trace reads
+``jit_join_probe(...)`` and not ``jit_kernel(...)``.  And the module
+listens to JAX's own compile events (``jax.monitoring``) and charges each
+to the task whose thread built the program (``spans.current_activity``):
+the account that sees what XLA sees (``TaskStats.xla_builds`` ...).
 """
 
 from __future__ import annotations
@@ -19,6 +27,8 @@ import threading
 from collections import OrderedDict
 from typing import Dict
 
+from presto_tpu.spans import current_activity
+
 _LOCK = threading.Lock()
 
 # process default for cache_put(cap=None); EngineConfig.kernel_cache_capacity
@@ -26,6 +36,46 @@ _LOCK = threading.Lock()
 _DEFAULT_CAPACITY = 256
 
 _REGISTRY: Dict[str, "KernelCache"] = {}
+
+#: the name of every kind of jitted program, one per kind and never per
+#: query: XLA names the module ``jit_<name>``, and that is what a device
+#: trace and the benchmark's ``breakdown.device_ops`` show.  Whatever a
+#: trace still names ``jit_<primitive>`` (``jit_scatter-add`` ...) is an
+#: eager dispatch outside any of these.
+PROGRAM_NAMES = (
+    "fused_segment",        # exec/fusion.py: one program per segment,
+    "fused_segment_probe",  #   named by what it absorbed: join probes,
+    "fused_segment_agg",    #   a partial aggregation,
+    "fused_segment_probe_agg",  # or both
+    "filter_project",       # exec/operators.py
+    "dynamic_filter",       # exec/dynamicfilter.py
+    "join_build_index",     # exec/joinop.py: sorted build-side key index
+    "join_key_ranges",      #   build-side [min, max] per key channel
+    "join_build_hash",      #   open-addressing table over the build pages
+    "join_probe_count",     #   match total before an expanding probe
+    "join_probe",           #   the streaming probe, every tier
+    "join_probe_residual",  #   probe with a residual filter fused in
+    "groupby_sort",         # ops/groupby.py: lexsort + segment reduce
+    "groupby_clustered",    #   clustered (streaming) keys
+    "groupby_hash",         #   open-addressing table update
+    "groupby_rehash",       #   rehash of a grown table
+    "aggregate_global",     #   no keys
+    "sort",                 # ops/sort.py
+    "device_append",        # exec/operator.py: device_concat
+    "mesh_step",            # parallel/steps.py
+    "mesh_program",         # parallel/sqlmesh.py: the SPMD query program
+    "mesh_slice",           #   cut of a device-resident scan input
+)
+
+
+def jit(fn, name: str, **jit_kwargs):
+    """``jax.jit(fn, **jit_kwargs)`` under a stable program name."""
+    import jax
+
+    if name not in PROGRAM_NAMES:
+        raise ValueError(f"{name!r} is not in kernelcache.PROGRAM_NAMES")
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kwargs)
 
 
 class KernelCache(OrderedDict):
@@ -144,3 +194,60 @@ def cache_stats() -> Dict[str, Dict[str, int]]:
                        "compiles": c.compiles,
                        "compile_ns": c.compile_ns}
                 for name, c in sorted(_REGISTRY.items())}
+
+
+# ---------------------------------------------------------------------------
+# The compile account: what XLA built, charged to the task that built it
+# ---------------------------------------------------------------------------
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BUILD_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+#: builds on threads that work for no task (planner constant folding,
+#: the collective plane's program, tests calling kernels bare):
+#: /v1/metrics reports them beside the kernel-cache families
+_PROCESS_XLA = {"builds": 0, "build_ns": 0, "trace_lower_ns": 0,
+                "cache_hits": 0}
+
+
+def _xla_account() -> Dict[str, int]:
+    recorder = current_activity()
+    return _PROCESS_XLA if recorder is None else recorder.xla
+
+
+def _on_xla_duration(event: str, secs: float, **_kw) -> None:
+    # JAX calls this on the thread that builds.  A "build" is
+    # compile_or_get_cached: a load from the persistent cache counts,
+    # with the seconds the load took.
+    if event == _BUILD_EVENT:
+        with _LOCK:
+            account = _xla_account()
+            account["builds"] += 1
+            account["build_ns"] += int(secs * 1e9)
+    elif event == _TRACE_EVENT or event == _LOWER_EVENT:
+        with _LOCK:
+            _xla_account()["trace_lower_ns"] += int(secs * 1e9)
+
+
+def _on_xla_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        with _LOCK:
+            _xla_account()["cache_hits"] += 1
+
+
+def process_xla_stats() -> Dict[str, int]:
+    """XLA builds charged to no task, since the process started."""
+    with _LOCK:
+        return dict(_PROCESS_XLA)
+
+
+def _listen_to_xla() -> None:
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_xla_duration)
+    jax.monitoring.register_event_listener(_on_xla_event)
+
+
+_listen_to_xla()     # once per process: at import

@@ -792,7 +792,9 @@ class LocalQueryRunner:
                 f"{s.jit_compile_ns / 1e6:>10.1f} "
                 f"{s.jit_dispatches:>8} {s.jit_compiles:>8} "
                 f"{s.prereduce_rows:>9}")
-        from presto_tpu.exec.context import hot_operator_lines
+        from presto_tpu.exec.context import (
+            host_and_xla_line, hot_operator_lines,
+        )
 
         lines.extend(hot_operator_lines([
             dict(s.as_dict(),
@@ -805,6 +807,7 @@ class LocalQueryRunner:
             f"compiles: {jc['compiles']} "
             f"({jc['compile_ns'] / 1e6:.1f} ms compile); "
             f"prereduce rows: {jc['prereduce_rows']}")
+        lines.append(host_and_xla_line(task.task_stats().as_dict()))
         # queued-vs-execution split: same footer shape as the
         # distributed tier's _render_analyze (the single-process runner
         # executes synchronously — queued is always 0)

@@ -526,18 +526,22 @@ def global_aggregate(aggs: Sequence[AggIn], num_rows: jax.Array,
 # kernel and share the compiled program across queries
 # (AccumulatorCompiler cache role).
 
+from presto_tpu import kernelcache
 from presto_tpu.kernelcache import cache_get, cache_put, new_cache
+from presto_tpu.spans import activity
 
 _AGG_PROGRAMS = new_cache("aggregation")
 
 
-def _program(key, build):
-    hit = cache_get(_AGG_PROGRAMS, key)
-    if hit is not None:
-        return hit
-    fn = build()
-    cache_put(_AGG_PROGRAMS, key, fn)
-    return fn
+def _dispatch(key, build, *args):
+    """The one call of an aggregation program: ``build()`` on a cache
+    miss, then the program over ``args``."""
+    fn = cache_get(_AGG_PROGRAMS, key)
+    if fn is None:
+        fn = build()
+        cache_put(_AGG_PROGRAMS, key, fn)
+    with activity("dispatch"):
+        return fn(*args)
 
 
 def grouped_aggregate_jit(key_columns, aggs, num_rows,
@@ -559,13 +563,13 @@ def grouped_aggregate_jit(key_columns, aggs, num_rows,
                   for i in range(len(prims))]
             return grouped_aggregate(kc, ag, n, group_capacity)
 
-        return jax.jit(kernel)
+        return kernelcache.jit(kernel, "groupby_sort")
 
-    fn = _program(key, build)
-    return fn(tuple(v for v, _, _ in key_columns),
-              tuple(v for _, v, _ in key_columns),
-              tuple(v for _, v, _ in aggs),
-              tuple(v for _, _, v in aggs), num_rows)
+    return _dispatch(
+        key, build, tuple(v for v, _, _ in key_columns),
+        tuple(v for _, v, _ in key_columns),
+        tuple(v for _, v, _ in aggs),
+        tuple(v for _, _, v in aggs), num_rows)
 
 
 def clustered_aggregate_jit(key_columns, aggs, num_rows,
@@ -587,13 +591,13 @@ def clustered_aggregate_jit(key_columns, aggs, num_rows,
                   for i in range(len(prims))]
             return clustered_aggregate(kc, ag, n, group_capacity)
 
-        return jax.jit(kernel)
+        return kernelcache.jit(kernel, "groupby_clustered")
 
-    fn = _program(key, build)
-    return fn(tuple(v for v, _, _ in key_columns),
-              tuple(v for _, v, _ in key_columns),
-              tuple(v for v, _ in [(a[1], a[2]) for a in aggs]),
-              tuple(v for _, v in [(a[1], a[2]) for a in aggs]), num_rows)
+    return _dispatch(
+        key, build, tuple(v for v, _, _ in key_columns),
+        tuple(v for _, v, _ in key_columns),
+        tuple(v for v, _ in [(a[1], a[2]) for a in aggs]),
+        tuple(v for _, v in [(a[1], a[2]) for a in aggs]), num_rows)
 
 
 def hash_groupby_update_jit(state, key_columns, aggs, num_rows,
@@ -624,14 +628,14 @@ def hash_groupby_update_jit(state, key_columns, aggs, num_rows,
                   for i in range(len(prims))]
             return groupby_update(st, kc, ag, n, live_mask=lm)
 
-        return jax.jit(kernel)
+        return kernelcache.jit(kernel, "groupby_hash")
 
-    fn = _program(key, build)
-    return fn(state,
-              tuple(v for v, _, _ in key_columns),
-              tuple(v for _, v, _ in key_columns),
-              tuple(v for _, v, _ in aggs),
-              tuple(v for _, _, v in aggs), num_rows, live_mask)
+    return _dispatch(
+        key, build, state,
+        tuple(v for v, _, _ in key_columns),
+        tuple(v for _, v, _ in key_columns),
+        tuple(v for _, v, _ in aggs),
+        tuple(v for _, _, v in aggs), num_rows, live_mask)
 
 
 def hash_groupby_rehash_jit(state, new_cap: int, prims=()):
@@ -652,9 +656,9 @@ def hash_groupby_rehash_jit(state, new_cap: int, prims=()):
 
             return groupby_rehash(st, new_cap, prims)
 
-        return jax.jit(kernel)
+        return kernelcache.jit(kernel, "groupby_rehash")
 
-    return _program(key, build)(state)
+    return _dispatch(key, build, state)
 
 
 def global_aggregate_jit(aggs, num_rows):
@@ -670,8 +674,8 @@ def global_aggregate_jit(aggs, num_rows):
                   for i in range(len(prims))]
             return global_aggregate(ag, n)
 
-        return jax.jit(kernel)
+        return kernelcache.jit(kernel, "aggregate_global")
 
-    fn = _program(key, build)
-    return fn(tuple(v for _, v, _ in aggs),
-              tuple(v for _, _, v in aggs), num_rows)
+    return _dispatch(
+        key, build, tuple(v for _, v, _ in aggs),
+        tuple(v for _, _, v in aggs), num_rows)

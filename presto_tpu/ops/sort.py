@@ -15,7 +15,9 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from presto_tpu import kernelcache
 from presto_tpu import types as T
+from presto_tpu.spans import activity
 from presto_tpu.kernelcache import (
     cache_get, cache_put, new_cache, timed_first_call,
 )
@@ -49,11 +51,14 @@ def sort_permutation(keys: Sequence[SortKey], num_rows: jax.Array) -> jax.Array:
     radix = use_radix()
     program = cache_get(_SORT_PROGRAMS, (spec, radix))
     if program is None:
-        program = timed_first_call(jax.jit(_sort_kernel(spec, radix)), None,
-                                   _SORT_PROGRAMS)
+        program = timed_first_call(
+            kernelcache.jit(_sort_kernel(spec, radix), "sort"), None,
+            _SORT_PROGRAMS)
         cache_put(_SORT_PROGRAMS, (spec, radix), program)
-    return program(tuple(values for values, *_ in keys),
-                   tuple(valid for _values, valid, *_ in keys), num_rows)
+    with activity("dispatch"):
+        return program(tuple(values for values, *_ in keys),
+                       tuple(valid for _values, valid, *_ in keys),
+                       num_rows)
 
 
 def _sort_kernel(spec, radix: bool):

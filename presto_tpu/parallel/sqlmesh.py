@@ -720,7 +720,7 @@ class _MeshProgram:
             # attributed to the shared "mesh_program" cache through
             # timed_first_call (the CacheStatsMBean role).
             t0 = _time.time()
-            lowered = jax.jit(mapped).lower(*self._args)
+            lowered = _kc.jit(mapped, "mesh_program").lower(*self._args)
             t1 = _time.time()
             cstats = OperatorStats(operator="mesh_program")
             self._jitted = timed_first_call(
@@ -817,7 +817,7 @@ class _MeshProgram:
                 return tuple(jnp.stack([arrs[i][:cap][perm] for i in idxs])
                              for _, idxs in layout)
 
-            fn = jax.jit(slicer)
+            fn = _kc.jit(slicer, "mesh_slice")
             self._slicers[(bucket, layout)] = fn
         stacked = [np.asarray(a) for a in fn(tuple(arrays), out[-5])]
         host: List[Optional[np.ndarray]] = [None] * len(arrays)

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from presto_tpu import kernelcache
 from presto_tpu import types as T
 from presto_tpu.ops import join as J
 from presto_tpu.ops.groupby import grouped_aggregate
@@ -279,4 +280,4 @@ def jit_step(mesh, shard_fn, in_specs, out_specs):
     """shard_map + jit a step built by one of the factories above."""
     mapped = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)
-    return jax.jit(mapped)
+    return kernelcache.jit(mapped, "mesh_step")

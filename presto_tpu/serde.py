@@ -38,6 +38,7 @@ import numpy as np
 from presto_tpu import native
 from presto_tpu import types as T
 from presto_tpu.batch import Batch, Column, Dictionary
+from presto_tpu.spans import activity
 
 # Deserialized dictionaries interned process-wide by CONTENT: kernel
 # caches key programs on the dictionary binding (token, length), so a
@@ -121,11 +122,8 @@ def _encode_column(parts: List[bytes], col: Column, num_rows: int,
 
 
 def _encode_payload(batch: Batch) -> bytes:
-    # host first, then drop the padding: compact() on device arrays is an
-    # eager slice whose static shape is the row count, i.e. one XLA program
-    # per distinct (capacity, rows) pair — TPC-H Q3 at SF1 compiled ~700
-    # such programs per cold run through the output operators (PR 25)
-    batch = batch.to_numpy().compact()
+    """``batch`` is on the host (serialize_batch brought it there)."""
+    batch = batch.compact()
     parts: List[bytes] = []
     for col in batch.columns:
         _encode_column(parts, col, batch.num_rows, with_type=True)
@@ -133,22 +131,31 @@ def _encode_payload(batch: Batch) -> bytes:
 
 
 def serialize_batch(batch: Batch, compress: bool = True) -> bytes:
-    payload = _encode_payload(batch)
-    raw_size = len(payload)
-    flags = 0
-    checksum = 0
-    if compress and native.available():
-        compressed = native.lz4_compress(payload)
-        # Keep the compressed form only when it actually wins (the
-        # reference does the same ratio check in PagesSerde.serialize).
-        if len(compressed) < raw_size:
-            payload = compressed
-            flags |= FLAG_LZ4
-    if native.available():
-        checksum = native.xxh64(payload)
-    header = _HEADER.pack(MAGIC, VERSION, flags, batch.num_columns,
-                          batch.num_rows, raw_size, len(payload), checksum)
-    return header + payload
+    # host first, then drop the padding: compact() on device arrays is an
+    # eager slice whose static shape is the row count, i.e. one XLA program
+    # per distinct (capacity, rows) pair — TPC-H Q3 at SF1 compiled ~700
+    # such programs per cold run through the output operators (PR 25).
+    # Coming to the host is a device_wait of its own, if anything waits.
+    batch = batch.to_numpy()
+    with activity("serialize"):
+        payload = _encode_payload(batch)
+        raw_size = len(payload)
+        flags = 0
+        checksum = 0
+        if compress and native.available():
+            compressed = native.lz4_compress(payload)
+            # Keep the compressed form only when it actually wins (the
+            # reference does the same ratio check in
+            # PagesSerde.serialize).
+            if len(compressed) < raw_size:
+                payload = compressed
+                flags |= FLAG_LZ4
+        if native.available():
+            checksum = native.xxh64(payload)
+        header = _HEADER.pack(MAGIC, VERSION, flags, batch.num_columns,
+                              batch.num_rows, raw_size, len(payload),
+                              checksum)
+        return header + payload
 
 
 class SerdeError(ValueError):
@@ -156,6 +163,11 @@ class SerdeError(ValueError):
 
 
 def deserialize_batch(data: bytes) -> Batch:
+    with activity("serialize"):     # the consumer's half of the kind
+        return _deserialize_batch(data)
+
+
+def _deserialize_batch(data: bytes) -> Batch:
     if len(data) < _HEADER.size:
         raise SerdeError("truncated frame header")
     (magic, version, flags, num_columns, num_rows, raw_size, payload_size,

@@ -43,6 +43,7 @@ from presto_tpu.server.errortracker import (
     RemoteRequestError, RequestErrorTracker,
 )
 from presto_tpu.server.fragmenter import DistributedPlan, Fragmenter
+from presto_tpu.spans import HOST_ACTIVITY_HEADER
 from presto_tpu.sql import tree as t
 from presto_tpu.sql.optimizer import optimize
 from presto_tpu.sql.parser import parse_statement
@@ -834,7 +835,7 @@ class QueryExecution:
         self.end_time = ev.now()
         qs = self.query_stats or {}
         try:
-            spans = self.spans()
+            spans = self.spans(activities=False)
         except Exception:  # noqa: BLE001 - observability never fails
             spans = {}
         self.co.event_bus.query_completed(ev.QueryCompletedEvent(
@@ -2001,14 +2002,20 @@ class QueryExecution:
 
     def _fetch_task_infos(self, placements,
                           join_timeout_s: float = 15.0,
-                          request_timeout_s: float = 10.0
+                          request_timeout_s: float = 10.0,
+                          activity: bool = False
                           ) -> Dict[int, List[Dict]]:
         """Fetch task info for every placement, one thread per worker so
         one hung worker costs exactly one timeout (never the whole
         sweep); budget 0 per request, best-effort per task.  Shared by
         the final post-drain collection and the live sampler (which
         passes a tighter timeout so one hung worker costs one sample).
+        ``activity`` asks for each task's host-activity intervals too
+        (the final collection only: they can be 4096 to a task).
         spool:// placements have no task to report."""
+        headers = self._internal_headers()
+        if activity:
+            headers[HOST_ACTIVITY_HEADER] = "1"
         by_uri: Dict[str, List[Tuple[int, str]]] = {}
         for fid, tid, uri in placements:
             if uri.startswith("spool://"):
@@ -2021,8 +2028,7 @@ class QueryExecution:
             for fid, tid in tasks:
                 try:
                     resp = self.co.http.request(
-                        f"{uri}/v1/task/{tid}",
-                        headers=self._internal_headers(),
+                        f"{uri}/v1/task/{tid}", headers=dict(headers),
                         timeout=request_timeout_s, task_id=tid,
                         description="task status",
                         trace_token=self.trace_token,
@@ -2097,7 +2103,7 @@ class QueryExecution:
         self._stats_collected = True
         with self._recovery_lock:
             placements = list(self._placements)
-        infos = self._fetch_task_infos(placements)
+        infos = self._fetch_task_infos(placements, activity=True)
         cfg = getattr(self, "_cfg", None) or self.co.config
         with self._stats_lock:
             self._task_infos = infos
@@ -2231,21 +2237,30 @@ class QueryExecution:
 
         return cm()
 
-    def spans(self) -> Dict:
+    def spans(self, activities: bool = True) -> Dict:
         """The timed span tree: query -> coordinator phases -> per-stage
-        -> per-task-attempt, from coordinator-owned timestamps plus the
-        task-info start/end lifecycle (live sampler mid-query, final
-        rollup after)."""
+        -> per-task-attempt -> host activity, from coordinator-owned
+        timestamps plus the task infos (start/end lifecycle, operator
+        stats, and a finished task's activity intervals; live sampler
+        mid-query, final rollup after).  ``activities=False`` leaves the
+        intervals out and keeps their totals: the completed event's
+        copy."""
         from presto_tpu.spans import build_span_tree
 
         with self._stats_lock:
             task_stats = {fid: [dict(ts) for ts in lst]
                           for fid, lst in self.task_stats.items()}
+            task_extras = {
+                info.get("taskId"): {
+                    "operators": info.get("operatorStats"),
+                    "activity": info.get("hostActivity")}
+                for infos in self._task_infos.values() for info in infos}
             marks = dict(self._marks)
         return build_span_tree(
             self.query_id, self.trace_token, self.create_time,
             self.end_time, marks, task_stats,
-            admit_time=self.admit_time)
+            admit_time=self.admit_time, task_extras=task_extras,
+            activities=activities)
 
     def _top_operator(self) -> str:
         """Name of the hottest operator by exclusive wall across every
@@ -2268,8 +2283,10 @@ class QueryExecution:
         set as the local tier's explain_analyze_text — jit dispatches/
         compiles, pre-reduce rows, peak memory — so the two tiers stay
         diffable."""
-        from presto_tpu.exec.context import hot_operator_lines as \
-            _hot_operator_lines
+        from presto_tpu.exec.context import (
+            host_and_xla_line as _host_and_xla_line,
+            hot_operator_lines as _hot_operator_lines,
+        )
         from presto_tpu.sql.plan import format_plan
 
         self._collect_stats()
@@ -2358,6 +2375,7 @@ class QueryExecution:
                 f" ms execute); "
                 f"prereduce rows: {qs['prereduce_rows']}; "
                 f"trace token: {self.trace_token}")
+            lines.append(_host_and_xla_line(qs))
             lines.append(
                 f"serving: queued {qs.get('queued_s', 0.0):.3f} s, "
                 f"execution {qs.get('execution_s', 0.0):.3f} s"

@@ -26,6 +26,7 @@ from presto_tpu.exec.context import OperatorContext
 from presto_tpu.exec.operator import Operator, OperatorFactory
 from presto_tpu.serde import deserialize_batch, frame_size, serialize_batch
 from presto_tpu.server.buffers import OutputBufferManager
+from presto_tpu.spans import activity
 from presto_tpu.server.errortracker import (
     RemoteRequestError, RetryingHttpClient,
 )
@@ -65,15 +66,18 @@ class PartitionedOutputOperator(Operator):
             self.ctx.stats.output_rows += batch.num_rows
             return
         if self.precomputed:
-            parts = np.asarray(parts_col.values)[:batch.num_rows]
+            parts = parts_col.values
         else:
             # hash at the padded capacity (bucketed shapes, so a bounded
             # set of programs) and cut the ids on the host
             key_cols = [value_hash_triple(batch.columns[c])
                         for c in self.channels]
-            hashes = row_hash(key_cols)
-            parts = np.asarray(
-                partition_of(hashes, self.n))[:batch.num_rows]
+            parts = partition_of(row_hash(key_cols), self.n)
+        if isinstance(parts, np.ndarray):
+            parts = parts[:batch.num_rows]
+        else:
+            with activity("device_wait"):
+                parts = np.asarray(parts)[:batch.num_rows]
         # the rows leave through the wire: stage them to the host ONCE
         # and cut the pages there.  take() on device arrays dispatches one
         # eager XLA program per distinct (rows, page rows) pair, and row
@@ -633,7 +637,8 @@ class ExchangeOperator(Operator):
             if not self.client.finished:
                 # condition-variable timed wait: wakes on page arrival
                 # instead of a fixed 2 ms timer (driver re-polls after)
-                self.client.wait_for_page()
+                with activity("exchange_wait"):
+                    self.client.wait_for_page()
             return None
         batch = deserialize_batch(page)
         self.ctx.stats.input_rows += batch.num_rows
@@ -819,7 +824,8 @@ class MergeExchangeOperator(Operator):
         if not ready:
             # park on the first stalled stream's arrival condition
             # instead of a fixed 2 ms sleep; driver re-polls after
-            self.clients[stalled].wait_for_page()
+            with activity("exchange_wait"):
+                self.clients[stalled].wait_for_page()
             return None
         out: List[tuple] = []
         while len(out) < self.batch_rows:

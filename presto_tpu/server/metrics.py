@@ -110,7 +110,7 @@ def _http_client_family(prefix: str, http) -> Family:
 
 
 def _kernel_cache_families(prefix: str) -> List[Family]:
-    from presto_tpu.kernelcache import cache_stats
+    from presto_tpu.kernelcache import cache_stats, process_xla_stats
 
     stats = cache_stats()
     fams: List[Family] = []
@@ -127,6 +127,21 @@ def _kernel_cache_families(prefix: str) -> List[Family]:
         "wall seconds spent building entries per named cache",
         [({"cache": name}, s.get("compile_ns", 0) / 1e9)
          for name, s in sorted(stats.items())]))
+    # what XLA built on threads that work for no task (a task's own
+    # builds are in its taskStats: xla_builds ...)
+    xla = process_xla_stats()
+    fams.append((
+        f"{prefix}_xla_untasked_total", "counter",
+        "XLA programs built (compiled or loaded) outside any task, "
+        "and how many of them were persistent-cache loads",
+        [({"kind": "builds"}, xla["builds"]),
+         ({"kind": "cache_hits"}, xla["cache_hits"])]))
+    fams.append((
+        f"{prefix}_xla_untasked_seconds_total", "counter",
+        "seconds outside any task inside XLA's compile-or-load, and "
+        "tracing and lowering before it",
+        [({"kind": "build"}, xla["build_ns"] / 1e9),
+         ({"kind": "trace_lower"}, xla["trace_lower_ns"] / 1e9)]))
     return fams
 
 

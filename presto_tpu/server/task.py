@@ -287,12 +287,13 @@ class SqlTask:
                     self._cache_entry is not None:
                 self._frag_cache.release(self._cache_entry)
 
-    def info(self) -> Dict:
+    def info(self, activity: bool = False) -> Dict:
         """TaskInfo with the per-operator stats rollup the coordinator's
         distributed EXPLAIN ANALYZE aggregates (TaskStatus + TaskStats,
-        presto-main/.../execution/TaskInfo.java role)."""
-        from presto_tpu.kernelcache import cache_stats
-
+        presto-main/.../execution/TaskInfo.java role).  ``activity``
+        adds ``hostActivity``, the intervals behind
+        ``taskStats.host_ns``: the coordinator's one final collection
+        asks for it, the live sampler's polls do not."""
         ctx = self._stats or self._live
         stats = ([s.as_dict() for s in ctx.operator_stats]
                  if ctx is not None else [])
@@ -300,12 +301,9 @@ class SqlTask:
         for source in self.exchange_sources:
             if hasattr(source, "source_stats"):
                 exchange_stats.update(source.source_stats())
-        return {"taskId": self.task_id, "state": self.state,
+        info = {"taskId": self.task_id, "state": self.state,
                 "error": self.error, "operatorStats": stats,
                 "traceToken": self.trace_token,
-                "jitCounters": (ctx.jit_counters() if ctx is not None
-                                else {"dispatches": 0, "compiles": 0}),
-                "kernelCaches": cache_stats(),
                 # producer progress + drain state for the coordinator's
                 # straggler detector, and the attempt-aware exchange
                 # dedup counters (whole-stage retry observability)
@@ -318,12 +316,12 @@ class SqlTask:
                 "exchangeSources": exchange_stats,
                 # the TaskStats rollup the coordinator aggregates into
                 # StageStats/QueryStats (distributed EXPLAIN ANALYZE,
-                # /v1/query detail, events, system.runtime), plus the
-                # per-pipeline DriverStats level below it
+                # /v1/query detail, events, system.runtime)
                 "taskStats": self.task_stats(),
-                "driverStats": ([d.as_dict() for d in ctx.driver_stats]
-                                if ctx is not None else []),
                 "peakMemory": ctx.memory.peak if ctx is not None else 0}
+        if activity and ctx is not None:
+            info["hostActivity"] = ctx.activity.as_dict()
+        return info
 
     def task_stats(self) -> Dict:
         """TaskStats rollup as a JSON-ready dict: operator sums from the

@@ -25,6 +25,7 @@ from typing import Optional
 from presto_tpu.config import DEFAULT, EngineConfig
 from presto_tpu.connectors.api import ConnectorRegistry
 from presto_tpu.server.task import SqlTaskManager
+from presto_tpu.spans import HOST_ACTIVITY_HEADER
 
 
 class WorkerServer:
@@ -179,7 +180,8 @@ class WorkerServer:
                     if task is None:
                         self._json(404, {"error": "no such task"})
                         return
-                    self._json(200, task.info())
+                    self._json(200, task.info(activity=self.headers.get(
+                        HOST_ACTIVITY_HEADER) == "1"))
                     return
                 if (parts[:2] == ["v1", "task"] and len(parts) == 6
                         and parts[3] == "results"):

@@ -12,6 +12,12 @@ timestamps it already owns:
     ├── execute          (drain span)
     └── stage-{fid}
         └── task {task_id} (attempt aN)   one span per task attempt
+            └── generate / stage_h2d / dispatch / ...   host activity
+
+What the host did inside a task is recorded by the task's
+``HostActivity`` through ``activity(kind)`` (below): intervals on the
+same epoch clock as every other span, and, while a profile is being
+taken, ``host:<kind>`` events in the profiler's host plane.
 
 Every span carries the query's trace token as its trace id, wall-clock
 ``start``/``end`` (epoch seconds), and nests inside its parent (the
@@ -24,12 +30,15 @@ serialized into ``QueryCompletedEvent``/query.json, and rendered by
 from __future__ import annotations
 
 import dataclasses
+import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 
 @dataclasses.dataclass
 class QuerySpan:
-    """One timed span; ``kind`` is query | phase | stage | task."""
+    """One timed span; ``kind`` is query | phase | stage | task |
+    activity."""
 
     name: str
     kind: str
@@ -59,6 +68,147 @@ PHASES = ("queue", "parse", "analyze", "optimize", "fragment", "schedule",
           "lower", "compile", "execute")
 
 
+#: what a task's host threads can be doing, each bracketed where the work
+#: happens (the kind's reader is in PERF.md's table of spans and counters):
+#: generate      the connector's page source produces a batch
+#: stage_h2d     padding a host batch and putting it on the device
+#: dispatch      the call of a jitted program, from call to return
+#:               (enqueue time, unless the call traces and compiles)
+#: device_wait   host code reads a device value and blocks until it is there
+#: serialize     encoding + LZ4 of an exchange page, and decoding on the
+#:               consumer
+#: exchange_wait an exchange-fed operator parked until a page arrives
+ACTIVITY_KINDS = ("generate", "stage_h2d", "dispatch", "device_wait",
+                  "serialize", "exchange_wait")
+
+#: GET /v1/task/{id} with this header set to 1 adds ``hostActivity``, the
+#: task's intervals, to the info: the coordinator's final collection
+HOST_ACTIVITY_HEADER = "X-Presto-Host-Activity"
+
+MERGE_GAP_NS = 1_000_000     # same-kind intervals closer than this are one
+MAX_INTERVALS = 4096         # per task; totals stay exact beyond it
+
+
+class HostActivity:
+    """One task's record of what its host threads did: per-kind
+    nanosecond totals (always exact; thread-seconds, so the feed drivers
+    of one task add up), the wall intervals behind them (merged when one
+    kind repeats within ``MERGE_GAP_NS``, at most ``MAX_INTERVALS`` and
+    ``truncated`` beyond), and the XLA builds JAX made on the task's
+    threads (``kernelcache``'s listener charges ``xla``).  Lives on
+    ``TaskContext``; the Driver makes it the thread's current recorder.
+
+    The intervals are kept, sent and held by the coordinator as five
+    parallel lists, not a list per interval: a finished query leaves a
+    few lists behind on either side, not hundreds of objects for the
+    garbage collector to walk."""
+
+    __slots__ = ("total_ns", "kinds", "start_ns", "end_ns", "counts",
+                 "busy_ns", "truncated", "xla", "_last", "_lock")
+
+    def __init__(self):
+        self.total_ns: Dict[str, int] = dict.fromkeys(ACTIVITY_KINDS, 0)
+        # interval i: kinds[i] from start_ns[i] to end_ns[i] (epoch
+        # clock), counts[i] brackets merged into it, busy_ns[i] inside
+        self.kinds: List[str] = []
+        self.start_ns: List[int] = []
+        self.end_ns: List[int] = []
+        self.counts: List[int] = []
+        self.busy_ns: List[int] = []
+        self.truncated = False
+        self.xla: Dict[str, int] = {"builds": 0, "build_ns": 0,
+                                    "trace_lower_ns": 0, "cache_hits": 0}
+        self._last: Dict[str, int] = {}     # kind -> its newest interval
+        self._lock = threading.Lock()
+
+    def add(self, kind: str, start_ns: int, end_ns: int) -> None:
+        busy = end_ns - start_ns
+        with self._lock:
+            self.total_ns[kind] += busy      # KeyError: not a kind
+            i = self._last.get(kind)
+            if i is not None and start_ns - self.end_ns[i] < MERGE_GAP_NS:
+                # also another thread's overlapping interval: the union
+                if start_ns < self.start_ns[i]:
+                    self.start_ns[i] = start_ns
+                if end_ns > self.end_ns[i]:
+                    self.end_ns[i] = end_ns
+                self.counts[i] += 1
+                self.busy_ns[i] += busy
+            elif len(self.kinds) < MAX_INTERVALS:
+                self._last[kind] = len(self.kinds)
+                self.kinds.append(kind)
+                self.start_ns.append(start_ns)
+                self.end_ns.append(end_ns)
+                self.counts.append(1)
+                self.busy_ns.append(busy)
+            else:
+                self.truncated = True
+
+    @property
+    def intervals(self) -> List[list]:
+        """[[kind, start ns, end ns, merged count, busy ns], ...]."""
+        with self._lock:
+            return [list(iv) for iv in zip(
+                self.kinds, self.start_ns, self.end_ns, self.counts,
+                self.busy_ns)]
+
+    def as_dict(self) -> Dict:
+        """The task's final info payload (``hostActivity``)."""
+        with self._lock:
+            return {"kinds": list(self.kinds),
+                    "startNs": list(self.start_ns),
+                    "endNs": list(self.end_ns),
+                    "counts": list(self.counts),
+                    "busyNs": list(self.busy_ns),
+                    "truncated": self.truncated}
+
+
+_CURRENT = threading.local()
+_annotation = None      # jax.profiler.TraceAnnotation, bound at first use
+
+
+def set_current_activity(recorder: Optional[HostActivity]
+                         ) -> Optional[HostActivity]:
+    """Makes ``recorder`` this thread's; returns the one it replaces."""
+    previous = getattr(_CURRENT, "recorder", None)
+    _CURRENT.recorder = recorder
+    return previous
+
+
+def current_activity() -> Optional[HostActivity]:
+    return getattr(_CURRENT, "recorder", None)
+
+
+class activity:
+    """``with activity(kind):`` charges the body to the thread's current
+    task under ``kind`` and shows it as ``host:<kind>`` in a profile
+    that is being taken.  Does nothing on a thread with no task."""
+
+    __slots__ = ("kind", "_recorder", "_start_ns", "_trace_me")
+
+    def __init__(self, kind: str):
+        self.kind = kind
+
+    def __enter__(self) -> "activity":
+        recorder = self._recorder = getattr(_CURRENT, "recorder", None)
+        if recorder is not None:
+            global _annotation
+            if _annotation is None:
+                from jax.profiler import TraceAnnotation as _annotation
+            self._trace_me = _annotation("host:" + self.kind)
+            self._trace_me.__enter__()
+            self._start_ns = time.time_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        recorder = self._recorder
+        if recorder is not None:
+            end_ns = time.time_ns()
+            self._trace_me.__exit__(*exc)
+            recorder.add(self.kind, self._start_ns, end_ns)
+        return False
+
+
 def _clamp(start: float, end: float, lo: float, hi: float
            ) -> Tuple[float, float]:
     start = min(max(start, lo), hi)
@@ -81,16 +231,21 @@ def build_span_tree(query_id: str, trace_token: str,
                     create_time: float, end_time: Optional[float],
                     marks: Dict[str, Tuple[float, float]],
                     task_stats: Dict, admit_time: Optional[float] = None,
-                    now: Optional[float] = None) -> Dict:
+                    now: Optional[float] = None,
+                    task_extras: Optional[Dict[str, Dict]] = None,
+                    activities: bool = True) -> Dict:
     """Assemble the span tree from coordinator-owned timestamps.
 
     ``marks`` holds per-phase (start, end) recorded by the query thread;
     ``task_stats`` is the {fid: [TaskStats dict]} rollup (live sampler
     mid-query, final collection after) whose per-task start/end times
-    become the stage/task-attempt spans."""
-    import time as _time
-
-    t_now = now if now is not None else _time.time()
+    become the stage/task-attempt spans.  ``task_extras`` is {task id:
+    {"operators": [OperatorStats dict], "activity": a final task info's
+    ``hostActivity`` or None}}: the operators become the task span's
+    ``operators`` attribute, the intervals its ``activity`` children
+    (left out with ``activities=False``: the completed event's copy)."""
+    t_now = now if now is not None else time.time()
+    task_extras = task_extras or {}
     q_end = end_time if end_time is not None else t_now
     q_end = max(q_end, create_time)
     root = QuerySpan(query_id, "query", create_time, q_end, trace_token)
@@ -118,14 +273,43 @@ def build_span_tree(query_id: str, trace_token: str,
                           ts.get("end_time") or t_now,
                           s0, e0)
             tid = ts.get("task_id", "?")
-            stage.children.append(QuerySpan(
+            extras = task_extras.get(tid) or {}
+            recorded = extras.get("activity") or {}
+            task = QuerySpan(
                 tid, "task", s, e, trace_token,
-                attributes={"attempt": _attempt_of(tid),
-                            "state": ts.get("state", ""),
-                            "outputRows": ts.get("output_rows", 0),
-                            "jitCompileNs": ts.get("jit_compile_ns", 0)}))
+                attributes={
+                    "attempt": _attempt_of(tid),
+                    "state": ts.get("state", ""),
+                    "outputRows": ts.get("output_rows", 0),
+                    "hostSeconds": {k: ns / 1e9 for k, ns in
+                                    (ts.get("host_ns") or {}).items()},
+                    "activityTruncated": bool(recorded.get("truncated")),
+                    "operators": [_operator_entry(o) for o in
+                                  extras.get("operators") or []]})
+            if activities:
+                for kind, a_ns, b_ns, count, busy_ns in zip(
+                        *(recorded.get(k) or () for k in (
+                            "kinds", "startNs", "endNs", "counts",
+                            "busyNs"))):
+                    a, b = _clamp(a_ns / 1e9, b_ns / 1e9, s, e)
+                    task.children.append(QuerySpan(
+                        kind, "activity", a, b, trace_token,
+                        attributes={"count": count,
+                                    "busyS": busy_ns / 1e9}))
+            stage.children.append(task)
         root.children.append(stage)
     return root.as_dict()
+
+
+def _operator_entry(stats: Dict) -> Dict:
+    """One operator's busy time: a sum over its calls, not an interval,
+    hence an attribute of the task span and not a child."""
+    return {"operator": stats.get("operator", ""),
+            "wallS": (stats.get("wall_ns", 0)
+                      + stats.get("finish_wall_ns", 0)) / 1e9,
+            "inputRows": stats.get("input_rows", 0),
+            "outputRows": stats.get("output_rows", 0),
+            "jitDispatches": stats.get("jit_dispatches", 0)}
 
 
 def validate_span_tree(tree: Dict) -> List[str]:
@@ -168,8 +352,26 @@ def render_span_tree(tree: Dict, width: int = 40) -> List[str]:
         lines.append(
             f"  {label:<30} |{bar(node['start'], node['end'])}| "
             f"{node['durationS'] * 1000:>9.1f} ms")
+        by_kind: Dict[str, List[float]] = {}
         for c in node.get("children", []):
-            walk(c, depth + 1)
+            if c["kind"] != "activity":
+                walk(c, depth + 1)
+                continue
+            # one line per kind, not one per interval
+            acc = by_kind.setdefault(c["name"], [0.0, 0])
+            acc[0] += c["attributes"].get("busyS", c["durationS"])
+            acc[1] += c["attributes"].get("count", 1)
+        if not by_kind:     # the completed event's copy: totals only
+            by_kind = {kind: [seconds, 0] for kind, seconds in
+                       (node.get("attributes", {}).get("hostSeconds")
+                        or {}).items() if seconds}
+        for kind in ACTIVITY_KINDS:
+            if kind in by_kind:
+                seconds, count = by_kind[kind]
+                label = ("  " * (depth + 1) + "host:" + kind)[:30]
+                lines.append(f"  {label:<30}  {' ' * width}  "
+                             f"{seconds * 1000:>9.1f} ms"
+                             + (f"  x{count}" if count else ""))
 
     walk(tree, 0)
     return lines
