@@ -384,20 +384,22 @@ class EngineConfig:
     # path for the remaining input — the "configured fraction of device
     # memory" guard (4M slots ~ a few hundred MB of state at Q1 widths)
     hash_groupby_max_slots: int = 1 << 22
-    # PagesHash: the join build side ALSO builds an open-addressing
-    # table over its raw normalized key words, and probes resolve
-    # match ranges through it (hash + prefix reject + one gather)
-    # instead of a ~20-step vectorized binary search; arbitrary
-    # multi-channel key types stream (equality needs no total order, so
-    # the canonical union-sort materialization disappears).  OFF
-    # restores the sorted-index probe exactly.
+    # Join probes run inside fused segments (exec/fusion.py ProbeStage),
+    # and keys that have no direct-address index (ops/join.py: integer
+    # keys whose live span fits 1 << 24 slots take the index whatever
+    # this says) build the PagesHash open-addressing table over their
+    # raw normalized key words: VARCHAR and wide multi-channel keys
+    # stream through it (equality needs no total order, so the canonical
+    # union-sort materialization disappears).  OFF restores the
+    # unabsorbed probe chains and the sorted / canonical lookups.
     device_join_probe: bool = True
-    # build sides LARGER than this keep the sorted index when their
-    # keys could take the single/packed tiers: claim-loop insertion of
-    # a huge build side costs more than one argsort, while the
-    # dimension-build/fact-probe pattern (small build, big probe) is
-    # where the hash table wins.  Unpackable (canonical-class) keys
-    # always build the hash table — that is what lets them stream.
+    # integer keys too sparse for the index, on the chip: build sides up
+    # to this many rows take the hash table (a probe measured 29 ms
+    # against the binary search's 33 ms a 64K batch on v5e, PERF.md
+    # PR 30), larger ones keep the sorted index (claim-inserting a build
+    # measured 245 ms per 128K rows against 12 ms for the sort).
+    # Unpackable (canonical-class) keys always build the hash table —
+    # that is what lets them stream.
     device_join_probe_max_build_rows: int = 1 << 17
     # Fuse the FINAL-step merge aggregation into exchange-fed segments
     # (PR 4's named remaining depth): the consumer fragment's merge

@@ -140,11 +140,15 @@ class OperatorStats:
     # emitted partial states, not row batches — tests pin on this
     # instead of eyeballing operator chains.
     prereduce_rows: int = 0
-    # which kernel tier served this operator's group-by/join hot loop:
-    # "hash" (device-resident open-addressing, ops/hashtable.py),
-    # "direct" (bounded-domain), "sort" (sorted-index), "stream"
-    # (clustered), "hash+sort" (overflow seam crossed mid-query) —
-    # surfaced per segment/operator by tools/fusion_report.py
+    # which kernel tier served this operator's group-by/join hot loop.
+    # Group-by: "hash" (device-resident open-addressing,
+    # ops/hashtable.py), "direct" (bounded-domain), "sort", "stream"
+    # (clustered), "hash+sort" (overflow seam crossed mid-query).
+    # Join build and probe (absorbed or stand-alone): "dense"
+    # (direct-address index, ops/join.py), "sorted", "hash"; a segment
+    # that absorbed probes of two tiers reads "dense+hash".  Surfaced by
+    # tools/fusion_report.py, the span tree (kernelTier) and EXPLAIN
+    # ANALYZE's "kernel tiers" line
     kernel_tier: str = ""
 
     def as_dict(self) -> Dict:
@@ -390,6 +394,25 @@ def host_and_xla_line(stats: Dict) -> str:
             f"({stats.get('xla_cache_hits', 0)} loaded) in "
             f"{stats.get('xla_build_ns', 0) / 1e6:.1f} ms, trace+lower "
             f"{stats.get('xla_trace_lower_ns', 0) / 1e6:.1f} ms")
+
+
+def kernel_tier_lines(ops) -> List[str]:
+    """EXPLAIN ANALYZE's "kernel tiers" line: how many operator
+    instances (join builds and probes, fused segments, group-bys) took
+    which tier (``OperatorStats.kernel_tier``).  ``ops`` are
+    operator-stats dicts, one per instance."""
+    by_tier: Dict[str, Dict[str, int]] = {}
+    for o in ops:
+        if o.get("kernel_tier"):
+            kinds = by_tier.setdefault(o["kernel_tier"], {})
+            kind = o.get("operator", "?").rsplit(".", 1)[-1]
+            kinds[kind] = kinds.get(kind, 0) + 1
+    if not by_tier:
+        return []
+    return ["kernel tiers: " + "; ".join(
+        f"{tier}: " + ", ".join(f"{kind} x{n}"
+                                for kind, n in sorted(kinds.items()))
+        for tier, kinds in sorted(by_tier.items()))]
 
 
 def hot_operator_lines(ops, top_n: int = 5) -> List[str]:

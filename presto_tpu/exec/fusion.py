@@ -814,9 +814,9 @@ class FusedSegmentOperator(Operator):
                         f"source, got mode={src.mode!r}; rerun with "
                         "device_join_probe=false")
                 srcs.append(src)
-                self.ctx.stats.kernel_tier = (
-                    self.ctx.stats.kernel_tier or
-                    ("hash" if src.mode == "hash" else "sorted"))
+            # one tier per absorbed probe, in stage order, repeats merged
+            self.ctx.stats.kernel_tier = "+".join(
+                dict.fromkeys(src.kernel_tier for src in srcs))
             self._probe_srcs = srcs
         key_parts, args, metas = [], [], []
         for k, src in zip(self._probe_idx, self._probe_srcs):
@@ -826,14 +826,19 @@ class FusedSegmentOperator(Operator):
             if src.mode == "hash":
                 aux = (src.pages, src.perm)
                 table_cap = src.pages[2].shape[0]
-            elif src.mode == "single":
-                aux = (src.sorted_ids, src.perm, src.mins,
-                       jnp.zeros(1, jnp.int64), jnp.zeros(1, jnp.int64))
-                table_cap = 0
             else:
-                aux = (src.sorted_ids, src.perm, jnp.asarray(src.mins),
-                       jnp.asarray(src.strides), jnp.asarray(src.maxs))
-                table_cap = 0
+                if src.mode == "single":
+                    ranges = (src.mins, jnp.zeros(1, jnp.int64),
+                              jnp.zeros(1, jnp.int64))
+                else:
+                    ranges = (jnp.asarray(src.mins),
+                              jnp.asarray(src.strides),
+                              jnp.asarray(src.maxs))
+                # a direct-address index (kernel tier "dense") rides in
+                # place of the sorted ids; its slot count keys the program
+                aux = (src.sorted_ids, src.perm) + ranges + (src.index,)
+                table_cap = (src.index.shape[0]
+                             if src.index is not None else 0)
             bstats = (jnp.asarray(src.n_build, jnp.int64),
                       src.has_null_key if src.has_null_key is not None
                       else jnp.zeros((), bool))
@@ -1038,13 +1043,16 @@ class FusedSegmentOperator(Operator):
                         lo, counts, live = pages_hash_probe(
                             pages, kcols, num_rows)
                     else:
-                        from presto_tpu.exec.joinop import _ids_from_pairs
+                        from presto_tpu.exec.joinop import (
+                            _ids_from_pairs, _index_lo_counts,
+                        )
 
-                        sorted_ids, perm, mins, strides, maxs = aux
+                        sorted_ids, perm, mins, strides, maxs, index = aux
                         ids = _ids_from_pairs(
                             jnp, cur, kc, meta["mode"], mins, strides,
                             maxs, num_rows)
-                        lo, counts = J.probe_counts(sorted_ids, perm, ids)
+                        lo, counts = _index_lo_counts(
+                            ids, sorted_ids, perm, index)
                         live = ids >= 0
                     alive = jnp.arange(cap_now) < num_rows
                     if mask is not None:

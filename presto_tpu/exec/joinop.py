@@ -5,18 +5,25 @@ PartitionedLookupSourceFactory), LookupJoinOperator.java:64 (probe),
 HashSemiJoinOperator/SetBuilderOperator (semi), with variants per
 LookupJoinOperators.java:45-60 (inner / probe-outer / semi / anti).
 
-TPU design (ops/join.py): the LookupSource is a *sorted id index*, not a
-hash table.  Three id strategies, chosen at build finish:
+TPU design (ops/join.py): the LookupSource is an index over the build
+rows grouped by key, never a chained table.  Id strategies, chosen at
+build finish from the key types and the live span of the build keys:
 
 - 'single': one integer-ish key channel; values are ids directly.
 - 'packed': multi-channel integer keys packed into one 63-bit word using
   build-side [min,max] ranges; probe values outside a channel's build range
   cannot match and map to the dead sentinel (keeps packing exact).
+- 'hash': the PagesHash table over normalized key words (ops/hashtable.py).
 - 'canonical': arbitrary keys; probe side must materialize, ids come from
   a union sort (exact, collision-free).
 
-Probe is streaming for 'single'/'packed' (one jitted program per probe
-batch shape), with output-capacity retry on expansion overflow.
+A 'single' / 'packed' source whose id span fits
+``ops.join.DENSE_INDEX_MAX_SLOTS`` carries a direct-address ``index``
+(kernel tier "dense": a probe is one row gather); wider spans carry the
+sorted id array (tier "sorted": histogram or binary search per probe).
+
+Probe is streaming for every mode but 'canonical' (one jitted program per
+probe batch shape), with output-capacity retry on expansion overflow.
 """
 
 from __future__ import annotations
@@ -53,8 +60,9 @@ class LookupSource:
 
     mode: str                      # 'single' | 'packed' | 'canonical'
                                    # | 'hash' (PagesHash table)
-    sorted_ids: object             # int64 [cap_b] (single/packed)
-    perm: object                   # int64 [cap_b]
+    sorted_ids: object             # int64 [cap_b] (single/packed without
+                                   # an index)
+    perm: object                   # [cap_b] build rows grouped by key
     data: Batch                    # padded device build batch
     n_build: int
     key_channels: List[int]
@@ -68,6 +76,18 @@ class LookupSource:
     # (starts, counts) index ``perm`` — the PagesHash role proper
     pages: Optional[tuple] = None
     key_types: Optional[tuple] = None     # probe-normalization types
+    # single/packed whose id span fits DENSE_INDEX_MAX_SLOTS: the
+    # direct-address index, int32 [size, 2] addressed by the id itself:
+    # index[id] = (start, count) of that key's run in ``perm``
+    index: object = None
+
+    @property
+    def kernel_tier(self) -> str:
+        """What OperatorStats.kernel_tier reads for a build or a probe
+        through this source."""
+        if self.mode == "hash":
+            return "hash"
+        return "dense" if self.index is not None else "sorted"
 
 
 class LookupSourceFactory:
@@ -151,11 +171,8 @@ def _key_ranges(pairs, num_rows):
     return jnp.stack(los), jnp.stack(his)
 
 
-@_partial(kernelcache.jit, name="join_build_index")
-def _build_index_packed(pairs, mins, strides, num_rows):
-    """Packed multi-key build: mixed-radix ids + sorted index."""
-    from presto_tpu.ops import join as J
-
+def _packed_ids(pairs, mins, strides, num_rows):
+    """Mixed-radix build ids (dead rows -2) and the null-key flag."""
     cap = pairs[0][0].shape[0]
     in_row = jnp.arange(cap) < num_rows
     dead = ~in_row
@@ -166,9 +183,33 @@ def _build_index_packed(pairs, mins, strides, num_rows):
             dead = dead | ~valid
             has_null = has_null | (in_row & ~valid).any()
         ids = ids + (values.astype(jnp.int64) - mins[i]) * strides[i]
-    ids = jnp.where(dead, jnp.int64(-2), ids)
+    return jnp.where(dead, jnp.int64(-2), ids), has_null
+
+
+@_partial(kernelcache.jit, name="join_build_index")
+def _build_index_packed(pairs, mins, strides, num_rows):
+    """Packed multi-key build: mixed-radix ids + sorted index."""
+    from presto_tpu.ops import join as J
+
+    ids, has_null = _packed_ids(pairs, mins, strides, num_rows)
     sb, perm = J.build_index(ids)
     return sb, perm, has_null
+
+
+@_partial(kernelcache.jit, name="join_build_index",
+          static_argnames=("size", "id_base"))
+def _build_index_dense(pairs, mins, strides, num_rows, *, size, id_base):
+    """Direct-address build for integer keys whose id span fits ``size``
+    slots: the ids of 'single' (``id_base`` 2, one channel, stride 1) or
+    'packed' (``id_base`` 0) mode address the index themselves.  ``size``
+    is the span's power-of-two bucket, so one table's builds share one
+    program."""
+    from presto_tpu.ops import join as J
+
+    ids, has_null = _packed_ids(pairs, mins, strides, num_rows)
+    ids = jnp.where(ids >= 0, ids + id_base, ids)
+    index, perm = J.build_dense_index(ids, size)
+    return index, perm, has_null
 
 
 from presto_tpu.kernelcache import cache_get, cache_put, new_cache
@@ -285,6 +326,16 @@ class HashBuildOperator(Operator):
         cfg = self.ctx.config
         packable = all(_is_single_word_type(data.columns[c].type)
                        for c in chans)
+        ranges = None
+        if packable:
+            # integer key words: the live span decides.  One small host
+            # read of the per-channel [min, max]; where the id span fits
+            # the direct-address bound the build publishes the index and
+            # every probe is one row gather, on every backend
+            ranges = self._live_key_ranges(key_pairs, n)
+            if self._set_dense_index(data, key_pairs, chans, n, n_build,
+                                     ranges):
+                return
         want_hash = False
         if getattr(cfg, "device_join_probe", False):
             if not packable:
@@ -296,22 +347,18 @@ class HashBuildOperator(Operator):
                     and n_build <= getattr(
                         cfg, "device_join_probe_max_build_rows",
                         1 << 17)):
-                # packable keys: platform economics decide.  On TPU,
-                # sorting is the expensive primitive and gathers run at
-                # device rate, so the table wins up to the build-size
-                # bound (claim-inserting a huge build still loses to
-                # one argsort).  On CPU the measured winner for
-                # integer-keyed builds is the existing sorted tier —
-                # its dense-histogram probe is two gathers — so the
-                # hash table is not engaged there; absorbed probes
-                # (exec/fusion.py) carry single/packed sources
-                # in-kernel either way, which is where the dispatch
-                # reduction lives.
+                # integer keys too sparse for the index: on the chip a
+                # probe of the sorted tier is a binary search of 2x18
+                # dependent int64 gathers, 33 ms a 64K batch against
+                # the table's 29 ms, while claim-inserting a 128K build
+                # costs 245 ms against 12 ms for the sort (v5e, PERF.md
+                # PR 30): the table serves builds up to the bound.  On
+                # the CPU the sorted tier stays.
                 want_hash = True
         if want_hash and self._set_pages_hash(data, key_pairs, chans,
                                               n, n_build):
             return
-        if len(chans) == 1 and _is_single_word_type(data.columns[chans[0]].type):
+        if len(chans) == 1 and packable:
             # one scalar sync guards the id arithmetic: a live key spread
             # >= 2^62 would overflow the (value - min + 2) ids, silently
             # dropping matches — such builds take the canonical path
@@ -321,32 +368,21 @@ class HashBuildOperator(Operator):
             with activity("device_wait"):
                 span_big = bool(span_big)
             if not span_big:
+                self.ctx.stats.kernel_tier = "sorted"
                 self.f.lookup.set(LookupSource(
                     "single", sb, perm, data, n_build, chans, mins=bmin,
                     has_null_key=has_null))
                 return
-        if all(_is_single_word_type(data.columns[c].type) for c in chans):
+        if packable:
             # pack multi-channel integer keys using build-side ranges
-            with activity("dispatch"):
-                los, his = _key_ranges(key_pairs, n)
-            with activity("device_wait"):               # one host sync
-                los = np.asarray(los)
-                his = np.asarray(his)
-            empty = bool((los > his).any())             # no live rows
-            if empty:
-                los = np.zeros_like(los)
-                his = np.zeros_like(his)
-            strides = []
-            span_product = 1
-            for lo, hi in zip(los, his):
-                strides.append(span_product)
-                span_product *= int(hi - lo + 1)
+            los, his, strides, span_product = ranges
             if span_product < (1 << 62):
                 strides_a = np.asarray(strides, np.int64)
                 with activity("dispatch"):
                     sb, perm, has_null = _build_index_packed(
                         key_pairs, jnp.asarray(los),
                         jnp.asarray(strides_a), n)
+                self.ctx.stats.kernel_tier = "sorted"
                 self.f.lookup.set(LookupSource(
                     "packed", sb, perm, data, n_build, chans,
                     mins=los, strides=strides_a, maxs=his,
@@ -361,6 +397,63 @@ class HashBuildOperator(Operator):
         # general path: probe side will materialize and union-sort
         self.f.lookup.set(LookupSource("canonical", None, None, data,
                                        n_build, chans))
+
+    @staticmethod
+    def _live_key_ranges(key_pairs, n):
+        """Per-channel live [min, max] of integer build keys, read to
+        the host (one sync), with the mixed-radix strides and the id
+        span they give: ``(los, his, strides, span_product)``, the last
+        two as Python ints (a product of wide spans passes int64)."""
+        with activity("dispatch"):
+            los, his = _key_ranges(key_pairs, n)
+        with activity("device_wait"):
+            los = np.asarray(los)
+            his = np.asarray(his)
+        if bool((los > his).any()):                     # no live rows
+            los = np.zeros_like(los)
+            his = np.zeros_like(his)
+        strides = []
+        span_product = 1
+        for lo, hi in zip(los, his):
+            strides.append(span_product)
+            span_product *= int(hi) - int(lo) + 1
+        return los, his, strides, span_product
+
+    def _set_dense_index(self, data, key_pairs, chans, n, n_build,
+                         ranges) -> bool:
+        """Build + publish the direct-address index; False when the id
+        span is over ``DENSE_INDEX_MAX_SLOTS`` (or the index's bytes
+        cannot be reserved): the caller then takes the other tiers."""
+        from presto_tpu.exec.context import MemoryReservationError
+        from presto_tpu.ops import join as J
+
+        los, his, strides, span_product = ranges
+        single = len(chans) == 1
+        # 'single' ids are (value - min + 2): two idle slots in front
+        id_base = 2 if single else 0
+        size = J.dense_index_size(span_product + id_base)
+        if size is None:
+            return False
+        try:
+            # the index and the int32 perm live through the probe
+            # with the build data; HashBuildOperatorFactory.release frees
+            self.ctx.memory.reserve(8 * size + 4 * data.capacity)
+        except MemoryReservationError:
+            return False
+        strides_a = np.asarray(strides, np.int64)
+        with activity("dispatch"):
+            index, perm, has_null = _build_index_dense(
+                key_pairs, jnp.asarray(los), jnp.asarray(strides_a), n,
+                size=size, id_base=id_base)
+        self.ctx.stats.kernel_tier = "dense"
+        # 'single' probes read ``mins`` as the device scalar of the
+        # build's live minimum and nothing else of the ranges
+        self.f.lookup.set(LookupSource(
+            "single" if single else "packed", None, perm, data, n_build,
+            chans, mins=jnp.asarray(los[0]) if single else los,
+            strides=strides_a, maxs=his, has_null_key=has_null,
+            index=index))
+        return True
 
     def _set_pages_hash(self, data, key_pairs, chans, n,
                         n_build) -> bool:
@@ -481,12 +574,22 @@ def _hash_lo_counts(probe_pairs, pages, key_channels, key_types,
     return pages_hash_probe(pages, kc, num_rows)
 
 
+def _index_lo_counts(ids, sorted_ids, perm, index):
+    """(lo, counts) for 'single'/'packed' ids: through the build's
+    direct-address index where it published one, else the sorted ids."""
+    from presto_tpu.ops import join as J
+
+    if index is not None:
+        return J.probe_dense(index, ids)
+    return J.probe_counts(sorted_ids, perm, ids)
+
+
 @_partial(kernelcache.jit, name="join_probe_count",
           static_argnames=("key_channels", "mode", "join_type",
                            "key_types"))
 def _probe_expand_total(probe_pairs, sorted_ids, perm, mins, strides,
-                        maxs, pages, num_rows, *, key_channels, mode,
-                        join_type, key_types=()):
+                        maxs, pages, index, num_rows, *, key_channels,
+                        mode, join_type, key_types=()):
     """Phase 1: exact expansion size for this batch (so phase 2 compiles
     at the right capacity bucket on the first try)."""
     from presto_tpu.ops import join as J
@@ -497,7 +600,7 @@ def _probe_expand_total(probe_pairs, sorted_ids, perm, mins, strides,
     else:
         ids = _ids_from_pairs(jnp, probe_pairs, key_channels, mode, mins,
                               strides, maxs, num_rows)
-        _, counts = J.probe_counts(sorted_ids, perm, ids)
+        _, counts = _index_lo_counts(ids, sorted_ids, perm, index)
     if join_type == "left":
         cap = probe_pairs[0][0].shape[0]
         live_probe = jnp.arange(cap) < num_rows
@@ -507,7 +610,7 @@ def _probe_expand_total(probe_pairs, sorted_ids, perm, mins, strides,
 
 @_partial(kernelcache.jit, name="join_probe", static_argnames=("s",))
 def _stream_probe(probe_pairs, build_pairs, sorted_ids, perm, mins,
-                  strides, maxs, pages, num_rows, bstats, *,
+                  strides, maxs, pages, index, num_rows, bstats, *,
                   s: _StreamStatics):
     """Phase 2: the streaming probe kernel (inner/left expansion or
     semi/anti masks) as one XLA program.  All build-side data arrives as
@@ -523,7 +626,7 @@ def _stream_probe(probe_pairs, build_pairs, sorted_ids, perm, mins,
     else:
         ids = _ids_from_pairs(jnp, probe_pairs, s.key_channels, s.mode,
                               mins, strides, maxs, num_rows)
-        lo, counts = J.probe_counts(sorted_ids, perm, ids)
+        lo, counts = _index_lo_counts(ids, sorted_ids, perm, index)
         live = ids >= 0
     if s.join_type in ("semi", "anti"):
         if s.join_type == "anti":
@@ -648,12 +751,13 @@ class LookupJoinOperator(Operator):
             return self._probe_streaming_global(src, batch, n)
         out_cap = next_bucket(cap * self.f.expansion)
         cres = self._residual_compiled(batch, src)
+        self.ctx.stats.kernel_tier = src.kernel_tier
         while True:
             kernel = self._kernel(src, cap, out_cap, cres)
             with activity("dispatch"):
                 outs, count, expand_total = kernel(
                     tuple(column_pairs(batch)),
-                    tuple(column_pairs(src.data)), n)
+                    tuple(column_pairs(src.data)), src.index, n)
             with activity("device_wait"):
                 total = int(count)
                 expand_total = int(expand_total)
@@ -697,9 +801,7 @@ class LookupJoinOperator(Operator):
         else:
             mins = strides = maxs = jnp.zeros(1, jnp.int64)
         key_types = src.key_types if src.mode == "hash" else ()
-        if not self.ctx.stats.kernel_tier:
-            self.ctx.stats.kernel_tier = (
-                "hash" if src.mode == "hash" else "sorted")
+        self.ctx.stats.kernel_tier = src.kernel_tier
         probe_pairs = tuple(column_pairs(batch))
         build_pairs = tuple(column_pairs(src.data))
         if join_type in ("semi", "anti"):
@@ -708,7 +810,8 @@ class LookupJoinOperator(Operator):
             with activity("dispatch"):
                 etotal = _probe_expand_total(
                     probe_pairs, src.sorted_ids, src.perm, mins, strides,
-                    maxs, src.pages, n, key_channels=kc, mode=src.mode,
+                    maxs, src.pages, src.index, n, key_channels=kc,
+                    mode=src.mode,
                     join_type=join_type, key_types=key_types)
             with activity("device_wait"):
                 etotal = int(etotal)
@@ -722,7 +825,7 @@ class LookupJoinOperator(Operator):
         with activity("dispatch"):
             outs, count, _ = _stream_probe(
                 probe_pairs, build_pairs, src.sorted_ids, src.perm, mins,
-                strides, maxs, src.pages, n, bstats, s=s)
+                strides, maxs, src.pages, src.index, n, bstats, s=s)
         # expansion joins already synced the exact total in phase 1; only
         # semi/anti need to read the selected count (every host read is
         # a device sync)
@@ -763,7 +866,9 @@ class LookupJoinOperator(Operator):
         probe_op = self
         residual = None if cres is None else cres.run
 
-        def kernel(probe_cols_pairs, build_cols_pairs, num_rows):
+        def kernel(probe_cols_pairs, build_cols_pairs, index, num_rows):
+            # the index rides as an argument: at up to 128 MB it must
+            # not be baked into the executable as a constant
             if src.mode == "hash":
                 lo, counts, live = _hash_lo_counts(
                     probe_cols_pairs, src.pages,
@@ -772,7 +877,8 @@ class LookupJoinOperator(Operator):
             else:
                 pb = _RebuiltBatch(probe_cols_pairs)
                 ids = probe_op._probe_ids(jnp, src, pb, num_rows)
-                lo, counts = J.probe_counts(src.sorted_ids, src.perm, ids)
+                lo, counts = _index_lo_counts(ids, src.sorted_ids,
+                                              src.perm, index)
                 live = ids >= 0
             zero = jnp.int64(0)
             if join_type in ("semi", "anti"):

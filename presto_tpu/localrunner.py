@@ -793,13 +793,13 @@ class LocalQueryRunner:
                 f"{s.jit_dispatches:>8} {s.jit_compiles:>8} "
                 f"{s.prereduce_rows:>9}")
         from presto_tpu.exec.context import (
-            host_and_xla_line, hot_operator_lines,
+            host_and_xla_line, hot_operator_lines, kernel_tier_lines,
         )
 
-        lines.extend(hot_operator_lines([
-            dict(s.as_dict(),
-                 wall_ns=s.wall_ns + s.finish_wall_ns)
-            for s in task.operator_stats]))
+        op_dicts = [dict(s.as_dict(), wall_ns=s.wall_ns + s.finish_wall_ns)
+                    for s in task.operator_stats]
+        lines.extend(hot_operator_lines(op_dicts))
+        lines.extend(kernel_tier_lines(op_dicts))
         jc = task.jit_counters()
         lines.append(
             f"peak memory: {task.memory.peak / (1 << 20):.1f} MiB; "

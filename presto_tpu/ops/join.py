@@ -1,4 +1,4 @@
-"""Hash-join kernel family (sorted-build design).
+"""Hash-join kernel family (grouped-build design).
 
 The reference's join is PagesHash — open-addressing table over PagesIndex
 with synthetic addresses, probed row-at-a-time
@@ -6,36 +6,45 @@ with synthetic addresses, probed row-at-a-time
 LookupJoinPageBuilder.java:74).  A probe loop with data-dependent chaining
 is the worst possible shape for a TPU, so the design here is different:
 
-  build:  normalize keys -> canonical dense ids -> sort build ids
-  probe:  vectorized binary search (searchsorted left/right) -> per-probe
-          match counts -> prefix-sum expansion -> two gathers
+  build:  normalize keys -> dense ids -> group the build rows by id
+  probe:  (lo, counts) per probe row into that grouping -> prefix-sum
+          expansion -> two gathers
 
-Everything is a sort, a searchsorted, a cumsum, or a gather — all
-XLA-native, all static-shape.  The expansion output is a static capacity
-with a ``total`` scalar; overflow means the host re-runs at the next bucket
+Three lookups share the (lo, counts) -> ``expand_matches``/``semi_mask``
+contract; the build picks one from the key types and the live key span
+(exec/joinop.py HashBuildOperator.finish):
+
+- **dense** (``build_dense_index`` / ``probe_dense``): integer ids whose
+  span fits ``DENSE_INDEX_MAX_SLOTS`` address an int32 [size, 2] index
+  of (start, count) directly.  A probe is one row gather: no loop, no
+  search.  On one v5e at TPC-H Q3's SF1 shapes a 65536-row probe takes
+  0.4-1.0 ms against 29 ms through the hash table and 33 ms by binary
+  search (PERF.md, PR 30).
+- **sorted** (``build_index`` / ``probe_counts``): sort the build ids,
+  then per call either an in-call histogram (span fits a scratch sized
+  by the batch) or a vectorized binary search.  Serves integer keys too
+  sparse for the index.
+- **hash** (``ops/hashtable.py pages_hash_build`` / ``pages_hash_probe``,
+  gated ``EngineConfig.device_join_probe``): the PagesHash table proper
+  over raw normalized key words with the 1-byte hash-prefix reject of
+  ``PagesHash.java:49``.  It probes by EQUALITY, not order, so arbitrary
+  multi-channel key types stream without the canonical union-sort
+  materialization; a probe costs the longest hash chain of its batch.
+
+Everything is a sort, a scatter, a cumsum, or a gather — all XLA-native,
+all static-shape.  The expansion output is a static capacity with a
+``total`` scalar; overflow means the host re-runs at the next bucket
 (same policy as groupby).  Duplicate build keys need no PositionLinks
-chains: they are adjacent runs in the sorted order.
+chains: they are adjacent runs in the grouped order.
 
-Multi-channel keys are canonicalized into dense int64 ids by sorting the
-UNION of build and probe keys (exact, collision-free — no hash needed),
-after which matching is single-word.  Null join keys never match (SQL
-semantics), encoded as distinct negative sentinels per side.
+Multi-channel keys that do not pack are canonicalized into dense int64
+ids by sorting the UNION of build and probe keys (exact, collision-free —
+no hash needed), after which matching is single-word.  Null join keys
+never match (SQL semantics), encoded as distinct negative sentinels per
+side.
 
 Join variants mirror LookupJoinOperators.java:45-60: inner, probe-outer
 (left), semi, anti; build-side-outer composes from ``matched_build``.
-
-A second lookup tier now exists beside the sorted index: the
-**PagesHash** table proper (``ops/hashtable.py pages_hash_build`` /
-``pages_hash_probe``, gated ``EngineConfig.device_join_probe``) — an
-open-addressing table over the build side's raw normalized key words
-with the 1-byte hash-prefix reject of ``PagesHash.java:49``.  It probes
-by EQUALITY, not order, so arbitrary multi-channel key types stream
-without this module's canonical union-sort materialization, and a probe
-costs its hash-chain length instead of a ~20-step binary search.  Both
-tiers share the (lo, counts) -> ``expand_matches``/``semi_mask``
-contract below; duplicate build keys are grouped runs either way (by
-sort order here, by slot-grouped permutation there), filling the
-``PositionLinks`` role without chains.
 """
 
 from __future__ import annotations
@@ -246,6 +255,50 @@ def probe_counts(sorted_build: jax.Array, perm_b: jax.Array,
         return lo_.astype(jnp.int64), cnt.astype(jnp.int64)
 
     return jax.lax.cond(fits, dense, search, 0)
+
+
+#: Largest direct-address index a build publishes, in slots: int32
+#: [slots, 2] is 128 MB at this many, under 1% of a v5e's HBM.  Integer
+#: keys whose live span (in id space) fits take the index; wider spans
+#: keep the sorted / hash tiers.
+DENSE_INDEX_MAX_SLOTS = 1 << 24
+
+
+def dense_index_size(id_span: int) -> Optional[int]:
+    """Static slot count for a direct-address index over ids in
+    ``[0, id_span)``: the power-of-two bucket of the span (so repeated
+    builds of one table share a program), or None over the bound."""
+    if id_span > DENSE_INDEX_MAX_SLOTS:
+        return None
+    return max(1 << max(id_span - 1, 0).bit_length(), 1 << 10)
+
+
+def build_dense_index(build_ids: jax.Array, size: int):
+    """Direct-address lookup index over build ids in ``[0, size)`` (dead
+    rows carry a negative sentinel): ``(index, perm)``, both int32.
+    ``index[id]`` is ``(start, count)``, the run of that id in ``perm``,
+    the build rows grouped by id with dead rows last — the (lo, counts)
+    -> ``expand_matches`` contract with no sorted id array and no
+    search: a scatter-add, a cumsum over the slots and one int32 sort of
+    the build rows.  The two columns are stacked because one row gather
+    from [size, 2] measured 0.38 ms for 65536 probes into 8 M slots on
+    v5e against 0.98 ms for two gathers from [size] (PERF.md, PR 30)."""
+    off = jnp.where(build_ids >= 0, build_ids, size).astype(jnp.int32)
+    counts = jnp.zeros(size, jnp.int32).at[off].add(1, mode="drop")
+    starts = jnp.cumsum(counts, dtype=jnp.int32) - counts
+    perm = jnp.argsort(off, stable=True).astype(jnp.int32)
+    return jnp.stack([starts, counts], axis=1), perm
+
+
+def probe_dense(index: jax.Array, probe_ids: jax.Array):
+    """Per-probe-row match range through a ``build_dense_index``: one
+    row gather, no loop, no search.  Ids outside ``[0, size)`` (dead
+    rows, keys past the build's maximum) match nothing."""
+    size = index.shape[0]
+    in_rng = (probe_ids >= 0) & (probe_ids < size)
+    q = jnp.clip(probe_ids, 0, size - 1).astype(jnp.int32)
+    hit = jnp.where(in_rng[:, None], index[q], 0).astype(jnp.int64)
+    return hit[:, 0], hit[:, 1]
 
 
 def _expand_probe_idx(emit: jax.Array, out_capacity: int):

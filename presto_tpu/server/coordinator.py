@@ -2286,6 +2286,7 @@ class QueryExecution:
         from presto_tpu.exec.context import (
             host_and_xla_line as _host_and_xla_line,
             hot_operator_lines as _hot_operator_lines,
+            kernel_tier_lines as _kernel_tier_lines,
         )
         from presto_tpu.sql.plan import format_plan
 
@@ -2363,6 +2364,9 @@ class QueryExecution:
         lines.extend(self._boundary_footer(dplan))
         lines.extend(self._device_resume_footer())
         lines.extend(_hot_operator_lines(hot))
+        lines.extend(_kernel_tier_lines(
+            s for infos in self._task_infos.values() for info in infos
+            for s in info.get("operatorStats") or []))
         qs = self.query_stats
         if qs:
             lines.append(

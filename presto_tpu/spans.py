@@ -309,7 +309,8 @@ def _operator_entry(stats: Dict) -> Dict:
                       + stats.get("finish_wall_ns", 0)) / 1e9,
             "inputRows": stats.get("input_rows", 0),
             "outputRows": stats.get("output_rows", 0),
-            "jitDispatches": stats.get("jit_dispatches", 0)}
+            "jitDispatches": stats.get("jit_dispatches", 0),
+            "kernelTier": stats.get("kernel_tier", "")}
 
 
 def validate_span_tree(tree: Dict) -> List[str]:
@@ -372,6 +373,14 @@ def render_span_tree(tree: Dict, width: int = 40) -> List[str]:
                 lines.append(f"  {label:<30}  {' ' * width}  "
                              f"{seconds * 1000:>9.1f} ms"
                              + (f"  x{count}" if count else ""))
+        tiers: Dict[str, int] = {}
+        for op in node.get("attributes", {}).get("operators") or []:
+            if op.get("kernelTier"):
+                tiers[op["kernelTier"]] = tiers.get(op["kernelTier"], 0) + 1
+        if tiers:
+            label = ("  " * (depth + 1) + "kernel tiers")[:30]
+            lines.append(f"  {label:<30}  " + ", ".join(
+                f"{tier} x{n}" for tier, n in sorted(tiers.items())))
 
     walk(tree, 0)
     return lines

@@ -261,7 +261,7 @@ def test_task_spans_carry_operator_busy_time(served, name):
         assert ops
         for op in ops:
             assert set(op) == {"operator", "wallS", "inputRows",
-                               "outputRows", "jitDispatches"}
+                               "outputRows", "jitDispatches", "kernelTier"}
             assert op["wallS"] >= 0
         assert "jitCompileNs" not in task["attributes"]
     dispatched = sum(op["jitDispatches"]
@@ -269,6 +269,21 @@ def test_task_spans_carry_operator_busy_time(served, name):
                      for op in task["attributes"]["operators"])
     assert dispatched == \
         served[name]["detail"]["queryStats"]["jit_dispatches"]
+
+
+def test_q3_span_tree_names_the_join_tier(served):
+    """Both of Q3's joins, in every task that builds or probes one, took
+    the direct-address index: the span tree says so per operator."""
+    tiers = {}
+    for task in _tasks(served["q3"]["tree"]):
+        for op in task["attributes"]["operators"]:
+            kind = op["operator"].rsplit(".", 1)[-1]
+            if kind == "HashBuildOperator" or (
+                    kind == "FusedSegmentOperator" and op["kernelTier"]):
+                tiers.setdefault(kind, []).append(op["kernelTier"])
+    assert len(tiers["HashBuildOperator"]) >= 2
+    assert len(tiers["FusedSegmentOperator"]) >= 2
+    assert {t for ts in tiers.values() for t in ts} == {"dense"}
 
 
 @pytest.mark.parametrize("name", ["q1", "q3"])
@@ -312,6 +327,9 @@ def test_render_prints_one_line_per_kind_per_task(served):
     assert len(host) == per_task
     assert any("host:exchange_wait" in ln for ln in host)
     assert all(" x" in ln for ln in host)
+    # and, for a task whose operators took a join or group-by tier, one
+    # line that counts them
+    assert any("kernel tiers" in ln and "dense x" in ln for ln in lines)
     # a replayed event has the totals and no counts
     replayed = [ln for ln in
                 spans.render_span_tree(served["q3"]["event"]["spans"])

@@ -145,6 +145,23 @@ def test_join_build_index_and_probe_counts(chip):
                  chip.spec(SMALL, jnp.int32), chip.spec(SMALL, jnp.int64))
 
 
+def test_join_dense_index_build_and_probe(chip):
+    """The direct-address join tier at Q3's SF1 shapes: one worker's
+    build of 128K rows over o_orderkey's span (1 << 23 slots), probed by
+    a 64K batch.  The build's one sort is over int32 offsets (its
+    compile, like every sort's, grows with the length: half a minute
+    here); the probe is one row gather."""
+    from presto_tpu.ops.join import build_dense_index, probe_dense
+
+    size = 1 << 23
+    built = chip.compile(lambda ids: build_dense_index(ids, size),
+                         chip.spec(2 * ROWS, jnp.int64))
+    # the index and the perm, and nothing else, leave the program
+    assert built.memory_analysis().output_size_in_bytes < 8 * size + (1 << 20)
+    chip.compile(probe_dense, chip.spec((size, 2), jnp.int32),
+                 chip.spec(ROWS, jnp.int64))
+
+
 def _q1_aggs(values, valid=None):
     return [("sum", values, valid), ("sum", values, valid),
             ("count", None, None)]

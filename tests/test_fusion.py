@@ -666,10 +666,41 @@ def test_q3_probe_absorbed_into_segment(runner_on):
     assert all(s.factory.join_type == "inner" for s in probes)
 
 
+def _join_tiers(task):
+    """operator kind -> the kernel tiers its instances report, for the
+    operators that build or probe a join."""
+    tiers = {}
+    for s in task.operator_stats:
+        kind = s.operator.split(".")[-1]
+        if s.kernel_tier and kind in ("HashBuildOperator",
+                                      "LookupJoinOperator",
+                                      "FusedSegmentOperator"):
+            tiers.setdefault(kind, []).append(s.kernel_tier)
+    return tiers
+
+
+def test_q3_joins_take_the_dense_index(runner_on):
+    """Q3's keys are BIGINT with spans far under the bound: both builds
+    publish the direct-address index and both absorbed probes read it,
+    whatever the backend."""
+    runner_on.execute(QUERIES[3])
+    assert _join_tiers(runner_on._last_task) == {
+        "HashBuildOperator": ["dense", "dense"],
+        "FusedSegmentOperator": ["dense", "dense"]}
+
+
+def test_explain_analyze_counts_the_join_tiers(runner_on):
+    res = runner_on.execute("explain analyze " + QUERIES[3])
+    text = "\n".join(r[0] for r in res.rows)
+    assert ("dense: FusedSegmentOperator x2, HashBuildOperator x2"
+            in text.split("kernel tiers: ")[1].splitlines()[0])
+
+
 def test_device_join_probe_off_restores_pr9_lowering(runner_on):
     """device_join_probe=false must reproduce the PR 9 chains exactly:
-    no ProbeStage anywhere, probe operators back in the chain, and the
-    build side building the sorted index (mode != 'hash')."""
+    no ProbeStage anywhere, probe operators back in the chain; the
+    builds still publish the direct-address index (the tier follows the
+    keys, not this option), and the stand-alone probes read it."""
     from presto_tpu.exec.fusion import ProbeStage
     from presto_tpu.exec.joinop import LookupJoinOperatorFactory
 
@@ -684,10 +715,9 @@ def test_device_join_probe_off_restores_pr9_lowering(runner_on):
                                for s in f.stages)
     r = LocalQueryRunner.tpch(scale=0.01, config=cfg)
     r.execute(QUERIES[3])
-    join_tiers = [s.kernel_tier for s in r._last_task.operator_stats
-                  if s.kernel_tier and ("Build" in s.operator
-                                        or "LookupJoin" in s.operator)]
-    assert join_tiers and "hash" not in join_tiers
+    assert _join_tiers(r._last_task) == {
+        "HashBuildOperator": ["dense", "dense"],
+        "LookupJoinOperator": ["dense", "dense"]}
 
 
 def test_all_new_knobs_off_restores_pr9_chain_shapes(runner_on):

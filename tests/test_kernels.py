@@ -266,6 +266,203 @@ def test_join_overflow_reports_total():
 
 
 # ---------------------------------------------------------------------------
+# the direct-address index against the sorted and the hash tiers
+# ---------------------------------------------------------------------------
+# key tuples per row, None = SQL NULL: (build rows, probe rows)
+_EDGE = 1 << 10       # the smallest bucket of ops.join.dense_index_size
+LOOKUP_CASES = {
+    "unique": ([(5,), (1,), (9,), (3,)],
+               [(1,), (2,), (3,), (9,), (9,), (10,)]),
+    "duplicates": ([(2,), (2,), (3,), (2,), (7,), (3,)],
+                   [(3,), (2,), (4,), (7,), (2,)]),
+    "negative": ([(-5,), (-1,), (-5,), (4,)],
+                 [(-5,), (-6,), (4,), (0,), (-1,)]),
+    "null_build": ([(1,), (None,), (2,)], [(1,), (2,), (3,)]),
+    "null_probe": ([(1,), (2,)], [(None,), (1,), (None,), (2,), (5,)]),
+    "empty_build": ([], [(1,), (2,)]),
+    "below_and_above": ([(10,), (20,), (20,)],
+                        [(9,), (-100,), (21,), (1 << 40,), (10,), (20,)]),
+    # 'single' ids start at 2: keys 0.._EDGE-3 fill the bucket exactly,
+    # one more key takes the next bucket
+    "bucket_edge_fits": ([(0,), (_EDGE - 3,)],
+                         [(0,), (_EDGE - 3,), (_EDGE - 2,), (_EDGE,)]),
+    "bucket_edge_over": ([(0,), (_EDGE - 2,)],
+                         [(0,), (_EDGE - 3,), (_EDGE - 2,), (_EDGE,)]),
+    "over_the_bound": ([(0,), (3,), (J.DENSE_INDEX_MAX_SLOTS,)],
+                       [(0,), (J.DENSE_INDEX_MAX_SLOTS,), (7,)]),
+    "packed_two_channel": ([(1, 10), (1, 20), (2, 10), (2, 10), (3, -4)],
+                           [(1, 10), (2, 10), (2, 20), (1, 20), (3, 10),
+                            (0, 10), (1, None), (3, -4)]),
+}
+_DENSE_SIZES = {"bucket_edge_fits": _EDGE, "bucket_edge_over": 2 * _EDGE,
+                "over_the_bound": None}
+
+
+def _is_null(key):
+    return any(k is None for k in key)
+
+
+def _ref_matches(bkeys, pkeys):
+    """probe row -> the build rows with an equal, non-null key."""
+    return {j: [i for i, b in enumerate(bkeys)
+                if not _is_null(b) and not _is_null(p) and b == p]
+            for j, p in enumerate(pkeys)}
+
+
+def _key_cols(keys, width, cap):
+    """[(values, valid|None, type)] per key channel, padded to ``cap``."""
+    cols = []
+    for c in range(width):
+        vals = [0 if k[c] is None else k[c] for k in keys]
+        valid = [k[c] is not None for k in keys]
+        cols.append((jnp.asarray(pad_to(np.asarray(vals, np.int64), cap)),
+                     None if all(valid)
+                     else jnp.asarray(pad_to(np.asarray(valid, bool), cap)),
+                     T.BIGINT))
+    return cols
+
+
+def _tier_ranges(bkeys, pkeys):
+    """{tier: (lo, counts, perm)} of the three lookups over one build
+    and one probe batch, through the id arithmetic the operators use."""
+    from presto_tpu.exec.joinop import _ids_from_pairs, _packed_ids
+    from presto_tpu.ops.hashtable import pages_hash_build, pages_hash_probe
+
+    width = len((bkeys or pkeys)[0])
+    cap = 16
+    bcols = _key_cols(bkeys, width, cap)
+    pcols = _key_cols(pkeys, width, cap)
+    nb, np_ = jnp.asarray(len(bkeys)), jnp.asarray(len(pkeys))
+    live = [k for k in bkeys if not _is_null(k)]
+    los = np.asarray([min((k[c] for k in live), default=0)
+                      for c in range(width)], np.int64)
+    his = np.asarray([max((k[c] for k in live), default=0)
+                      for c in range(width)], np.int64)
+    strides, span = [], 1
+    for lo, hi in zip(los, his):
+        strides.append(span)
+        span *= int(hi) - int(lo) + 1
+    strides = np.asarray(strides, np.int64)
+    id_base = 2 if width == 1 else 0
+    bids, _ = _packed_ids([(v, g) for v, g, _ in bcols], jnp.asarray(los),
+                          jnp.asarray(strides), nb)
+    bids = jnp.where(bids >= 0, bids + id_base, bids)
+    if width == 1:
+        pids = _ids_from_pairs(jnp, [(v, g) for v, g, _ in pcols], [0],
+                               "single", jnp.asarray(los[0]), None, None,
+                               np_)
+    else:
+        pids = _ids_from_pairs(jnp, [(v, g) for v, g, _ in pcols],
+                               list(range(width)), "packed", los, strides,
+                               his, np_)
+    out = {}
+    sb, perm = J.build_index(bids)
+    out["sorted"] = J.probe_counts(sb, perm, pids) + (perm,)
+    table = pages_hash_build(bcols, nb, 64)
+    assert bool(table[7])
+    lo, counts, _ = pages_hash_probe(table[:5], pcols, np_)
+    out["hash"] = (lo, counts, table[5])
+    size = J.dense_index_size(span + id_base)
+    if size is not None:
+        index, perm = J.build_dense_index(bids, size)
+        out["dense"] = J.probe_dense(index, pids) + (perm,)
+    return out, size
+
+
+@pytest.mark.parametrize("case", sorted(LOOKUP_CASES))
+def test_probe_dense_parity_with_sorted_and_hash(case):
+    """Every tier answers (lo, counts) into its own perm: the counts and
+    the build rows each probe row reaches must be the same three times,
+    and what a nested loop says."""
+    bkeys, pkeys = LOOKUP_CASES[case]
+    tiers, size = _tier_ranges(bkeys, pkeys)
+    if case in _DENSE_SIZES:
+        assert size == _DENSE_SIZES[case]
+    assert ("dense" in tiers) == (case != "over_the_bound")
+    want = _ref_matches(bkeys, pkeys)
+    for tier, (lo, counts, perm) in tiers.items():
+        lo, counts, perm = (np.asarray(x) for x in (lo, counts, perm))
+        assert counts[len(pkeys):].tolist() == [0] * (16 - len(pkeys)), tier
+        for j in range(len(pkeys)):
+            got = sorted(perm[lo[j]:lo[j] + counts[j]].tolist())
+            assert got == want[j], (tier, j)
+
+
+JOIN_KINDS = ["inner", "left", "semi", "anti", "notin"]
+
+
+def _ref_join_rows(bkeys, pkeys, kind):
+    m = _ref_matches(bkeys, pkeys)
+    rows_p = range(len(pkeys))
+    if kind == "inner":
+        return sorted((j, i) for j in rows_p for i in m[j])
+    if kind == "left":
+        return sorted([(j, i) for j in rows_p for i in m[j]]
+                      + [(j, None) for j in rows_p if not m[j]],
+                      key=lambda r: (r[0], -1 if r[1] is None else r[1]))
+    if kind == "semi":
+        return [(j,) for j in rows_p if m[j]]
+    if kind == "anti":
+        return [(j,) for j in rows_p if not m[j]]
+    if not bkeys:                       # NOT IN an empty set: every row
+        return [(j,) for j in rows_p]
+    if any(_is_null(b) for b in bkeys):  # a NULL in the set: UNKNOWN
+        return []
+    return [(j,) for j in rows_p if not _is_null(pkeys[j]) and not m[j]]
+
+
+@pytest.mark.parametrize("kind", JOIN_KINDS)
+@pytest.mark.parametrize("case", sorted(LOOKUP_CASES))
+def test_join_operators_through_the_dense_index(case, kind):
+    """The same cases through HashBuildOperator + LookupJoinOperator:
+    the build publishes the index exactly where the span fits, the
+    stand-alone probe kernels read it, and every join type answers what
+    a nested loop answers (NOT IN with a NULL in the build included)."""
+    from presto_tpu.batch import batch_from_pylist
+    from presto_tpu.exec.driver import Pipeline
+    from presto_tpu.exec.joinop import (
+        HashBuildOperatorFactory, LookupJoinOperatorFactory,
+    )
+    from presto_tpu.exec.operators import (
+        OutputCollectorFactory, ValuesOperatorFactory,
+    )
+    from presto_tpu.exec.runner import execute_pipelines
+
+    bkeys, pkeys = LOOKUP_CASES[case]
+    width = len((bkeys or pkeys)[0])
+    schema = [T.BIGINT] * (width + 1)          # keys..., row number
+    chans = list(range(width))
+    build = HashBuildOperatorFactory(chans, schema)
+    bp = Pipeline([
+        ValuesOperatorFactory(
+            [batch_from_pylist(schema, [k + (i,)
+                                        for i, k in enumerate(bkeys)])]
+            if bkeys else []),
+        build], name="build")
+    out = OutputCollectorFactory()
+    pp = Pipeline([
+        ValuesOperatorFactory([batch_from_pylist(
+            schema, [k + (j,) for j, k in enumerate(pkeys)])]),
+        LookupJoinOperatorFactory(
+            build, chans, schema,
+            "anti" if kind == "notin" else kind,
+            null_aware=(kind == "notin")),
+        out], name="probe")
+    task = execute_pipelines([bp, pp])
+    if kind in ("inner", "left"):
+        got = sorted(((r[width], r[2 * width + 1]) for r in out.rows()),
+                     key=lambda r: (r[0], -1 if r[1] is None else r[1]))
+    else:
+        got = sorted((r[width],) for r in out.rows())
+    assert got == _ref_join_rows(bkeys, pkeys, kind)
+    tiers = {s.operator.split(".")[-1]: s.kernel_tier
+             for s in task.operator_stats if s.kernel_tier}
+    want_tier = "sorted" if case == "over_the_bound" else "dense"
+    assert tiers == {"HashBuildOperator": want_tier,
+                     "LookupJoinOperator": want_tier}
+
+
+# ---------------------------------------------------------------------------
 # filter / sort / hash
 # ---------------------------------------------------------------------------
 
