@@ -228,6 +228,15 @@ class TaskStats:
     xla_trace_lower_ns: int = 0
     xla_cache_hits: int = 0
 
+    def take_activity(self, recorder: HostActivity) -> None:
+        """The recorder's totals as this task's host and XLA accounts."""
+        self.host_ns = dict(recorder.total_ns)
+        xla = recorder.xla
+        self.xla_builds = xla["builds"]
+        self.xla_build_ns = xla["build_ns"]
+        self.xla_trace_lower_ns = xla["trace_lower_ns"]
+        self.xla_cache_hits = xla["cache_hits"]
+
     def add_operator(self, s: OperatorStats) -> None:
         self.wall_ns += s.wall_ns + s.finish_wall_ns
         self.input_rows += s.input_rows
@@ -475,12 +484,7 @@ class TaskContext:
         for s in list(self.operator_stats):
             ts.add_operator(s)
         ts.peak_memory_bytes = self.memory.peak
-        ts.host_ns = dict(self.activity.total_ns)
-        xla = self.activity.xla
-        ts.xla_builds = xla["builds"]
-        ts.xla_build_ns = xla["build_ns"]
-        ts.xla_trace_lower_ns = xla["trace_lower_ns"]
-        ts.xla_cache_hits = xla["cache_hits"]
+        ts.take_activity(self.activity)
         return ts
 
     def jit_counters(self) -> Dict[str, int]:

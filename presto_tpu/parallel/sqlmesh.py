@@ -75,8 +75,15 @@ _MESH_PRIMS = ("sum", "count", "min", "max")
 # misses, and compile wall land on /metrics (the generated-class-cache
 # role at whole-query granularity)
 from presto_tpu import kernelcache as _kc  # noqa: E402
+from presto_tpu.spans import activity  # noqa: E402
 
 _PROGRAM_CACHE = _kc.new_cache("mesh_program")
+
+#: rows a page of a scan's first build is generated in: the connector's
+#: numpy temporaries stay in reused allocations at this size (TPC-H
+#: lineitem on the chip's host: 3.7 M rows/s, against 0.56 M rows/s in
+#: pages of 1 << 24, which at SF10 is 16 s against 107 s of set-up)
+_SCAN_BATCH_ROWS = 1 << 20
 
 #: fragments actually traced/lowered into SPMD programs, process-wide —
 #: the mesh-tier mirror of ``sql.physical.FRAGMENTS_LOWERED``.  The
@@ -594,9 +601,10 @@ class _MeshProgram:
         handle = conn.get_table(node.table)
         splits = conn.get_splits(handle, 1)
         batches = []
-        for split in splits:
-            batches.extend(conn.page_source(split, list(node.column_names),
-                                            1 << 24))
+        with activity("generate"):
+            for split in splits:
+                batches.extend(conn.page_source(
+                    split, list(node.column_names), _SCAN_BATCH_ROWS))
         if batches:
             b = (concat_batches(batches) if len(batches) > 1
                  else batches[0]).to_numpy()
@@ -707,9 +715,10 @@ class _MeshProgram:
                 in_specs=tuple(PS(AXIS) for _ in self.inputs),
                 out_specs=tuple(PS(AXIS) for _ in range(n_out)),
                 check_vma=False)
-            self._args = [
-                jax.device_put(a, row_sharding(self.runner.mesh, 1))
-                for a in self.inputs]
+            with activity("stage_h2d"):
+                self._args = [
+                    jax.device_put(a, row_sharding(self.runner.mesh, 1))
+                    for a in self.inputs]
             # AOT-compile and keep the loaded executable: the plain
             # jit dispatch path can lose the trace-time constant buffers
             # when several whole-query programs coexist in one process
@@ -728,22 +737,31 @@ class _MeshProgram:
             t2 = _time.time()
             self.compile_ns += cstats.jit_compile_ns
             self.build_spans = {"lower": (t0, t1), "compile": (t1, t2)}
-        out = self._jitted(*self._args)
+        with activity("dispatch"):
+            out = self._jitted(*self._args)
         # Read only the control outputs eagerly: the content arrays are
         # full static capacity regardless of how few rows are live (the
-        # per-transfer cost is not measured on the chip).
-        of = bool(np.asarray(out[-4]).any())
+        # per-transfer cost is not measured on the chip).  The first read
+        # waits for the program to finish; the host blocks in one
+        # ``device_wait`` bracket for all of them (a bracket costs host
+        # time under the executor lock, where it counts once a client).
+        with activity("device_wait"):
+            of = bool(np.asarray(out[-4]).any())
+            if of:
+                flags = np.asarray(out[-2]).reshape(self.nparts, -1)
+            else:
+                err = bool(np.asarray(out[-3]).any())
+                stats = np.asarray(out[-1])
+                live_g = np.asarray(out[-5])
         if of:
-            flags = np.asarray(out[-2]).reshape(self.nparts, -1)
             self.overflow_labels = [
                 lbl for i, lbl in enumerate(self._flag_labels)
                 if flags[:, i].any()]
             return Batch((), 0), True
-        if bool(np.asarray(out[-3]).any()):
+        if err:
             raise ValueError(
                 "scalar subquery returned more than one row")
-        self._read_shard_stats(out[-1])
-        live_g = np.asarray(out[-5])
+        self._read_shard_stats(stats)
         cap = live_g.shape[0] // self.nparts
         if self.root_fid != self.dplan.root_fragment_id \
                 and not self._root_replicated:
@@ -781,9 +799,11 @@ class _MeshProgram:
         live_pg = live_g.reshape(P, cap).astype(bool)
         n_live = int(live_pg.sum())
         cols = []
+        with activity("device_wait"):
+            content = [np.asarray(a) for a in out[:2 * len(self._out_meta)]]
         for i, (typ, d) in enumerate(self._out_meta):
-            vals_g = np.asarray(out[2 * i]).reshape(P, cap)
-            valid_g = np.asarray(out[2 * i + 1]).reshape(P, cap)
+            vals_g = content[2 * i].reshape(P, cap)
+            valid_g = content[2 * i + 1].reshape(P, cap)
             vals = np.concatenate([vals_g[p][live_pg[p]]
                                    for p in range(P)])
             valid = np.concatenate([valid_g[p][live_pg[p]]
@@ -819,18 +839,21 @@ class _MeshProgram:
 
             fn = _kc.jit(slicer, "mesh_slice")
             self._slicers[(bucket, layout)] = fn
-        stacked = [np.asarray(a) for a in fn(tuple(arrays), out[-5])]
+        with activity("dispatch"):
+            compacted = fn(tuple(arrays), out[-5])
+        with activity("device_wait"):
+            stacked = [np.asarray(a) for a in compacted]
         host: List[Optional[np.ndarray]] = [None] * len(arrays)
         for (_, idxs), mat in zip(layout, stacked):
             for row, i in enumerate(idxs):
                 host[i] = mat[row]
         return host
 
-    def _read_shard_stats(self, stats_out) -> None:
+    def _read_shard_stats(self, stats_out: np.ndarray) -> None:
         """Parse the program's stats vector output ([P*S] -> [P, S])
         into per-key per-shard int lists; same-key entries (several
         scans in one fragment) sum."""
-        raw = np.asarray(stats_out).reshape(self.nparts, -1)
+        raw = stats_out.reshape(self.nparts, -1)
         folded: Dict[tuple, np.ndarray] = {}
         order: List[tuple] = []
         for i, key in enumerate(self._stat_keys):

@@ -43,7 +43,9 @@ from presto_tpu.server.errortracker import (
     RemoteRequestError, RequestErrorTracker,
 )
 from presto_tpu.server.fragmenter import DistributedPlan, Fragmenter
-from presto_tpu.spans import HOST_ACTIVITY_HEADER
+from presto_tpu.spans import (
+    HOST_ACTIVITY_HEADER, HostActivity, activity, set_current_activity,
+)
 from presto_tpu.sql import tree as t
 from presto_tpu.sql.optimizer import optimize
 from presto_tpu.sql.parser import parse_statement
@@ -978,10 +980,17 @@ class QueryExecution:
         collector = None
         if cfg.mesh_progress_beacons:
             collector = self._device_beacon_collector(n_bound, nparts, cfg)
+        # this query has no task threads: what its own thread does inside
+        # the execute phase (spans.ACTIVITY_KINDS, bracketed here and in
+        # parallel/sqlmesh.py) is recorded for the root fragment's task
+        recorder = HostActivity()
+        previous = set_current_activity(recorder)
         try:
             with self._mark("execute"):
                 exec_t0 = ev.now()
-                with self.co.mesh_executor_lock:
+                with activity("lock_wait"):
+                    self.co.mesh_executor_lock.acquire()
+                try:
                     runner = self.co.mesh_executor(cfg, nparts)
                     ctx = (beacons.install(collector)
                            if collector is not None
@@ -993,6 +1002,8 @@ class QueryExecution:
                         else:
                             result = runner.execute_dplan(dplan, key)
                     info = dict(runner.last_run_info)
+                finally:
+                    self.co.mesh_executor_lock.release()
                 exec_t1 = ev.now()
         except _DeviceDegradeToHttp as e:
             # resume budget spent (or mesh_resume_mode='http'): degrade
@@ -1027,6 +1038,8 @@ class QueryExecution:
                         f"({type(e).__name__}: {e}); falling back to the "
                         f"task-scheduled plane")
             return fallback(f"{type(e).__name__}: {e}", "execution_error")
+        finally:
+            set_current_activity(previous)
         self.result_rows = [tuple(r) for r in result.rows]
         boundaries = info.get("boundaries", [])
         self.exchange_modes = {"device": len(boundaries) or n_bound}
@@ -1060,7 +1073,7 @@ class QueryExecution:
         for name, window in (info.get("build_spans") or {}).items():
             self._marks[name] = (float(window[0]), float(window[1]))
         self.co.count_device_success(boundaries)
-        self._fold_device_stats(dplan, info, (exec_t0, exec_t1))
+        self._fold_device_stats(dplan, info, (exec_t0, exec_t1), recorder)
         if collector is not None:
             self._settle_device_progress(collector)
         if analyze:
@@ -1344,7 +1357,8 @@ class QueryExecution:
         return completed
 
     def _fold_device_stats(self, dplan: DistributedPlan, info: Dict,
-                           window: Tuple[float, float]) -> None:
+                           window: Tuple[float, float],
+                           recorder: HostActivity) -> None:
         """Per-shard program counters -> synthetic TaskStats -> real
         per-fragment StageStats -> QueryStats: the SAME rollup shapes
         _rollup_stats builds from remote task info, so every downstream
@@ -1353,7 +1367,10 @@ class QueryExecution:
         query without knowing which tier ran it.  'single' fragments
         fold as ONE task (their per-shard copies are replicas, exactly
         like the HTTP plane schedules one task); the program's single
-        dispatch + compile attribution land on the root task."""
+        dispatch + compile attribution land on the root task, and so does
+        ``recorder``, what the query thread did inside the window: its
+        totals as the task's ``host_ns`` and XLA account, its intervals
+        as the task's final info (``spans()`` hangs them under it)."""
         from presto_tpu.exec.context import (
             QueryStats, StageStats, TaskStats,
         )
@@ -1403,6 +1420,7 @@ class QueryExecution:
                     ts.jit_compile_ns = int(info.get("compile_ns") or 0)
                     ts.peak_memory_bytes = max(
                         [int(v) for v in peak] or [0])
+                    ts.take_activity(recorder)
                 task_stats.setdefault(fid, []).append(ts.as_dict())
                 st.add_task(ts)
             stage_stats[fid] = st.as_dict()
@@ -1418,6 +1436,9 @@ class QueryExecution:
             self.stage_stats = stage_stats
             self.task_stats = task_stats
             self.query_stats = qs_dict
+            self._task_infos = {root_fid: [{
+                "taskId": f"{self.query_id}.{root_fid}.0",
+                "hostActivity": recorder.as_dict()}]}
 
     def _device_beacon_collector(self, n_bound: int, nparts: int, cfg):
         """Host-side sink for the in-program beacons: each NEW
@@ -1527,6 +1548,7 @@ class QueryExecution:
         time), but the fragment structure, stage lines, hot totals, and
         serving footer keep the same shape so the two tiers stay
         diffable."""
+        from presto_tpu.exec.context import host_and_xla_line
         from presto_tpu.sql.plan import format_plan
 
         nparts = max(int(info.get("nparts") or 1), 1)
@@ -1592,6 +1614,7 @@ class QueryExecution:
             f"compiles: {qs.get('jit_compiles', 0)} "
             f"({qs.get('jit_compile_ns', 0) / 1e6:.1f} ms compile); "
             f"trace token: {self.trace_token}")
+        lines.append(host_and_xla_line(qs))
         lines.append(
             f"serving: queued {qs.get('queued_s', 0.0):.3f} s, "
             f"execution {qs.get('execution_s', 0.0):.3f} s"

@@ -13,6 +13,8 @@ timestamps it already owns:
     └── stage-{fid}
         └── task {task_id} (attempt aN)   one span per task attempt
             └── generate / stage_h2d / dispatch / ...   host activity
+                (collective plane: what the query thread did inside
+                ``execute``, under the root fragment's task)
 
 What the host did inside a task is recorded by the task's
 ``HostActivity`` through ``activity(kind)`` (below): intervals on the
@@ -78,8 +80,13 @@ PHASES = ("queue", "parse", "analyze", "optimize", "fragment", "schedule",
 #: serialize     encoding + LZ4 of an exchange page, and decoding on the
 #:               consumer
 #: exchange_wait an exchange-fed operator parked until a page arrives
+#: lock_wait     a query of the collective plane waiting for
+#:               ``mesh_executor_lock`` (one SPMD program at a time)
+#: A query served by the collective plane has no task threads: its query
+#: thread records for the length of the ``execute`` phase, and the
+#: intervals hang under the root fragment's (synthetic) task span.
 ACTIVITY_KINDS = ("generate", "stage_h2d", "dispatch", "device_wait",
-                  "serialize", "exchange_wait")
+                  "serialize", "exchange_wait", "lock_wait")
 
 #: GET /v1/task/{id} with this header set to 1 adds ``hostActivity``, the
 #: task's intervals, to the info: the coordinator's final collection
