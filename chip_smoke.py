@@ -11,7 +11,10 @@ must build nothing, by the engine's own ``jit_compiles`` (kernel-cache
 misses) and by XLA's backend-compile event, which JAX raises around
 ``compile_or_get_cached``: it fires for a load from the persistent cache
 as well as for a compile, so the cold run's count is of programs built,
-not of cache misses.  A worker's scan batch must sit on a TPU device.
+not of cache misses.  The warm run must also scan nothing: every table
+scan of every leaf task is a hit of the device-resident scan cache
+(``scan_cache_hits`` / ``scan_cache_misses`` in ``queryStats``).  A
+worker's scan batch must sit on a TPU device.
 
 ``--chips 4``: only the collective data plane (``mesh_device_exchange``,
 four co-resident workers on one 4-device mesh), Q1 and Q3 at SF1 against
@@ -313,6 +316,9 @@ def one_chip(xla: XlaCompiles) -> None:
                 line[f"{temp}_wall_s"] = wall
                 line[f"{temp}_jit_compiles"] = int(stats["jit_compiles"])
                 line[f"{temp}_xla_compiles"] = len(compiled)
+                line[f"{temp}_scan_cache"] = [
+                    int(stats["scan_cache_hits"]),
+                    int(stats["scan_cache_misses"])]
                 line[f"{temp}_max_rel_err"] = compare(
                     f"{name} {temp}", rows, want[name])
                 line["rows"] = len(rows)
@@ -325,6 +331,12 @@ def one_chip(xla: XlaCompiles) -> None:
                     f"{name}: the warm run compiled "
                     f"({line['warm_jit_compiles']} jit, {len(compiled)} "
                     f"XLA: {sorted(set(compiled))})")
+            hits, misses = line["warm_scan_cache"]
+            if misses or not hits:
+                raise AssertionError(
+                    f"{name}: the warm run scanned a table the cold run "
+                    f"should have kept on the device ({hits} hits, "
+                    f"{misses} misses)")
     seen = set().union(*placement.values())
     emit({"phase": "placement",
           **{k: sorted(v) for k, v in sorted(placement.items())}})

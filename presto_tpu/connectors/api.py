@@ -163,6 +163,11 @@ class Connector:
 
     name: str = "connector"
 
+    #: True promises that a split yields the same rows every time and no
+    #: statement changes a table; both tiers then keep scanned tables on
+    #: the device (connectors/README.md).  Leave False where data changes
+    immutable_data: bool = False
+
     # -- metadata -------------------------------------------------------
     def list_tables(self) -> List[str]:
         raise NotImplementedError
@@ -293,6 +298,9 @@ class ConnectorRegistry:
 
     def catalogs(self) -> List[str]:
         return sorted(self._catalogs)
+
+    def connectors(self) -> List[Connector]:
+        return list(self._catalogs.values())
 
 
 def coerce_value(typ: T.Type, v: Any, lenient: bool = False) -> Any:

@@ -150,6 +150,15 @@ class OperatorStats:
     # tools/fusion_report.py, the span tree (kernelTier) and EXPLAIN
     # ANALYZE's "kernel tiers" line
     kernel_tier: str = ""
+    # the device-resident scan cache (exec/scancache.py), on the scan
+    # operator of a connector with ``immutable_data``: 1 hit where the
+    # pipeline's scan of its table was handed kept device batches (and
+    # the bytes of the columns handed over), 1 miss where it generated
+    # and staged (feed drivers are one scan: the first counts it).
+    # Both 0 for a table that can change: the cache never saw the scan
+    scan_cache_hits: int = 0
+    scan_cache_misses: int = 0
+    scan_cache_hit_bytes: int = 0
 
     def as_dict(self) -> Dict:
         return dataclasses.asdict(self)
@@ -194,6 +203,10 @@ class TaskStats:
     jit_compiles: int = 0
     jit_compile_ns: int = 0
     prereduce_rows: int = 0
+    # the scan cache's account (OperatorStats.scan_cache_*), summed
+    scan_cache_hits: int = 0
+    scan_cache_misses: int = 0
+    scan_cache_hit_bytes: int = 0
     peak_memory_bytes: int = 0
     # attempt-aware exchange dedup counters (sums across this task's
     # remote sources) + producer-side page accounting
@@ -247,6 +260,7 @@ class TaskStats:
         self.jit_compiles += s.jit_compiles
         self.jit_compile_ns += s.jit_compile_ns
         self.prereduce_rows += s.prereduce_rows
+        _add_scan_cache(self, s)
 
     def as_dict(self) -> Dict:
         return dataclasses.asdict(self)
@@ -274,6 +288,10 @@ class StageStats:
     jit_compiles: int = 0
     jit_compile_ns: int = 0
     prereduce_rows: int = 0
+    # the scan cache's account (OperatorStats.scan_cache_*), summed
+    scan_cache_hits: int = 0
+    scan_cache_misses: int = 0
+    scan_cache_hit_bytes: int = 0
     peak_memory_bytes: int = 0
     exchange_fetched: int = 0
     exchange_consumed: int = 0
@@ -300,6 +318,7 @@ class StageStats:
         self.jit_compiles += ts.jit_compiles
         self.jit_compile_ns += ts.jit_compile_ns
         self.prereduce_rows += ts.prereduce_rows
+        _add_scan_cache(self, ts)
         self.peak_memory_bytes = max(self.peak_memory_bytes,
                                      ts.peak_memory_bytes)
         self.exchange_fetched += ts.exchange_fetched
@@ -327,6 +346,13 @@ def _add_host_and_xla(into, other) -> None:
     into.xla_cache_hits += other.xla_cache_hits
 
 
+def _add_scan_cache(into, other) -> None:
+    """The scan cache's account, summed one level up."""
+    into.scan_cache_hits += other.scan_cache_hits
+    into.scan_cache_misses += other.scan_cache_misses
+    into.scan_cache_hit_bytes += other.scan_cache_hit_bytes
+
+
 @dataclasses.dataclass
 class QueryStats:
     """Whole-query rollup over stages (QueryStats role): the shape the
@@ -346,6 +372,10 @@ class QueryStats:
     jit_compiles: int = 0
     jit_compile_ns: int = 0
     prereduce_rows: int = 0
+    # the scan cache's account (OperatorStats.scan_cache_*), summed
+    scan_cache_hits: int = 0
+    scan_cache_misses: int = 0
+    scan_cache_hit_bytes: int = 0
     peak_memory_bytes: int = 0   # max single-task peak across the query
     exchange_fetched: int = 0
     exchange_consumed: int = 0
@@ -377,6 +407,7 @@ class QueryStats:
         self.jit_compiles += st.jit_compiles
         self.jit_compile_ns += st.jit_compile_ns
         self.prereduce_rows += st.prereduce_rows
+        _add_scan_cache(self, st)
         self.peak_memory_bytes = max(self.peak_memory_bytes,
                                      st.peak_memory_bytes)
         self.exchange_fetched += st.exchange_fetched
@@ -403,6 +434,15 @@ def host_and_xla_line(stats: Dict) -> str:
             f"({stats.get('xla_cache_hits', 0)} loaded) in "
             f"{stats.get('xla_build_ns', 0) / 1e6:.1f} ms, trace+lower "
             f"{stats.get('xla_trace_lower_ns', 0) / 1e6:.1f} ms")
+
+
+def scan_cache_line(stats: Dict) -> str:
+    """EXPLAIN ANALYZE's line for the scan cache's account of a
+    TaskStats / QueryStats dict: table scans by tasks that were handed
+    kept device batches, and those that generated and staged."""
+    return (f"scan cache: {stats.get('scan_cache_hits', 0)} hits "
+            f"({stats.get('scan_cache_hit_bytes', 0) / (1 << 20):.1f} MiB "
+            f"handed over), {stats.get('scan_cache_misses', 0)} misses")
 
 
 def kernel_tier_lines(ops) -> List[str]:

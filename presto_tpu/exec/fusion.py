@@ -580,8 +580,11 @@ class FusedSegmentOperator(Operator):
 
     def __init__(self, ctx: OperatorContext, stages: Sequence,
                  coalesce_rows: int, partition_spec, min_batch_capacity,
-                 agg_spec: Optional[PreReduceSpec] = None):
+                 agg_spec: Optional[PreReduceSpec] = None, scan_fill=None):
         super().__init__(ctx)
+        # the scan cache's record of what this segment stages for its
+        # scan (exec/scancache.py ScanFill); None off a cached table
+        self._scan_fill = scan_fill
         self.stages = list(stages)
         self.partition_spec = partition_spec
         self.agg_spec = agg_spec
@@ -641,6 +644,8 @@ class FusedSegmentOperator(Operator):
                 passthrough = self._passthrough_ok()
                 with activity("stage_h2d"):
                     batch = self._flush()
+                    if self._scan_fill is not None:
+                        batch = self._scan_fill.stage(batch)
                 if passthrough:
                     return self._emit(batch.compact())
                 return self._emit(self._dispatch(batch))
@@ -1224,18 +1229,31 @@ class FusedSegmentOperatorFactory(OperatorFactory):
 
     def __init__(self, stages: Sequence, coalesce_rows: int = 0,
                  partition_spec=None, min_batch_capacity: int = 1024,
-                 agg_spec: Optional[PreReduceSpec] = None):
+                 agg_spec: Optional[PreReduceSpec] = None, scan_fill=None):
         self.stages = list(stages)
         self.coalesce_rows = coalesce_rows
         self.partition_spec = partition_spec
         self.min_batch_capacity = min_batch_capacity
         self.agg_spec = agg_spec
+        self.scan_fill = scan_fill
 
     def create(self, ctx: OperatorContext) -> FusedSegmentOperator:
         return FusedSegmentOperator(ctx, self.stages, self.coalesce_rows,
                                     self.partition_spec,
                                     self.min_batch_capacity,
-                                    agg_spec=self.agg_spec)
+                                    agg_spec=self.agg_spec,
+                                    scan_fill=self.scan_fill)
+
+    def for_cached_scan(self, fill=None) -> "FusedSegmentOperatorFactory":
+        """This segment for one execution of a scan the scan cache
+        knows (exec/scancache.py).  A miss: the same segment, recording
+        what it stages into ``fill``.  A hit (no fill): it is fed the
+        kept device batches, so it coalesces nothing and dispatches each
+        as it comes (the ``_pending`` path)."""
+        return FusedSegmentOperatorFactory(
+            self.stages, self.coalesce_rows if fill is not None else 0,
+            self.partition_spec, self.min_batch_capacity, self.agg_spec,
+            scan_fill=fill)
 
     def describe(self) -> str:
         """Human-readable stage summary (tools/fusion_report.py)."""

@@ -24,6 +24,11 @@ from presto_tpu.exec.context import OperatorContext
 from presto_tpu.exec.operator import Operator, OperatorFactory
 
 
+class ConsumerFinished(Exception):
+    """Raised in a producer's ``put`` once the consumer has finished
+    without it (a LIMIT was met): the producer has nothing left to do."""
+
+
 class LocalExchange:
     """Deterministic N-producer rendezvous: the consumer drains batches
     in strict producer round-robin, so the DOWNSTREAM batch order is a
@@ -39,21 +44,32 @@ class LocalExchange:
         self._cursor = 0
         self._capacity = max(capacity // max(n_producers, 1), 2)
         self._error: Optional[BaseException] = None
+        self._consumer_finished = False
         self._cond = threading.Condition()
 
     def put(self, producer: int, batch: Batch) -> None:
         with self._cond:
             q = self._queues[producer]
-            while len(q) >= self._capacity and self._error is None:
+            while (len(q) >= self._capacity and self._error is None
+                   and not self._consumer_finished):
                 self._cond.wait(timeout=1.0)
             if self._error is not None:
                 raise self._error
+            if self._consumer_finished:
+                raise ConsumerFinished()
             q.append(batch)
             self._cond.notify_all()
 
     def producer_finished(self, producer: int) -> None:
         with self._cond:
             self._done[producer] = True
+            self._cond.notify_all()
+
+    def consumer_finished(self) -> None:
+        """The consumer's chain finished; producers still feeding stop
+        at their next ``put`` instead of waiting on a full queue."""
+        with self._cond:
+            self._consumer_finished = True
             self._cond.notify_all()
 
     def fail(self, exc: BaseException) -> None:

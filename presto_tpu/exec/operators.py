@@ -7,6 +7,7 @@ FilterAndProjectOperator.java:38 (+ compiled PageProcessor), LimitOperator
 
 from __future__ import annotations
 
+import collections
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -29,7 +30,8 @@ class TableScanOperator(SourceOperator):
     device (the LazyBlock-load + ConnectorPageSource.getNextPage path)."""
 
     def __init__(self, ctx: OperatorContext, connector: Connector,
-                 columns: Sequence[str], batch_rows: int, to_device: bool):
+                 columns: Sequence[str], batch_rows: int, to_device: bool,
+                 fill=None):
         super().__init__(ctx)
         self.connector = connector
         self.columns = list(columns)
@@ -38,6 +40,12 @@ class TableScanOperator(SourceOperator):
         self._splits: List[Split] = []
         self._no_more_splits = False
         self._iter = None
+        # the scan cache's record of this pipeline's miss
+        # (exec/scancache.py ScanFill); None for a table that can change
+        self._fill = fill
+        self._rows_out = 0
+        if fill is not None and fill.scan_opened():
+            ctx.stats.scan_cache_misses = 1
 
     def add_split(self, split: Split) -> None:
         self._splits.append(split)
@@ -65,13 +73,25 @@ class TableScanOperator(SourceOperator):
             if batch.num_rows == 0:
                 continue
             self.ctx.memory.set_bytes(batch.size_bytes)
+            self._rows_out += batch.num_rows
             if self.to_device:
-                return pad_batch(batch, self.ctx.config.min_batch_capacity)
+                batch = pad_batch(batch, self.ctx.config.min_batch_capacity)
+                if self._fill is not None:
+                    self._fill.add(batch)
             return batch
 
-    def is_finished(self) -> bool:
+    def _drained(self) -> bool:
         return (self._no_more_splits and not self._splits
-                and self._iter is None) or self._finishing
+                and self._iter is None)
+
+    def is_finished(self) -> bool:
+        return self._drained() or self._finishing
+
+    def close(self) -> None:
+        fill, self._fill = self._fill, None
+        if fill is not None:
+            fill.scan_closed(self._rows_out, self._drained())
+        super().close()
 
 
 class TableScanOperatorFactory(OperatorFactory):
@@ -79,16 +99,52 @@ class TableScanOperatorFactory(OperatorFactory):
 
     def __init__(self, connector: Connector, columns: Sequence[str],
                  batch_rows: int = 65536, to_device: bool = True,
-                 table: str = ""):
+                 table: str = "", fill=None):
         self.connector = connector
         self.columns = list(columns)
         self.batch_rows = batch_rows
         self.to_device = to_device
         self.table = table  # for grouped-execution bucket lookup
+        self.fill = fill    # one execution's ScanFill (exec/runner.py)
 
     def create(self, ctx: OperatorContext) -> TableScanOperator:
         return TableScanOperator(ctx, self.connector, self.columns,
-                                 self.batch_rows, self.to_device)
+                                 self.batch_rows, self.to_device,
+                                 fill=self.fill)
+
+    def filling(self, fill) -> "TableScanOperatorFactory":
+        """This scan for one execution that missed the scan cache."""
+        return TableScanOperatorFactory(
+            self.connector, self.columns, self.batch_rows, self.to_device,
+            self.table, fill=fill)
+
+
+class CachedScanOperator(Operator):
+    """A scan the scan cache answered (exec/scancache.py): hands over
+    the device batches an earlier scan of the same splits staged."""
+
+    def __init__(self, ctx: OperatorContext, hit):
+        super().__init__(ctx)
+        self._batches = collections.deque(hit.batches)
+        ctx.stats.scan_cache_hits = 1
+        ctx.stats.scan_cache_hit_bytes = hit.nbytes
+
+    def needs_input(self) -> bool:
+        return False
+
+    def get_output(self) -> Optional[Batch]:
+        return self._batches.popleft() if self._batches else None
+
+    def is_finished(self) -> bool:
+        return not self._batches or self._finishing
+
+
+class CachedScanOperatorFactory(OperatorFactory):
+    def __init__(self, hit):
+        self.hit = hit
+
+    def create(self, ctx: OperatorContext) -> CachedScanOperator:
+        return CachedScanOperator(ctx, self.hit)
 
 
 class ValuesOperator(Operator):

@@ -57,10 +57,60 @@ def _parallel_prefix(p: Pipeline, config: EngineConfig) -> int:
     return k
 
 
+def _through_scan_cache(p: Pipeline):
+    """``p`` as this execution runs it, and the scan cache's fill to
+    close when it ended (exec/scancache.py).  A pipeline that scans a
+    table of a connector with ``immutable_data`` either takes the device
+    batches an earlier scan of the same splits staged (a hit: the scan
+    prefix, its splits and so its feed drivers are gone, and the segment
+    that adopted the scan is fed device batches) or runs as it is and
+    records what it stages (a miss).  Every other pipeline comes back
+    untouched: a table that can change is never kept, and where the plan
+    needs the scan's order the cache stands aside, a kept run being in
+    the order its fill staged it."""
+    from presto_tpu.exec.fusion import FusedSegmentOperatorFactory
+    from presto_tpu.exec.operators import (
+        CachedScanOperatorFactory, TableScanOperatorFactory,
+    )
+    from presto_tpu.exec.scancache import SCAN_CACHE, ScanHit
+
+    scan = p.factories[0] if p.factories else None
+    if not (isinstance(scan, TableScanOperatorFactory) and p.splits
+            and scan.columns
+            and getattr(scan.connector, "immutable_data", False)):
+        return p, None
+    if any(getattr(f, "requires_ordered_input", False)
+           for f in p.factories):
+        return p, None
+    rest = p.factories[1:]
+    segment = None          # the segment that stages for this scan
+    if not scan.to_device:
+        if not (rest and isinstance(rest[0], FusedSegmentOperatorFactory)
+                and rest[0].coalesce_rows):
+            return p, None  # nothing here puts the scan on the device
+        segment, rest = rest[0], rest[1:]
+    key = (p.splits[0].handle, tuple(s.info for s in p.splits),
+           scan.batch_rows, segment.coalesce_rows if segment else 0)
+    try:
+        hash(key)
+    except TypeError:       # a split descriptor that does not hash
+        return p, None
+    found = SCAN_CACHE.open(scan.connector, key, scan.columns)
+    if isinstance(found, ScanHit):
+        head = [CachedScanOperatorFactory(found)]
+        if segment is not None:
+            head.append(segment.for_cached_scan())
+        return Pipeline(head + rest, name=p.name), None
+    head = [scan.filling(found)]
+    if segment is not None:
+        head.append(segment.for_cached_scan(found))
+    return Pipeline(head + rest, p.splits, name=p.name), found
+
+
 def _run_parallel(p: Pipeline, task: TaskContext, prefix: int,
                   width: int, deadline=None) -> None:
     from presto_tpu.exec.localexchange import (
-        LocalExchange, LocalExchangeSinkOperatorFactory,
+        ConsumerFinished, LocalExchange, LocalExchangeSinkOperatorFactory,
         LocalExchangeSourceOperatorFactory,
     )
 
@@ -74,6 +124,8 @@ def _run_parallel(p: Pipeline, task: TaskContext, prefix: int,
             p.splits[i::width], name=f"{p.name}.feed{i}")
         try:
             feeder.instantiate(task).run_to_completion(deadline=deadline)
+        except ConsumerFinished:
+            pass    # a LIMIT downstream was met: the rest is not wanted
         except BaseException as e:  # noqa: BLE001 - crossed to consumer
             errors.append(e)
             exchange.fail(e)
@@ -88,6 +140,7 @@ def _run_parallel(p: Pipeline, task: TaskContext, prefix: int,
         + p.factories[prefix:], name=p.name)
     try:
         consumer.instantiate(task).run_to_completion(deadline=deadline)
+        exchange.consumer_finished()
     except BaseException as e:
         # unblock feeders stuck in put() backpressure, then re-raise
         exchange.fail(e)
@@ -135,13 +188,21 @@ def execute_pipelines(pipelines: Sequence[Pipeline],
                 raise RuntimeError(
                     "Query exceeded maximum run time "
                     f"({config.query_max_run_time_s:g}s)")
-            prefix = _parallel_prefix(p, config)
-            width = min(config.task_concurrency, len(p.splits))
-            if prefix > 0 and width > 1:
-                _run_parallel(p, task, prefix, width, deadline=deadline)
-            else:
-                driver = p.instantiate(task)
-                driver.run_to_completion(deadline=deadline)
+            p, fill = _through_scan_cache(p)
+            ok = False
+            try:
+                prefix = _parallel_prefix(p, config)
+                width = min(config.task_concurrency, len(p.splits))
+                if prefix > 0 and width > 1:
+                    _run_parallel(p, task, prefix, width,
+                                  deadline=deadline)
+                else:
+                    driver = p.instantiate(task)
+                    driver.run_to_completion(deadline=deadline)
+                ok = True
+            finally:
+                if fill is not None:
+                    fill.close(ok)
     finally:
         task.close()
         # return any charge a failure path never freed — a leak in the

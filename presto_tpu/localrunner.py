@@ -13,12 +13,14 @@ pyramid and the in-process benchmark harness.  Same role here:
 from __future__ import annotations
 
 import dataclasses
+import weakref
 from typing import List, Optional, Sequence, Tuple
 
 from presto_tpu import types as T
 from presto_tpu.config import DEFAULT, EngineConfig
 from presto_tpu.connectors.api import Connector, ConnectorRegistry
 from presto_tpu.exec.runner import execute_pipelines
+from presto_tpu.exec.scancache import drop_connectors
 from presto_tpu.sql import tree as t
 from presto_tpu.sql.optimizer import optimize
 from presto_tpu.sql.parser import parse_statement
@@ -79,6 +81,9 @@ class LocalQueryRunner:
         self.registry = registry
         self.metadata = Metadata(registry, default_catalog)
         self.config = config
+        # the tables this runner's connectors keep on the device
+        # (exec/scancache.py) go when it is collected
+        weakref.finalize(self, drop_connectors, registry)
         from presto_tpu.events import EventBus
 
         self.session = session or Session(catalog=default_catalog)
@@ -794,6 +799,7 @@ class LocalQueryRunner:
                 f"{s.prereduce_rows:>9}")
         from presto_tpu.exec.context import (
             host_and_xla_line, hot_operator_lines, kernel_tier_lines,
+            scan_cache_line,
         )
 
         op_dicts = [dict(s.as_dict(), wall_ns=s.wall_ns + s.finish_wall_ns)
@@ -807,7 +813,9 @@ class LocalQueryRunner:
             f"compiles: {jc['compiles']} "
             f"({jc['compile_ns'] / 1e6:.1f} ms compile); "
             f"prereduce rows: {jc['prereduce_rows']}")
-        lines.append(host_and_xla_line(task.task_stats().as_dict()))
+        task_stats = task.task_stats().as_dict()
+        lines.append(host_and_xla_line(task_stats))
+        lines.append(scan_cache_line(task_stats))
         # queued-vs-execution split: same footer shape as the
         # distributed tier's _render_analyze (the single-process runner
         # executes synchronously — queued is always 0)

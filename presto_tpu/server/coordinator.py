@@ -2310,6 +2310,7 @@ class QueryExecution:
             host_and_xla_line as _host_and_xla_line,
             hot_operator_lines as _hot_operator_lines,
             kernel_tier_lines as _kernel_tier_lines,
+            scan_cache_line as _scan_cache_line,
         )
         from presto_tpu.sql.plan import format_plan
 
@@ -2403,6 +2404,7 @@ class QueryExecution:
                 f"prereduce rows: {qs['prereduce_rows']}; "
                 f"trace token: {self.trace_token}")
             lines.append(_host_and_xla_line(qs))
+            lines.append(_scan_cache_line(qs))
             lines.append(
                 f"serving: queued {qs.get('queued_s', 0.0):.3f} s, "
                 f"execution {qs.get('execution_s', 0.0):.3f} s"
@@ -5239,6 +5241,10 @@ class CoordinatorServer:
         self._ha_stop.set()
         self._memory_stop.set()
         self.dispatcher.close()
+        # tables kept on the device by coordinator-side execution
+        from presto_tpu.exec.scancache import drop_connectors
+
+        drop_connectors(self.registry)
         self.nodes.close()
         self.spool.close()
         self._httpd.shutdown()

@@ -145,6 +145,31 @@ def _kernel_cache_families(prefix: str) -> List[Family]:
     return fams
 
 
+def _scan_cache_families(prefix: str) -> List[Family]:
+    """The device-resident scan cache (exec/scancache.py), process-wide
+    like the kernel caches: what is resident, and how often a task's
+    scan of a table was handed kept batches."""
+    from presto_tpu.exec.scancache import SCAN_CACHE
+
+    s = SCAN_CACHE.stats()
+    return [
+        (f"{prefix}_scan_cache_resident_bytes", "gauge",
+         "device bytes of table columns kept by the scan cache",
+         [({}, s["resident_bytes"])]),
+        (f"{prefix}_scan_cache_entries", "gauge",
+         "kept runs (one task's scan of one table)",
+         [({}, s["entries"])]),
+        (f"{prefix}_scan_cache_total", "counter",
+         "scans of a cacheable table by a task: handed kept batches "
+         "(hits), generated and staged (misses); runs evicted for the "
+         "byte budget (evictions)",
+         [({"kind": k}, s[k]) for k in ("hits", "misses", "evictions")]),
+        (f"{prefix}_scan_cache_hit_bytes_total", "counter",
+         "device bytes handed to scans by the scan cache",
+         [({}, s["hit_bytes"])]),
+    ]
+
+
 def _spool_families(prefix: str, spool, bytes_evicted: int = 0
                     ) -> List[Family]:
     """presto_spool_bytes_written/read/evicted_total: the spooled
@@ -353,6 +378,7 @@ def coordinator_metrics(co) -> str:
     fams.extend(_result_cache_families("presto"))
     fams.extend(_spool_families("presto", getattr(co, "spool", None)))
     fams.extend(_kernel_cache_families("presto"))
+    fams.extend(_scan_cache_families("presto"))
     text = prometheus_text(fams)
     # dispatcher-lifecycle latency histograms: the scrape-side
     # cross-check for tools/qps_run.py's client-side latency numbers
@@ -433,4 +459,5 @@ def worker_metrics(worker) -> str:
                                 getattr(worker, "spool", None),
                                 bytes_evicted=bytes_evicted))
     fams.extend(_kernel_cache_families("presto_worker"))
+    fams.extend(_scan_cache_families("presto_worker"))
     return prometheus_text(fams)

@@ -592,9 +592,17 @@ class SqlTaskManager:
             q["peak"] += mi["peak"]
             total_reserved += mi["reserved"]
             total_peak += mi["peak"]
+        from presto_tpu.exec.scancache import SCAN_CACHE
+
+        kept = SCAN_CACHE.stats(self.registry.connectors())
         return {"reserved": total_reserved, "peak": total_peak,
                 "queries": per_query,
-                "pool": self.memory_pool.info()}
+                "pool": self.memory_pool.info(),
+                # device bytes this node's connectors keep resident
+                # between queries (exec/scancache.py): no query's
+                # reservation, so outside the totals above
+                "scanCache": {"bytes": kept["resident_bytes"],
+                              "entries": kept["entries"]}}
 
     def running_count(self) -> int:
         with self._lock:

@@ -462,6 +462,10 @@ class WorkerServer:
     def close(self) -> None:
         self._announce_stop.set()
         self.task_manager.cancel_all()
+        # the tables this node's connectors kept on the device go with it
+        from presto_tpu.exec.scancache import drop_connectors
+
+        drop_connectors(self.task_manager.registry)
         self.spool.close()
         self._httpd.shutdown()
         self._httpd.server_close()

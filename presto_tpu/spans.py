@@ -317,7 +317,11 @@ def _operator_entry(stats: Dict) -> Dict:
             "inputRows": stats.get("input_rows", 0),
             "outputRows": stats.get("output_rows", 0),
             "jitDispatches": stats.get("jit_dispatches", 0),
-            "kernelTier": stats.get("kernel_tier", "")}
+            "kernelTier": stats.get("kernel_tier", ""),
+            # "hit" / "miss" on the scan of a cached table, else ""
+            "scanCache": ("hit" if stats.get("scan_cache_hits")
+                          else "miss" if stats.get("scan_cache_misses")
+                          else "")}
 
 
 def validate_span_tree(tree: Dict) -> List[str]:

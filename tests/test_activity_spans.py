@@ -261,7 +261,8 @@ def test_task_spans_carry_operator_busy_time(served, name):
         assert ops
         for op in ops:
             assert set(op) == {"operator", "wallS", "inputRows",
-                               "outputRows", "jitDispatches", "kernelTier"}
+                               "outputRows", "jitDispatches", "kernelTier",
+                               "scanCache"}
             assert op["wallS"] >= 0
         assert "jitCompileNs" not in task["attributes"]
     dispatched = sum(op["jitDispatches"]
