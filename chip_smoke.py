@@ -75,7 +75,7 @@ limit 10
 """
 
 SCALE = 1.0  # TPC-H SF1, the first pinned config of BASELINE.json
-RTOL = 1e-6  # the engine's own DOUBLE parity tolerance (bench.py)
+RTOL = 1e-6  # DOUBLE to 1e-6, as benchmark/check.py compares
 CLIENT_TIMEOUT_S = 900.0  # a cold query compiles for minutes on the chip
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 

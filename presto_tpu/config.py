@@ -59,8 +59,10 @@ class EngineConfig:
     # Default hash-aggregation group capacity per kernel invocation.
     group_capacity: int = 1 << 20
     # Largest packed key domain for the gather-free direct GROUP BY path
-    # (mixed-radix ids + segment reduce; ~100x the sort path on v5e when it
-    # applies).  Above this, scatter cost grows and the sort path wins.
+    # (mixed-radix ids + segment reduce: no sort, no gather, and the
+    # group keys decode arithmetically).  Above this the dense per-batch
+    # state outgrows what a batch amortizes and the hash / sort tiers
+    # take over.  Not measured on the current code against either.
     direct_groupby_max_domain: int = 1 << 12
     # Default join match-expansion capacity multiplier (output rows per
     # probe batch before chunked re-probe kicks in).
@@ -82,29 +84,6 @@ class EngineConfig:
     # Build-side key domains prune probe rows before the join kernel
     # (DynamicFilterSourceOperator role, SURVEY §2.6).
     dynamic_filtering_enabled: bool = True
-    # Pipeline fusion (exec/fusion.py): compile maximal runs of adjacent
-    # row-local operators (chained FilterProjects, dynamic-filter
-    # application, the partition-id hash feeding PartitionedOutput) into
-    # ONE jitted segment program per batch — the cross-operator
-    # generalization of the reference's generated PageProcessor loop.
-    # Scan-adjacent segments additionally coalesce small per-split scan
-    # batches up to scan_batch_rows before dispatching (the
-    # ScanFilterAndProjectOperator role).  OFF restores today's
-    # per-operator dispatch exactly.
-    pipeline_fusion: bool = True
-    # Fusion II (requires pipeline_fusion): segments feeding a partial
-    # or single-step aggregation pre-reduce inside the jitted program —
-    # the per-batch group-accumulate (device group-by kernels) runs
-    # before anything materializes, so the segment emits partial-state
-    # batches (keys + component columns) instead of row batches, the
-    # downstream aggregation merges tiny partials, and its filter-less
-    # finalize projection folds into the aggregation finish.  Also
-    # gates exchange-adjacent segment coalescing (remote-exchange-fed
-    # segments batch pages up to scan_batch_rows before dispatching)
-    # and the runner's consumer-side placement of coalescing segments
-    # (one dispatch across all LocalExchange feeders).  OFF restores
-    # PR 3 lowering exactly.
-    fusion_partial_agg: bool = True
     # LRU capacity for the shared compiled-kernel caches (filter/project,
     # fused segments, dynamic filter, aggregation...).  Caches are
     # process-global; this is applied as the process default when a query
@@ -357,16 +336,9 @@ class EngineConfig:
     # operator).  0 disables.
     slow_query_log_threshold_s: float = 60.0
     # --- device-resident hash tier (ops/hashtable.py, SURVEY §3.4 "hot
-    # five" / §7 step 5) ------------------------------------------------
-    # GroupByHash: HashAggregationOperator accumulates into an
-    # open-addressing table resident ON DEVICE across batches (the
-    # MultiChannelGroupByHash role, 1-byte hash-prefix reject per
-    # PagesHash.java:49) instead of materializing every input batch and
-    # sorting once at finish.  Serves unbounded-key aggregations (the
-    # bounded-domain direct path and the clustered streaming path still
-    # win where they apply).  OFF restores the materialize+sort tier
-    # exactly.
-    hash_groupby_enabled: bool = True
+    # five" / §7 step 5): HashAggregationOperator accumulates unbounded-key
+    # aggregations into an open-addressing table resident on the device
+    # across batches; the thresholds below say when (exec/README.md).
     # first table capacity (slots, power of two); the rehash ladder
     # doubles from here while fill exceeds 1/2
     hash_groupby_init_slots: int = 1 << 13
@@ -384,38 +356,20 @@ class EngineConfig:
     # path for the remaining input — the "configured fraction of device
     # memory" guard (4M slots ~ a few hundred MB of state at Q1 widths)
     hash_groupby_max_slots: int = 1 << 22
-    # Join probes run inside fused segments (exec/fusion.py ProbeStage),
-    # and keys that have no direct-address index (ops/join.py: integer
-    # keys whose live span fits 1 << 24 slots take the index whatever
-    # this says) build the PagesHash open-addressing table over their
-    # raw normalized key words: VARCHAR and wide multi-channel keys
-    # stream through it (equality needs no total order, so the canonical
-    # union-sort materialization disappears).  OFF restores the
-    # unabsorbed probe chains and the sorted / canonical lookups.
-    device_join_probe: bool = True
-    # integer keys too sparse for the index, on the chip: build sides up
+    # Join lookup source (exec/joinop.py HashBuildOperator.finish):
+    # integer keys whose live span fits the direct-address index take it;
+    # unpackable (VARCHAR, wide multi-channel) keys always build the
+    # PagesHash open-addressing table, which is what lets them stream.
+    # Integer keys too sparse for the index, on the chip: build sides up
     # to this many rows take the hash table (a probe measured 29 ms
     # against the binary search's 33 ms a 64K batch on v5e, PERF.md
     # PR 30), larger ones keep the sorted index (claim-inserting a build
     # measured 245 ms per 128K rows against 12 ms for the sort).
-    # Unpackable (canonical-class) keys always build the hash table —
-    # that is what lets them stream.
     device_join_probe_max_build_rows: int = 1 << 17
-    # Fuse the FINAL-step merge aggregation into exchange-fed segments
-    # (PR 4's named remaining depth): the consumer fragment's merge
-    # accumulates inside the coalescing segment program, so distributed
-    # aggregations run one dispatch end-to-end per flush.  OFF restores
-    # the PR 9 lowering (separate merge aggregation operator) exactly.
-    fusion_final_merge: bool = True
-    # Cost-based pre-reduce: skip segment_pre_reduce (emit raw rows in
-    # partial-state schema) when the estimated OR observed group
-    # cardinality approaches the row count — per-batch grouping that
-    # does not reduce is pure overhead.  Plan-time estimate from the
-    # memo's stats tier; runtime confirmation from the observed
-    # groups/rows ratio of dispatched batches.  OFF restores the
-    # unconditional pre-reduce decision exactly.
-    prereduce_cost_based: bool = True
-    # groups/rows ratio above which pre-reduce is skipped
+    # Pre-reduce inside a fused segment (exec/fusion.py) is skipped, and
+    # raw rows emitted in partial-state schema, when the estimated or the
+    # observed groups/rows ratio of a hash-path aggregation passes this:
+    # per-batch grouping that does not reduce is pure overhead.
     prereduce_max_group_fraction: float = 0.9
     # --- collectives as the data plane (parallel/, SURVEY §5.8 / §2.13,
     # roles P1/P2/P8/P9) -------------------------------------------------

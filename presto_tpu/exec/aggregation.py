@@ -261,7 +261,7 @@ class HashAggregationOperator(Operator):
             # self._carried; THIS batch falls through to the sort tier
         elif (self._spiller is None and not self._hash_decided
                 and self._accumulated_rows + batch.num_rows
-                >= getattr(self.ctx.config, "hash_groupby_min_rows", 0)):
+                >= self.ctx.config.hash_groupby_min_rows):
             # the engagement threshold crossed: small inputs never pay
             # the claim-loop's fixed round costs (one sort at finish is
             # cheaper), large ones drain what accumulated so far into
@@ -297,9 +297,6 @@ class HashAggregationOperator(Operator):
         state would be interning codes), keys not already served by the
         bounded-domain direct path (which is faster where it applies),
         and grouping actually present."""
-        cfg = self.ctx.config
-        if not getattr(cfg, "hash_groupby_enabled", False):
-            return False
         if not self.group_channels or _has_collect(self.aggs):
             return False
         for a in self.aggs:
@@ -323,7 +320,7 @@ class HashAggregationOperator(Operator):
         from presto_tpu.ops.hashtable import groupby_init
 
         cfg = self.ctx.config
-        cap = int(getattr(cfg, "hash_groupby_init_slots", 1 << 13))
+        cap = int(cfg.hash_groupby_init_slots)
         key_cols = [batch.columns[c] for c in self.group_channels]
         # every key column is declared nullable in the resident state:
         # validity presence may differ batch-to-batch (an all-valid
@@ -364,7 +361,7 @@ class HashAggregationOperator(Operator):
         )
 
         cfg = self.ctx.config
-        max_slots = int(getattr(cfg, "hash_groupby_max_slots", 1 << 22))
+        max_slots = int(cfg.hash_groupby_max_slots)
         batch = batch.to_device()
         key_cols, agg_ins, n = self._hash_inputs(batch)
         while True:

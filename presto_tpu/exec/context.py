@@ -147,7 +147,7 @@ class OperatorStats:
     # Join build and probe (absorbed or stand-alone): "dense"
     # (direct-address index, ops/join.py), "sorted", "hash"; a segment
     # that absorbed probes of two tiers reads "dense+hash".  Surfaced by
-    # tools/fusion_report.py, the span tree (kernelTier) and EXPLAIN
+    # the span tree (kernelTier), tools/query_profile.py and EXPLAIN
     # ANALYZE's "kernel tiers" line
     kernel_tier: str = ""
     # the device-resident scan cache (exec/scancache.py), on the scan

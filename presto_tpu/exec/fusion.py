@@ -50,24 +50,20 @@ aggregation is replaced by its merge form (MERGE_PRIM re-aggregation
 of the tiny partials, filter-less finalize projection folded into the
 aggregation finish); a partial-step aggregation is dropped outright —
 the FINAL stage's merge already accepts partials at any granularity.
-Gated by ``EngineConfig.fusion_partial_agg`` (default on; off restores
-the PR 3 lowering exactly).
 
 Segment programs are cached globally (``kernelcache``) keyed by segment
 expression keys + capacity bucket + dictionary binding (token, length) +
 the dynamic-filter value shape — the same keying discipline as
-``_FP_KERNELS``.  Gated by ``EngineConfig.pipeline_fusion`` (default on;
-off restores per-operator dispatch exactly).
+``_FP_KERNELS``.
 
-PR 10 extends the segment grammar three ways (see exec/README.md
+The segment grammar reaches three ways further (see exec/README.md
 "Device-resident hash tier"): residual-free inner/semi/anti LookupJoin
-probes absorb as ``ProbeStage`` (gate ``device_join_probe``) so
-filter -> project -> probe -> partial-agg chains are one dispatch;
-grouped FINAL merges directly on a remote exchange absorb into
-empty-stage coalescing segments (gate ``fusion_final_merge``); and the
-pre-reduce decision is cost-based (gate ``prereduce_cost_based``) —
-plan-time NDV hints plus a runtime observed-ratio switch to raw
-partial-state emission when grouping stops reducing.
+probes absorb as ``ProbeStage`` so filter -> project -> probe ->
+partial-agg chains are one dispatch; grouped FINAL merges directly on a
+remote exchange absorb into empty-stage coalescing segments; and the
+pre-reduce decision is cost-based — plan-time NDV hints plus a runtime
+observed-ratio switch to raw partial-state emission when grouping stops
+reducing.
 
 What breaks a segment: any non-row-local operator (aggregation — except
 an absorbed one, join — except an absorbed probe, sort, exchange,
@@ -160,7 +156,7 @@ class DFStage:
 
 @dataclasses.dataclass(frozen=True, eq=False)
 class ProbeStage:
-    """An absorbed residual-free LookupJoin probe (device_join_probe):
+    """An absorbed residual-free LookupJoin probe:
     the probe primitive runs INSIDE the segment program — the way
     ``segment_pre_reduce`` absorbed partial aggregation — so
     filter -> project -> probe -> partial-agg chains cost one dispatch.
@@ -215,9 +211,7 @@ def _probe_absorbable(f, config) -> bool:
     emission interacts with downstream outer-composition paths).
     Grouped execution keeps per-bucket probe operators so Lifespan
     memory retirement stays observable."""
-    if not getattr(config, "device_join_probe", False):
-        return False
-    if getattr(config, "grouped_execution_buckets", 1) > 1:
+    if config.grouped_execution_buckets > 1:
         return False
     if f.join_type not in ("inner", "semi", "anti"):
         return False
@@ -317,8 +311,6 @@ def _try_pre_reduce(stages, factory, config, out_types=None,
     pre-reduce outright when estimated groups approach input rows.
     Returns (None, None) when ineligible.
     """
-    if not getattr(config, "fusion_partial_agg", False):
-        return None, None
     is_hash = isinstance(factory, HashAggregationOperatorFactory)
     is_global = isinstance(factory, GlobalAggregationOperatorFactory)
     if not (is_hash or is_global):
@@ -327,10 +319,10 @@ def _try_pre_reduce(stages, factory, config, out_types=None,
         out_types = _segment_out_types(stages)
     if out_types is None or len(out_types) != len(factory.input_types):
         return None, None
-    if (getattr(config, "prereduce_cost_based", False) and is_hash):
+    if is_hash:
         hint = getattr(factory, "prereduce_ratio_hint", None)
-        if hint is not None and hint > getattr(
-                config, "prereduce_max_group_fraction", 0.9):
+        if (hint is not None
+                and hint > config.prereduce_max_group_fraction):
             return None, None
     for a in factory.aggs:
         if a.prim not in MERGE_PRIM:
@@ -409,8 +401,7 @@ def _partition_spec(sink) -> Optional[Tuple[Tuple[int, ...], int]]:
 # ---------------------------------------------------------------------------
 
 def _try_final_merge(factory, prev, config):
-    """FINAL-merge fusion (PR 4's named remaining depth, gated
-    ``fusion_final_merge``): a grouped merge aggregation fed DIRECTLY by
+    """FINAL-merge fusion: a grouped merge aggregation fed DIRECTLY by
     a remote exchange absorbs into an empty-stage coalescing segment —
     partial pages batch up to scan_batch_rows and merge-accumulate in
     ONE dispatch per flush, with the finalize projections folded into
@@ -418,8 +409,6 @@ def _try_final_merge(factory, prev, config):
     empty-input default row must come from the original prims, which
     the merge form no longer names.  Returns (spec, replacement) or
     (None, None)."""
-    if not getattr(config, "fusion_final_merge", False):
-        return None, None
     if not isinstance(factory, HashAggregationOperatorFactory):
         return None, None
     if not _exchange_adjacent(prev):
@@ -480,8 +469,7 @@ def fuse_chain(factories: List[OperatorFactory], config
         scan = (result[-1] if result
                 and isinstance(result[-1], TableScanOperatorFactory)
                 and result[-1].to_device else None)
-        exch = (getattr(config, "fusion_partial_agg", False) and result
-                and _exchange_adjacent(result[-1]))
+        exch = bool(result) and _exchange_adjacent(result[-1])
         # in-segment partial-aggregation pre-reduce: the run's output
         # feeds an eligible aggregation -> absorb its per-batch
         # accumulate; the aggregation becomes its merge form (or, for
@@ -662,10 +650,11 @@ class FusedSegmentOperator(Operator):
     # a FINAL-merge segment flush below this many rows skips its own
     # dispatch: the rows pass through AS partial states (identity — the
     # segment has no stages and its input/output schemas coincide) and
-    # the downstream merge pays exactly what the unfused PR 9 path
-    # paid.  Pre-reducing a tiny flush costs a full program launch to
-    # save the merge almost nothing; at real exchange volumes the
-    # flush crosses the bound and the in-segment merge-accumulate wins.
+    # the downstream merge pays exactly what a merge aggregation fed by
+    # the exchange alone pays.  Pre-reducing a tiny flush costs a full
+    # program launch to save the merge almost nothing; at real exchange
+    # volumes the flush crosses the bound and the in-segment
+    # merge-accumulate wins.
     _PASSTHROUGH_ROWS = 8192
 
     def _passthrough_ok(self) -> bool:
@@ -816,8 +805,7 @@ class FusedSegmentOperator(Operator):
                 if src.mode not in ("hash", "single", "packed"):
                     raise RuntimeError(
                         "absorbed join probe needs a streaming lookup "
-                        f"source, got mode={src.mode!r}; rerun with "
-                        "device_join_probe=false")
+                        f"source, got mode={src.mode!r}")
                 srcs.append(src)
             # one tier per absorbed probe, in stage order, repeats merged
             self.ctx.stats.kernel_tier = "+".join(
@@ -959,12 +947,9 @@ class FusedSegmentOperator(Operator):
         if (self.agg_spec is None or self.agg_spec.global_
                 or self._raw_emit):
             return
-        cfg = self.ctx.config
-        if not getattr(cfg, "prereduce_cost_based", False):
-            return
         if rows_in < 2048:      # tiny batches prove nothing
             return
-        frac = getattr(cfg, "prereduce_max_group_fraction", 0.9)
+        frac = self.ctx.config.prereduce_max_group_fraction
         if groups_out > frac * rows_in:
             self._raw_emit = True
 
@@ -1256,7 +1241,7 @@ class FusedSegmentOperatorFactory(OperatorFactory):
             scan_fill=fill)
 
     def describe(self) -> str:
-        """Human-readable stage summary (tools/fusion_report.py)."""
+        """Human-readable stage summary."""
         parts = []
         for s in self.stages:
             if isinstance(s, FPStage):

@@ -71,7 +71,7 @@ class LookupSource:
     strides: Optional[np.ndarray] = None  # packed: per-channel stride
     maxs: Optional[np.ndarray] = None
     has_null_key: object = None           # device bool scalar (single/packed)
-    # device_join_probe tier (ops/hashtable.py): the open-addressing
+    # mode 'hash' (ops/hashtable.py): the open-addressing
     # table (t_words tuple, t_prefix, t_used, starts, counts) whose
     # (starts, counts) index ``perm`` — the PagesHash role proper
     pages: Optional[tuple] = None
@@ -336,25 +336,18 @@ class HashBuildOperator(Operator):
             if self._set_dense_index(data, key_pairs, chans, n, n_build,
                                      ranges):
                 return
-        want_hash = False
-        if getattr(cfg, "device_join_probe", False):
-            if not packable:
-                # canonical-class multi-channel keys: the hash table is
-                # what lets the probe STREAM at all (the sorted tier
-                # would materialize the probe side for a union sort)
-                want_hash = True
-            elif (jax.default_backend() == "tpu"
-                    and n_build <= getattr(
-                        cfg, "device_join_probe_max_build_rows",
-                        1 << 17)):
-                # integer keys too sparse for the index: on the chip a
-                # probe of the sorted tier is a binary search of 2x18
-                # dependent int64 gathers, 33 ms a 64K batch against
-                # the table's 29 ms, while claim-inserting a 128K build
-                # costs 245 ms against 12 ms for the sort (v5e, PERF.md
-                # PR 30): the table serves builds up to the bound.  On
-                # the CPU the sorted tier stays.
-                want_hash = True
+        # unpackable (canonical-class) keys: the hash table is what lets
+        # the probe STREAM at all (the sorted tier would materialize the
+        # probe side for a union sort).  Integer keys too sparse for the
+        # index: on the chip a probe of the sorted tier is a binary
+        # search of 2x18 dependent int64 gathers, 33 ms a 64K batch
+        # against the table's 29 ms, while claim-inserting a 128K build
+        # costs 245 ms against 12 ms for the sort (v5e, PERF.md PR 30):
+        # the table serves builds up to the bound.  On the CPU the
+        # sorted tier stays.
+        want_hash = not packable or (
+            jax.default_backend() == "tpu"
+            and n_build <= cfg.device_join_probe_max_build_rows)
         if want_hash and self._set_pages_hash(data, key_pairs, chans,
                                               n, n_build):
             return
@@ -390,9 +383,8 @@ class HashBuildOperator(Operator):
                 return
         # key spans overflowed the single/packed id arithmetic: the
         # hash table still streams such keys (equality needs no ids)
-        if (getattr(cfg, "device_join_probe", False) and not want_hash
-                and self._set_pages_hash(data, key_pairs, chans, n,
-                                         n_build)):
+        if not want_hash and self._set_pages_hash(data, key_pairs, chans,
+                                                  n, n_build):
             return
         # general path: probe side will materialize and union-sort
         self.f.lookup.set(LookupSource("canonical", None, None, data,

@@ -41,7 +41,7 @@ def _parallel_prefix(p: Pipeline, config: EngineConfig) -> int:
     # the whole chain being safe means there is no consumer stage left
     # to protect — still split before the terminal sink
     k = min(k, len(p.factories) - 1)
-    if k > 1 and getattr(config, "fusion_partial_agg", False):
+    if k > 1:
         from presto_tpu.exec.fusion import FusedSegmentOperatorFactory
 
         last = p.factories[k - 1]

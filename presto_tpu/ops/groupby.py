@@ -33,7 +33,7 @@ Three grouping tiers now coexist, chosen per operator/batch:
   capacity-doubling rehash (MultiChannelGroupByHash.java:273-286),
   vectorized as a data-parallel claim loop.  Group state stays ON
   DEVICE across batches, so nothing re-sorts and input batches are
-  never retained (``EngineConfig.hash_groupby_enabled``).
+  never retained (unbounded keys past ``hash_groupby_min_rows``).
 - **sort** (``grouped_aggregate``): the exact, rehash-free fallback —
   also the overflow target when the hash table would exceed
   ``hash_groupby_max_slots`` (accumulated state carries over via
@@ -50,20 +50,6 @@ import jax.numpy as jnp
 
 from presto_tpu import types as T
 from presto_tpu.ops.keys import normalize_keys
-
-
-def _pallas_enabled() -> bool:
-    """Opt-in Pallas path for the direct-groupby reduction
-    (PRESTO_TPU_PALLAS=1).  Not measured on the chip against the current
-    einsum path: XLA fuses the elementwise prologue (filter mask,
-    expression arithmetic, hi/lo split) into the einsum's operand reads,
-    while a pallas_call is a fusion barrier that forces those operands
-    through HBM.  Kept as the kernel-authoring template (grid
-    accumulation, MXU dots, compensated-f32 pairs) and for shapes where
-    the prologue is trivial.  Opted in, a kernel failure raises."""
-    import os
-
-    return os.environ.get("PRESTO_TPU_PALLAS", "0") == "1"
 
 
 # One aggregation input: (prim, values, valid|None) with prim in
@@ -251,9 +237,8 @@ def direct_grouped_aggregate(
     (GroupByHash.java:30-43); the TPU analogue special-cases *bounded* key
     domains (dictionary codes, booleans, small ints): when the product of
     key cardinalities is small, the group id is computed arithmetically and
-    aggregation is a handful of segment reductions — no sort, no gather,
-    ~100x faster than the sort path on v5e (measured: Q1 at 1M rows goes
-    0.29s -> <2ms).
+    aggregation is a handful of segment reductions — no sort, no gather
+    (not measured against the sort path on the current code).
 
     ``key_codes``: per key column ``(codes, valid)`` with codes already in
     ``[0, domain_size)``.  Nullable keys get slot 0 reserved by the +1 shift
@@ -287,9 +272,9 @@ def direct_grouped_aggregate(
     # --- sums & counts ---------------------------------------------------
     # Small domains ride the MXU: blocked one-hot einsum with a hi/lo f32
     # split (two f32 matmuls + f64 cross-block combine, ~1.5e-9 rel err)
-    # is ~10x faster than scatter-add segment_sum on v5e (8.6ms vs 130ms
-    # for Q1 at 1M rows).  Above the memory threshold (one-hot is [N, G])
-    # fall back to scatter.
+    # in place of a scatter-add segment_sum, which the TPU serializes
+    # (not measured against it on the current code).  Above the memory
+    # threshold (one-hot is [N, G]) fall back to scatter.
     # Float sums ride the matmul; integer sums must stay exact, so they go
     # through native-dtype scatter even when the matmul path is on (a
     # hi/lo f32 einsum rounds int64 sums near 2^53 — confirmed off-by-4096
@@ -310,37 +295,26 @@ def direct_grouped_aggregate(
     sum_cols.append(live.astype(jnp.float64))    # group-present count
 
     # MXU path only on TPU: on CPU, XLA's f32 einsum accumulates worse
-    # (~3e-9 rel) while f64 scatter is exact and fast; on TPU scatter costs
-    # ~130ms/M rows and the MXU einsum ~2-8ms.  Decided at trace time.
+    # (~3e-9 rel) while f64 scatter is exact and fast; on TPU the scatter
+    # serializes and the matmul does not.  Decided at trace time.
     use_matmul = (n_seg <= 32 and cap % 1024 == 0
                   and jax.default_backend() == "tpu")
     m = jnp.stack(sum_cols, 1)                   # [N, A]
     if use_matmul:
         hi = m.astype(jnp.float32)
         lo = (m - hi.astype(jnp.float64)).astype(jnp.float32)
-        if _pallas_enabled():
-            # single-pass VMEM-resident Pallas kernel: no [B, G, A]
-            # intermediate, compensated-f32 running totals (see
-            # ops/pallas_groupby.py).  Opted in = a kernel failure raises.
-            from presto_tpu.ops.pallas_groupby import (
-                direct_segment_sums_pallas,
-            )
-
-            reduced = direct_segment_sums_pallas(
-                gid.astype(jnp.int32), hi, lo, n_seg)
-        else:
-            block = 2048 if cap % 2048 == 0 else 1024
-            B = cap // block
-            oh = jax.nn.one_hot(gid.reshape(B, block), n_seg,
-                                dtype=jnp.float32)
-            # HIGHEST: TPU matmuls default to bf16 passes (1e-4 rel
-            # error); HIGHEST forces full-f32 (3-pass bf16) accumulation.
-            hp = jax.lax.Precision.HIGHEST
-            reduced = (
-                jnp.einsum("bng,bna->bga", oh, hi.reshape(B, block, -1),
-                           precision=hp).astype(jnp.float64).sum(0)
-                + jnp.einsum("bng,bna->bga", oh, lo.reshape(B, block, -1),
-                             precision=hp).astype(jnp.float64).sum(0))
+        block = 2048 if cap % 2048 == 0 else 1024
+        B = cap // block
+        oh = jax.nn.one_hot(gid.reshape(B, block), n_seg,
+                            dtype=jnp.float32)
+        # HIGHEST: TPU matmuls default to bf16 passes (1e-4 rel
+        # error); HIGHEST forces full-f32 (3-pass bf16) accumulation.
+        hp = jax.lax.Precision.HIGHEST
+        reduced = (
+            jnp.einsum("bng,bna->bga", oh, hi.reshape(B, block, -1),
+                       precision=hp).astype(jnp.float64).sum(0)
+            + jnp.einsum("bng,bna->bga", oh, lo.reshape(B, block, -1),
+                         precision=hp).astype(jnp.float64).sum(0))
     else:
         reduced = jax.ops.segment_sum(m, gid, num_segments=n_seg)
     reduced = reduced[:total]                    # [G, A]

@@ -24,10 +24,9 @@ contract; the build picks one from the key types and the live key span
   then per call either an in-call histogram (span fits a scratch sized
   by the batch) or a vectorized binary search.  Serves integer keys too
   sparse for the index.
-- **hash** (``ops/hashtable.py pages_hash_build`` / ``pages_hash_probe``,
-  gated ``EngineConfig.device_join_probe``): the PagesHash table proper
-  over raw normalized key words with the 1-byte hash-prefix reject of
-  ``PagesHash.java:49``.  It probes by EQUALITY, not order, so arbitrary
+- **hash** (``ops/hashtable.py pages_hash_build`` / ``pages_hash_probe``):
+  the PagesHash table proper over raw normalized key words with the
+  1-byte hash-prefix reject of ``PagesHash.java:49``.  It probes by EQUALITY, not order, so arbitrary
   multi-channel key types stream without the canonical union-sort
   materialization; a probe costs the longest hash chain of its batch.
 

@@ -1,17 +1,18 @@
 """Device radix sort: O(1)-in-length compile, range-adaptive runtime.
 
 Why not XLA's sort: on TPU the sort lowering's COMPILE time scales with the
-input length (measured ~0.4 ms/row/key for lexsort on v5e — BASELINE.md),
-so every new shape of a generic join/group-by/order-by program pays
-minutes of compilation.  The reference instead pays a one-time bytecode
+input length (how steeply is not measured on the current code), so every
+new shape of a generic join/group-by/order-by program would pay for it
+again.  The reference instead pays a one-time bytecode
 specialization per type combination (OrderingCompiler,
 presto-main/.../sql/gen/OrderingCompiler.java:62).  This module is that
 idea rebuilt for XLA: a least-significant-digit radix sort made of
 primitives whose compile cost is independent of N (cumsum, compare,
 scatter), specialized per (shape, word-count) by the jit cache.
 
-Design (shaped by measured v5e costs: random gather ~7 ms and scatter
-~4 ms per 1M rows, one-hot cumsum/compare ~free in comparison):
+Design (shaped by what the chip does badly: memory-random gathers and
+scatters, against one-hot cumsums and compares that vectorize; the costs
+are not measured on the current code):
 
 - Keys are normalized order-preserving int64 words (ops/keys.py), split
   into two uint32 halves after an in-program per-word min-subtraction.
@@ -39,7 +40,6 @@ single 1-bit passes appended most-significant.
 
 from __future__ import annotations
 
-import os
 from typing import Optional, Sequence
 
 import jax
@@ -54,13 +54,8 @@ _RADIX_BITS = 4
 def use_radix() -> bool:
     """Trace-time backend dispatch: radix on TPU (where XLA sort compile
     scales with length), XLA sort elsewhere (CPU lexsort compiles fast
-    and runs faster than emulated radix passes).  PRESTO_TPU_RADIX=1/0
-    forces either way (tests force 1 to exercise radix on CPU)."""
-    env = os.environ.get("PRESTO_TPU_RADIX", "auto")
-    if env == "1":
-        return True
-    if env == "0":
-        return False
+    and runs faster than emulated radix passes).  Tests reach the radix
+    passes on the CPU by calling ``radix_argsort_i64`` itself."""
     return jax.default_backend() == "tpu"
 
 

@@ -58,10 +58,6 @@ SESSION_PROPERTIES: Dict[str, Tuple[str, Callable[[str], Any]]] = {
     "dynamic_filtering_enabled": ("dynamic_filtering_enabled",
                                   lambda v: v.lower() in ("true", "1",
                                                           "on")),
-    "pipeline_fusion": ("pipeline_fusion",
-                        lambda v: v.lower() in ("true", "1", "on")),
-    "fusion_partial_agg": ("fusion_partial_agg",
-                           lambda v: v.lower() in ("true", "1", "on")),
     "kernel_cache_capacity": ("kernel_cache_capacity", int),
     "whole_query_execution": ("whole_query_execution",
                               lambda v: v.lower() in ("true", "1", "on")),
@@ -111,23 +107,11 @@ SESSION_PROPERTIES: Dict[str, Tuple[str, Callable[[str], Any]]] = {
     "result_cache_max_entry_bytes": ("result_cache_max_entry_bytes",
                                      int),
     "query_queue_timeout_s": ("query_queue_timeout_s", float),
-    "hash_groupby_enabled": (
-        "hash_groupby_enabled",
-        lambda v: v.lower() in ("true", "1", "on")),
     "hash_groupby_init_slots": ("hash_groupby_init_slots", int),
     "hash_groupby_max_slots": ("hash_groupby_max_slots", int),
     "hash_groupby_min_rows": ("hash_groupby_min_rows", int),
-    "device_join_probe": (
-        "device_join_probe",
-        lambda v: v.lower() in ("true", "1", "on")),
     "device_join_probe_max_build_rows": (
         "device_join_probe_max_build_rows", int),
-    "fusion_final_merge": (
-        "fusion_final_merge",
-        lambda v: v.lower() in ("true", "1", "on")),
-    "prereduce_cost_based": (
-        "prereduce_cost_based",
-        lambda v: v.lower() in ("true", "1", "on")),
     "prereduce_max_group_fraction": (
         "prereduce_max_group_fraction", float),
     "mesh_device_exchange": (

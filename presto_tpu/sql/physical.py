@@ -150,8 +150,6 @@ class PhysicalPlanner:
         programs.  Runs after every lowering decision that inspects the
         raw chains (streaming-agg eligibility, grouped execution,
         dynamic-filter placement)."""
-        if not getattr(self.config, "pipeline_fusion", False):
-            return
         from presto_tpu.exec.fusion import fuse_pipelines
 
         fuse_pipelines(self._done_pipelines, self.config)
@@ -435,8 +433,6 @@ class PhysicalPlanner:
         through the same stats tier the memo's cost model uses
         (sql/stats.py NDV propagation).  None when unknown — the fusion
         pass then decides from the runtime observed ratio alone."""
-        if not getattr(self.config, "prereduce_cost_based", False):
-            return None
         try:
             import types as _pytypes
 

@@ -2,7 +2,7 @@
 attached (on-chip-measurement guide, section 2).
 
 These are compiles, not chip runs: the TPU compiler installed here raises
-what the chip's compiler would raise (64-bit rewrites it cannot do, Pallas
+what the chip's compiler would raise (64-bit rewrites it cannot do,
 kernels it refuses, programs that do not fit), at the shapes SF1 produces
 behind ``scan_batch_rows`` = 65536.  Nothing executes, so they say nothing
 about results or times — ``chip_smoke.py`` does that on the chip.
@@ -262,14 +262,3 @@ def test_device_concat_append(chip):
              (chip.spec(ROWS, jnp.float64), chip.spec(ROWS, bool))),
             chip.spec((), jnp.int32), chip.spec((), jnp.int32))
 
-
-def test_pallas_direct_segment_sums(chip):
-    """The opt-in (PRESTO_TPU_PALLAS=1) VMEM-resident group-by kernel at
-    Q1's width: it must be a real Mosaic kernel, not interpret mode."""
-    from presto_tpu.ops.pallas_groupby import direct_segment_sums_pallas
-
-    compiled = chip.compile(
-        lambda g, hi, lo: direct_segment_sums_pallas(g, hi, lo, 8),
-        chip.spec(ROWS, jnp.int32), chip.spec((ROWS, 13), jnp.float32),
-        chip.spec((ROWS, 13), jnp.float32))
-    assert "tpu_custom_call" in compiled.as_text()

@@ -1,13 +1,14 @@
 """Pipeline-fusion tier tests (exec/fusion.py).
 
-Covers: fused-vs-unfused result parity (hand-built chains, the SQL
-runner, and — under the slow marker — the full TPC-H suite), the
-dispatch-counter regression pin (fused Q1 issues >= 2x fewer jit
-launches than unfused), segment formation/breaking rules, the
-precomputed partition-id path, dictionary cache tokens, and the
-kernel-cache counters/capacity knob.
+Covers: the operator tier's answers against references that share no
+engine code (the benchmark's numpy Q1/Q6/Q3, ``collections`` group-bys of
+hand-built inputs), the dispatch counts the lowering produces, which
+group-by tier an aggregation picks from its input, segment
+formation/breaking rules, the precomputed partition-id path, dictionary
+cache tokens, and the kernel-cache counters/capacity knob.
 """
 
+import collections
 import dataclasses as dc
 
 import numpy as np
@@ -28,6 +29,7 @@ from presto_tpu.exec.runner import execute_pipelines
 from presto_tpu.expr import build as B
 from presto_tpu.localrunner import LocalQueryRunner
 
+import tpch_reference
 from tpch_queries import QUERIES
 
 
@@ -41,9 +43,8 @@ def runner_on():
 
 
 @pytest.fixture(scope="module")
-def runner_off():
-    return LocalQueryRunner.tpch(
-        scale=0.01, config=_cfg(pipeline_fusion=False))
+def want():
+    return tpch_reference.reference_answers(0.01)
 
 
 def _norm(rows):
@@ -141,27 +142,6 @@ def test_scan_adjacent_single_stage_fuses():
     assert chain[1].coalesce_rows == EngineConfig().scan_batch_rows
 
 
-def test_fusion_off_reproduces_unfused_chains(runner_off):
-    """pipeline_fusion=false leaves lowering byte-identical to the
-    pre-fusion engine: no fused segments anywhere."""
-    from presto_tpu.sql.optimizer import optimize
-    from presto_tpu.sql.parser import parse_statement
-    from presto_tpu.sql.physical import PhysicalPlanner
-    from presto_tpu.sql.planner import Planner
-
-    plan = optimize(
-        Planner(runner_off.metadata).plan(parse_statement(QUERIES[3])),
-        runner_off.metadata, runner_off.config)
-    phys = PhysicalPlanner(runner_off.registry,
-                           runner_off.config).plan(plan)
-    for p in phys.pipelines:
-        for f in p.factories:
-            assert not isinstance(f, FusedSegmentOperatorFactory)
-        for f in p.factories:
-            if isinstance(f, TableScanOperatorFactory):
-                assert f.to_device is True
-
-
 def test_q3_forms_multi_stage_segments(runner_on):
     from presto_tpu.sql.optimizer import optimize
     from presto_tpu.sql.parser import parse_statement
@@ -187,39 +167,37 @@ def test_q3_forms_multi_stage_segments(runner_on):
 # SQL-level parity + the dispatch-counter regression pin
 # ---------------------------------------------------------------------------
 
-def test_q1_dispatch_reduction(runner_on, runner_off):
-    """Fusion must cut the TPC-H Q1 engine path's jit launches by >= 2x
-    (the tentpole's measurable claim), with matching results."""
-    ra = runner_on.execute(QUERIES[1])
-    fused = runner_on._last_task.jit_counters()
-    rb = runner_off.execute(QUERIES[1])
-    unfused = runner_off._last_task.jit_counters()
-    assert_rows_close(ra.rows, rb.rows)
-    assert fused["dispatches"] > 0
-    assert unfused["dispatches"] >= 2 * fused["dispatches"], (
-        fused, unfused)
+def _run_statement(runner, want, name):
+    """Execute benchmark statement ``name``, hold its answer to the
+    plain reference, and return the task's jit counters."""
+    res = runner.execute(tpch_reference.statement(name))
+    tpch_reference.compare(tpch_reference.as_served(res.rows), want[name],
+                           tpch_reference.RTOL)
+    return runner._last_task.jit_counters()
 
 
-def test_q6_q3_parity_and_strictly_fewer(runner_on, runner_off):
-    for qn in (6, 3):
-        ra = runner_on.execute(QUERIES[qn])
-        fused = runner_on._last_task.jit_counters()
-        rb = runner_off.execute(QUERIES[qn])
-        unfused = runner_off._last_task.jit_counters()
-        assert_rows_close(ra.rows, rb.rows)
-        assert fused["dispatches"] < unfused["dispatches"], (
-            qn, fused, unfused)
+@pytest.mark.parametrize("name", tpch_reference.STATEMENTS)
+def test_operator_tier_matches_plain_reference(runner_on, want, name):
+    """The default lowering (fused segments, absorbed probes, in-segment
+    pre-reduce) against the benchmark's numpy references, DOUBLE to 1e-6:
+    tier-1's check that fusion computes the right answer."""
+    _run_statement(runner_on, want, name)
 
 
-def test_session_property_toggles_fusion(runner_on):
-    r = LocalQueryRunner.tpch(scale=0.01)
-    r.execute("set session pipeline_fusion = false")
-    r.execute(QUERIES[6])
-    off_counters = r._last_task.jit_counters()
-    r.execute("set session pipeline_fusion = true")
-    r.execute(QUERIES[6])
-    on_counters = r._last_task.jit_counters()
-    assert on_counters["dispatches"] < off_counters["dispatches"]
+def test_q1_dispatch_reduction(runner_on, want):
+    """Q1 at SF0.01 is ONE jit launch (filter, projections and the
+    per-batch aggregation in one segment over the coalesced scan), with
+    the reference's answer."""
+    assert _run_statement(runner_on, want, "q1")["dispatches"] == 1
+
+
+def test_q6_q3_parity_and_strictly_fewer(runner_on, want):
+    """Q6 is one launch, Q3 four (a segment a table, those over orders
+    and lineitem each with a join probe absorbed, and the projection
+    after the aggregation): a probe or an aggregation that left its
+    segment shows here as a larger count."""
+    assert _run_statement(runner_on, want, "q6")["dispatches"] == 1
+    assert _run_statement(runner_on, want, "q3")["dispatches"] == 4
 
 
 def test_explain_analyze_reports_jit_counters(runner_on):
@@ -228,18 +206,6 @@ def test_explain_analyze_reports_jit_counters(runner_on):
     text = "\n".join(r[0] for r in res.rows)
     assert "jit disp" in text and "jit dispatches:" in text
     assert "kernel caches" in text
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("qnum", sorted(QUERIES))
-def test_tpch_fusion_parity(qnum, runner_on, runner_off):
-    """Fusion-on vs fusion-off result parity across the full TPC-H
-    suite (the conformance oracle separately validates fusion-on against
-    sqlite; this pins on==off directly)."""
-    ra = runner_on.execute(QUERIES[qnum])
-    rb = runner_off.execute(QUERIES[qnum])
-    assert ra.column_names == rb.column_names
-    assert_rows_close(ra.rows, rb.rows)
 
 
 # ---------------------------------------------------------------------------
@@ -264,7 +230,29 @@ def _agg_chain(aggs, group_channels=(0,)):
         [B.ref(0, types[0]), B.ref(1, T.BIGINT), B.ref(2, T.DOUBLE)],
         types)
     agg = HashAggregationOperatorFactory(list(group_channels), aggs, types)
-    return batch, [fp, agg]
+    return batch, [fp, agg], [r for r in rows
+                              if r[1] is not None and r[1] < 90]
+
+
+def _grouped(rows, key_channel, aggs):
+    """A ``collections`` GROUP BY of python rows: sorted
+    ``(key, agg...)`` tuples with SQL's null rules (a null key is a
+    group; aggregates skip null inputs; count(*) has ``channel`` None)."""
+    groups = collections.defaultdict(list)
+    for r in rows:
+        groups[r[key_channel]].append(r)
+    fold = {"sum": sum, "min": min, "max": max, "count": len}
+    out = []
+    for key, members in groups.items():
+        cells = [key]
+        for a in aggs:
+            vals = [r if a.channel is None else r[a.channel]
+                    for r in members
+                    if a.channel is None or r[a.channel] is not None]
+            cells.append(fold[a.prim](vals)
+                         if vals or a.prim == "count" else None)
+        out.append(tuple(cells))
+    return sorted(out, key=repr)
 
 
 def _run_chain(batch, factories, cfg):
@@ -278,27 +266,21 @@ def _run_chain(batch, factories, cfg):
 
 def test_prereduce_hash_chain_parity():
     """Hand-built chain: the pre-reduced segment + merge aggregation
-    must reproduce the unfused aggregation exactly — nullable dict key
-    (null group included), sum/count/count(*)/min/max with nulls."""
+    against a ``collections`` group-by of the input rows — nullable dict
+    key (null group included), sum/count/count(*)/min/max with nulls."""
     from presto_tpu.exec.aggregation import AggChannel
-    from presto_tpu.exec.fusion import FusedSegmentOperatorFactory
 
     aggs = [AggChannel("sum", 1, T.BIGINT),
             AggChannel("count", 1, T.BIGINT),
             AggChannel("count", None, T.BIGINT),
             AggChannel("min", 2, T.DOUBLE),
             AggChannel("max", 2, T.DOUBLE)]
-    batch, factories = _agg_chain(aggs)
-    chain_on, rows_on = _run_chain(batch, factories, _cfg())
-    batch, factories = _agg_chain(aggs)
-    chain_off, rows_off = _run_chain(
-        batch, factories, _cfg(fusion_partial_agg=False))
-    assert rows_on == rows_off
-    seg_on = [f for f in chain_on
-              if isinstance(f, FusedSegmentOperatorFactory)]
-    assert seg_on and seg_on[0].agg_spec is not None
-    assert all(f.agg_spec is None for f in chain_off
-               if isinstance(f, FusedSegmentOperatorFactory))
+    batch, factories, live = _agg_chain(aggs)
+    chain, rows = _run_chain(batch, factories, _cfg())
+    assert rows == _grouped(live, 0, aggs)
+    segments = [f for f in chain
+                if isinstance(f, FusedSegmentOperatorFactory)]
+    assert segments and segments[0].agg_spec is not None
 
 
 def test_prereduce_sort_path_fallback():
@@ -308,11 +290,11 @@ def test_prereduce_sort_path_fallback():
 
     aggs = [AggChannel("sum", 1, T.BIGINT),
             AggChannel("count", None, T.BIGINT)]
-    batch, factories = _agg_chain(aggs)
-    on = _run_chain(batch, factories, _cfg(direct_groupby_max_domain=1))
-    batch, factories = _agg_chain(aggs)
-    off = _run_chain(batch, factories, _cfg(fusion_partial_agg=False))
-    assert on[1] == off[1]
+    batch, factories, live = _agg_chain(aggs)
+    chain, rows = _run_chain(batch, factories,
+                             _cfg(direct_groupby_max_domain=1))
+    assert chain[1].agg_spec is not None
+    assert rows == _grouped(live, 0, aggs)
 
 
 def test_prereduce_global_empty_scan(runner_on):
@@ -340,10 +322,9 @@ def test_prereduce_global_default_row(runner_on):
 
 
 def test_q1_prereduce_dispatch_pin(runner_on):
-    """The acceptance pin: TPC-H Q1 at SF0.01 with fusion_partial_agg on
-    runs with strictly fewer jit dispatches than PR 3's 5, the scan rows
-    fold into in-segment partial states, and the downstream aggregation
-    consumes group-sized partials instead of row batches."""
+    """TPC-H Q1 at SF0.01 runs with fewer than 5 jit dispatches, the
+    scan rows fold into in-segment partial states, and the downstream
+    aggregation consumes group-sized partials instead of row batches."""
     runner_on.execute(QUERIES[1])
     task = runner_on._last_task
     jc = task.jit_counters()
@@ -363,42 +344,9 @@ def test_q6_prereduce_single_dispatch(runner_on):
     assert jc["prereduce_rows"] > 50_000, jc
 
 
-def test_partial_agg_off_restores_pr3_lowering(runner_on):
-    """fusion_partial_agg=false must reproduce the PR 3 lowering
-    exactly: same factory chain (segment without agg_spec, standard
-    aggregation, separate finalize FilterProjects)."""
-    from presto_tpu.exec.aggregation import (
-        GlobalAggregationOperatorFactory, HashAggregationOperatorFactory,
-    )
-    from presto_tpu.sql.optimizer import optimize
-    from presto_tpu.sql.parser import parse_statement
-    from presto_tpu.sql.physical import PhysicalPlanner
-    from presto_tpu.sql.planner import Planner
-
-    cfg = _cfg(fusion_partial_agg=False)
-    plan = optimize(
-        Planner(runner_on.metadata).plan(parse_statement(QUERIES[1])),
-        runner_on.metadata, cfg)
-    phys = PhysicalPlanner(runner_on.registry, cfg).plan(plan)
-    kinds = [type(f).__name__ for f in phys.pipelines[0].factories]
-    # the PR 3 shape: a plain segment feeds a standard aggregation, and
-    # the two finalize FilterProjects fuse into their own segment
-    assert kinds == [
-        "TableScanOperatorFactory", "FusedSegmentOperatorFactory",
-        "HashAggregationOperatorFactory", "FusedSegmentOperatorFactory",
-        "OrderByOperatorFactory", "OutputCollectorFactory"], kinds
-    for p in phys.pipelines:
-        for f in p.factories:
-            if isinstance(f, FusedSegmentOperatorFactory):
-                assert f.agg_spec is None
-            if isinstance(f, (HashAggregationOperatorFactory,
-                              GlobalAggregationOperatorFactory)):
-                assert f.post_projections is None
-
-
 def test_partial_agg_on_q1_lowering(runner_on):
-    """With the gate on, Q1's pipeline is scan -> pre-reducing segment
-    -> merge aggregation with the finalize projections folded in."""
+    """Q1's pipeline is scan -> pre-reducing segment -> merge
+    aggregation with the finalize projections folded in."""
     from presto_tpu.exec.aggregation import HashAggregationOperatorFactory
     from presto_tpu.sql.optimizer import optimize
     from presto_tpu.sql.parser import parse_statement
@@ -424,57 +372,6 @@ def test_partial_agg_on_q1_lowering(runner_on):
     assert {a.prim for a in agg.aggs} <= {"sum", "min", "max"}
 
 
-def test_session_property_toggles_partial_agg():
-    r = LocalQueryRunner.tpch(scale=0.01)
-    r.execute("set session fusion_partial_agg = false")
-    r.execute(QUERIES[6])
-    off = r._last_task.jit_counters()
-    r.execute("set session fusion_partial_agg = true")
-    r.execute(QUERIES[6])
-    on = r._last_task.jit_counters()
-    assert off["prereduce_rows"] == 0
-    assert on["prereduce_rows"] > 0
-    assert on["dispatches"] < off["dispatches"]
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("qnum", sorted(QUERIES))
-def test_tpch_partial_agg_parity(qnum, runner_on):
-    """fusion_partial_agg on vs off result parity across the full TPC-H
-    suite (partial sums merge in a different association order, so the
-    comparison is approximate like the conformance oracle's)."""
-    r_off = _PAGG_OFF_RUNNERS.setdefault(
-        "tpch", LocalQueryRunner.tpch(
-            scale=0.01, config=_cfg(fusion_partial_agg=False)))
-    ra = runner_on.execute(QUERIES[qnum])
-    rb = r_off.execute(QUERIES[qnum])
-    assert ra.column_names == rb.column_names
-    assert_rows_close(ra.rows, rb.rows)
-
-
-_PAGG_OFF_RUNNERS = {}
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("qnum", sorted(__import__(
-    "tpcds_queries").QUERIES))
-def test_tpcds_partial_agg_parity(qnum, runner_on):
-    """fusion_partial_agg on/off parity across the TPC-DS suite."""
-    from tpcds_queries import QUERIES as DSQ
-
-    r_off = _PAGG_OFF_RUNNERS.setdefault(
-        "tpcds", LocalQueryRunner.tpch(
-            scale=0.003, config=_cfg(fusion_partial_agg=False)))
-    r_on = _PAGG_OFF_RUNNERS.setdefault(
-        "tpcds_on", LocalQueryRunner.tpch(scale=0.003))
-    for r in (r_off, r_on):
-        r.metadata.default_catalog = "tpcds"
-    ra = r_on.execute(DSQ[qnum])
-    rb = r_off.execute(DSQ[qnum])
-    assert ra.column_names == rb.column_names
-    assert_rows_close(ra.rows, rb.rows)
-
-
 # ---------------------------------------------------------------------------
 # shared dictionary interning (one compile per (table, expr))
 # ---------------------------------------------------------------------------
@@ -497,7 +394,7 @@ def test_shared_interning_compiles_once():
         B.comparison(">", B.ref(0, T.BIGINT), B.const(5, T.BIGINT)),
         [B.ref(1, vt), B.ref(2, vt)], [T.BIGINT, vt, vt])
     collector = OutputCollectorFactory()
-    cfg = _cfg(pipeline_fusion=False, task_concurrency=1)
+    cfg = _cfg(task_concurrency=1)
     task = execute_pipelines(
         [Pipeline([scan, fp, collector], splits, name="t")], cfg)
     jc = task.jit_counters()
@@ -508,20 +405,21 @@ def test_shared_interning_compiles_once():
 
 def test_memory_interning_shares_table_dictionaries():
     """Inserted batches re-code dictionary columns into per-table shared
-    interning tables, so multi-batch scans compile once per expression."""
-    r = LocalQueryRunner.tpch(
-        scale=0.01, config=_cfg(pipeline_fusion=False, task_concurrency=1))
-    r.execute("create table memory.interning_t (k bigint, s varchar)")
-    for i in range(3):
-        r.execute(f"insert into memory.interning_t values "
-                  f"({i}, 'v{i}'), ({i + 10}, 'w{i}')")
+    interning tables, so a scan of many batches compiles as a scan of
+    one does: the count does not grow with the batches inserted."""
+    r = LocalQueryRunner.tpch(scale=0.01, config=_cfg(task_concurrency=1))
     conn = r.registry.get("memory")
-    dicts = {id(b.columns[1].dictionary)
-             for b in conn.tables["interning_t"].batches}
-    assert len(dicts) == 1
-    res = r.execute("select s from memory.interning_t where k >= 0")
-    assert len(res.rows) == 6
-    assert r._last_task.jit_counters()["compiles"] == 1
+    for table, inserts in (("interning_one", 1), ("interning_t", 3)):
+        r.execute(f"create table memory.{table} (k bigint, s varchar)")
+        for i in range(inserts):
+            r.execute(f"insert into memory.{table} values "
+                      f"({i}, 'v{i}'), ({i + 10}, 'w{i}')")
+        batches = conn.tables[table].batches
+        assert len(batches) == inserts
+        assert len({id(b.columns[1].dictionary) for b in batches}) == 1
+        res = r.execute(f"select s from memory.{table} where k >= 0")
+        assert len(res.rows) == 2 * inserts
+        assert r._last_task.jit_counters()["compiles"] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -696,64 +594,11 @@ def test_explain_analyze_counts_the_join_tiers(runner_on):
             in text.split("kernel tiers: ")[1].splitlines()[0])
 
 
-def test_device_join_probe_off_restores_pr9_lowering(runner_on):
-    """device_join_probe=false must reproduce the PR 9 chains exactly:
-    no ProbeStage anywhere, probe operators back in the chain; the
-    builds still publish the direct-address index (the tier follows the
-    keys, not this option), and the stand-alone probes read it."""
-    from presto_tpu.exec.fusion import ProbeStage
-    from presto_tpu.exec.joinop import LookupJoinOperatorFactory
-
-    cfg = _cfg(device_join_probe=False)
-    pipelines = _plan_chains(runner_on, QUERIES[3], cfg)
-    kinds = [type(f).__name__ for p in pipelines for f in p.factories]
-    assert "LookupJoinOperatorFactory" in kinds
-    for p in pipelines:
-        for f in p.factories:
-            if isinstance(f, FusedSegmentOperatorFactory):
-                assert not any(isinstance(s, ProbeStage)
-                               for s in f.stages)
-    r = LocalQueryRunner.tpch(scale=0.01, config=cfg)
-    r.execute(QUERIES[3])
-    assert _join_tiers(r._last_task) == {
-        "HashBuildOperator": ["dense", "dense"],
-        "LookupJoinOperator": ["dense", "dense"]}
-
-
-def test_all_new_knobs_off_restores_pr9_chain_shapes(runner_on):
-    """The acceptance pin: hash_groupby_enabled=false +
-    device_join_probe=false + fusion_final_merge=false (+ the
-    cost-based gate off) leaves every lowered chain shaped exactly as
-    PR 9 left it, and results match the defaults-on engine."""
-    from presto_tpu.exec.fusion import ProbeStage
-
-    cfg = _cfg(hash_groupby_enabled=False, device_join_probe=False,
-               fusion_final_merge=False, prereduce_cost_based=False)
-    r_off = LocalQueryRunner.tpch(scale=0.01, config=cfg)
-    for qn in (1, 3, 6):
-        pipelines = _plan_chains(runner_on, QUERIES[qn], cfg)
-        for p in pipelines:
-            for f in p.factories:
-                if isinstance(f, FusedSegmentOperatorFactory):
-                    assert not any(isinstance(s, ProbeStage)
-                                   for s in f.stages)
-        ra = runner_on.execute(QUERIES[qn])
-        rb = r_off.execute(QUERIES[qn])
-        assert_rows_close(ra.rows, rb.rows)
-    # the PR 9 Q1 lowering pin still holds under the off-config
-    pipelines = _plan_chains(runner_on, QUERIES[1], cfg)
-    kinds = [type(f).__name__ for f in pipelines[0].factories]
-    assert kinds == [
-        "TableScanOperatorFactory", "FusedSegmentOperatorFactory",
-        "HashAggregationOperatorFactory", "OrderByOperatorFactory",
-        "OutputCollectorFactory"], kinds
-
-
 def test_final_merge_fuses_exchange_fed_grouped_merge():
     """A grouped FINAL merge directly on a remote exchange absorbs into
     an empty-stage coalescing segment with the finalize projections
-    folded into the merge finish; fusion_final_merge=false restores the
-    PR 9 chain exactly."""
+    folded into the merge finish: the chain is exchange -> segment ->
+    merge aggregation, the projection gone."""
     from presto_tpu.exec.aggregation import (
         AggChannel, HashAggregationOperatorFactory,
     )
@@ -769,14 +614,12 @@ def test_final_merge_fuses_exchange_fed_grouped_merge():
         None, [B.ref(0, T.BIGINT), B.ref(1, T.DOUBLE)], types)
     exch = ExchangeOperatorFactory(["http://x/v1/task/t/results/0"])
     chain = fuse_chain([exch, agg, fin], _cfg())
-    assert isinstance(chain[1], FusedSegmentOperatorFactory)
-    assert chain[1].agg_spec is not None
+    assert [type(f).__name__ for f in chain] == [
+        "ExchangeOperatorFactory", "FusedSegmentOperatorFactory",
+        "HashAggregationOperatorFactory"]
+    assert chain[1].stages == [] and chain[1].agg_spec is not None
     assert chain[1].coalesce_rows == _cfg().scan_batch_rows
     assert chain[2].post_projections
-    off = fuse_chain([exch, agg, fin], _cfg(fusion_final_merge=False))
-    assert [type(f).__name__ for f in off] == [
-        "ExchangeOperatorFactory", "HashAggregationOperatorFactory",
-        "FilterProjectOperatorFactory"]
 
 
 def test_final_merge_skips_global_merges():
@@ -800,8 +643,8 @@ def test_final_merge_skips_global_merges():
 def test_cost_based_raw_emission_switch():
     """A pre-reducing segment whose observed groups/rows ratio says
     grouping is not reducing flips to raw partial-state emission after
-    the first batch — results stay exact, and prereduce_rows stops
-    accumulating once flipped."""
+    the first batch — results stay exact against a ``collections``
+    group-by, and prereduce_rows stops accumulating once flipped."""
     from presto_tpu.exec.aggregation import AggChannel
     from presto_tpu.exec.aggregation import HashAggregationOperatorFactory
 
@@ -828,25 +671,25 @@ def test_cost_based_raw_emission_switch():
         [0], [AggChannel("sum", 1, T.DOUBLE),
               AggChannel("count", None, T.BIGINT)], types)
 
-    def run(cfg):
-        collector = OutputCollectorFactory()
-        chain = fuse_chain(
-            [ValuesOperatorFactory([mk(rows1).to_device(),
-                                    mk(rows2).to_device()]),
-             fp, agg], cfg)
-        task = execute_pipelines(
-            [Pipeline(chain + [collector], name="t")], cfg)
-        return task, sorted(collector.rows())
-
-    cfg_on = _cfg(direct_groupby_max_domain=1 << 14)
-    task_on, rows_on = run(cfg_on)
-    cfg_off = _cfg(direct_groupby_max_domain=1 << 14,
-                   prereduce_cost_based=False)
-    task_off, rows_off = run(cfg_off)
-    assert rows_on == rows_off
-    # with the gate on, only the FIRST batch pre-reduced; off, both did
-    assert 0 < task_on.jit_counters()["prereduce_rows"] \
-        < task_off.jit_counters()["prereduce_rows"]
+    cfg = _cfg(direct_groupby_max_domain=1 << 14)
+    collector = OutputCollectorFactory()
+    chain = fuse_chain(
+        [ValuesOperatorFactory([mk(rows1).to_device(),
+                                mk(rows2).to_device()]),
+         fp, agg], cfg)
+    assert chain[1].agg_spec is not None
+    task = execute_pipelines(
+        [Pipeline(chain + [collector], name="t")], cfg)
+    sums = collections.Counter()
+    counts = collections.Counter()
+    for code, v in rows1 + rows2:
+        sums[f"k{code}"] += v
+        counts[f"k{code}"] += 1
+    assert sorted(collector.rows()) == sorted(
+        (k, sums[k], counts[k]) for k in sums)
+    # only the FIRST batch pre-reduced: it emitted a group a row, and
+    # the second went out raw in the partial schema
+    assert task.jit_counters()["prereduce_rows"] == n
 
 
 def test_hash_groupby_overflow_seam_exact(runner_on):
@@ -867,68 +710,57 @@ def test_hash_groupby_overflow_seam_exact(runner_on):
     assert "hash+sort" in tiers
 
 
-def test_hash_groupby_tier_engages_on_unbounded_keys(runner_on):
-    sql = "select l_partkey, count(*) from lineitem group by l_partkey"
+def _group_tiers(task):
+    return [s.kernel_tier for s in task.operator_stats if s.kernel_tier]
+
+
+def _count_by(column):
+    """``select column, count(*) from lineitem group by column`` by a
+    ``collections.Counter`` over the generated column."""
+    values = tpch_reference.host_columns(
+        0.01, {"lineitem": [column]})[column]
+    return sorted(collections.Counter(values.tolist()).items())
+
+
+def test_hash_groupby_tier_engages_on_unbounded_keys():
+    """An unbounded key past the row threshold accumulates in the
+    device-resident table from its first batch (threshold 0), and
+    answers as a plain count does."""
     r = LocalQueryRunner.tpch(scale=0.01,
                               config=_cfg(hash_groupby_min_rows=0))
-    ra = r.execute(sql)
-    tiers = [s.kernel_tier for s in r._last_task.operator_stats
-             if s.kernel_tier]
-    assert "hash" in tiers
-    r_off = LocalQueryRunner.tpch(
-        scale=0.01, config=_cfg(hash_groupby_enabled=False))
-    rb = r_off.execute(sql)
-    assert_rows_close(ra.rows, rb.rows)
-    tiers = [s.kernel_tier for s in r_off._last_task.operator_stats
-             if s.kernel_tier]
-    assert "hash" not in tiers and "sort" in tiers
+    res = r.execute(
+        "select l_partkey, count(*) from lineitem group by l_partkey")
+    assert _group_tiers(r._last_task) == ["hash"]
+    assert sorted(res.rows) == _count_by("l_partkey")
 
 
-def test_session_property_toggles_hash_tier():
-    r = LocalQueryRunner.tpch(scale=0.01)
-    sql = "select l_partkey, count(*) from lineitem group by l_partkey"
-    r.execute("set session hash_groupby_min_rows = 0")
-    r.execute("set session hash_groupby_enabled = false")
-    r.execute(sql)
-    assert not any(s.kernel_tier == "hash"
-                   for s in r._last_task.operator_stats)
-    r.execute("set session hash_groupby_enabled = true")
-    r.execute(sql)
-    assert any(s.kernel_tier == "hash"
-               for s in r._last_task.operator_stats)
+# key column, what the config sets to reach the tier at 60 K rows, the
+# tier the aggregation reports, whether it streams
+GROUPBY_TIERS = {
+    # dictionary codes: a bounded domain
+    "direct": ("l_shipmode", {}, "direct", False),
+    # unbounded, past hash_groupby_min_rows part of the way through
+    "hash": ("l_partkey", {"hash_groupby_min_rows": 1 << 14}, "hash",
+             False),
+    # unbounded, under the default threshold of 1 << 17 rows
+    "sort": ("l_partkey", {}, "sort", False),
+    # the scan's sort key: rows arrive clustered, no tier at all
+    "streaming": ("l_orderkey", {}, "", True),
+}
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("qnum", sorted(QUERIES))
-def test_tpch_hash_tier_parity(qnum, runner_on):
-    """All new knobs off vs defaults on: result parity across the full
-    TPC-H suite (the per-knob acceptance sweep)."""
-    r_off = _PAGG_OFF_RUNNERS.setdefault(
-        "pr10_off", LocalQueryRunner.tpch(scale=0.01, config=_cfg(
-            hash_groupby_enabled=False, device_join_probe=False,
-            fusion_final_merge=False, prereduce_cost_based=False)))
-    ra = runner_on.execute(QUERIES[qnum])
-    rb = r_off.execute(QUERIES[qnum])
-    assert ra.column_names == rb.column_names
-    assert_rows_close(ra.rows, rb.rows)
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("qnum", sorted(__import__(
-    "tpcds_queries").QUERIES))
-def test_tpcds_hash_tier_parity(qnum):
-    """All new knobs off vs defaults on across the TPC-DS suite."""
-    from tpcds_queries import QUERIES as DSQ
-
-    r_off = _PAGG_OFF_RUNNERS.setdefault(
-        "pr10_ds_off", LocalQueryRunner.tpch(scale=0.003, config=_cfg(
-            hash_groupby_enabled=False, device_join_probe=False,
-            fusion_final_merge=False, prereduce_cost_based=False)))
-    r_on = _PAGG_OFF_RUNNERS.setdefault(
-        "pr10_ds_on", LocalQueryRunner.tpch(scale=0.003))
-    for r in (r_off, r_on):
-        r.metadata.default_catalog = "tpcds"
-    ra = r_on.execute(DSQ[qnum])
-    rb = r_off.execute(DSQ[qnum])
-    assert ra.column_names == rb.column_names
-    assert_rows_close(ra.rows, rb.rows)
+@pytest.mark.parametrize("case", sorted(GROUPBY_TIERS))
+def test_groupby_tier_follows_the_input(case):
+    """Both sides of every choice the aggregation makes alone: which
+    tier serves a GROUP BY follows the key's type, the rows seen and the
+    scan's order, and every tier counts as a Counter does."""
+    column, knobs, tier, streams = GROUPBY_TIERS[case]
+    r = LocalQueryRunner.tpch(scale=0.01, config=_cfg(**knobs))
+    res = r.execute(
+        f"select {column}, count(*) from lineitem group by {column}")
+    stats = r._last_task.operator_stats
+    assert any("StreamingAggregation" in s.operator
+               for s in stats) == streams
+    tiers = set(_group_tiers(r._last_task))
+    assert tiers == ({tier} if tier else set()), tiers
+    assert sorted(res.rows) == _count_by(column)

@@ -1,64 +1,15 @@
-"""Pallas kernels: correctness under interpret mode.
+"""The open-addressing table (ops/hashtable.py) against numpy oracles.
 
-On CPU the kernels run through the Pallas interpreter; the real-TPU
-compile path was validated on v5e (see ops/pallas_groupby.py docstring
-for the measured status vs the XLA einsum).  The open-addressing table
-section covers the hash tier's XLA claim loop (ops/hashtable.py) against
-numpy oracles: collision storms, the rehash boundary (including the
-min/max identity carry), null keys, and the 1-byte hash-prefix reject."""
+The hash tier's XLA claim loop, as the group-by and the join lookup use
+it: collision storms, the rehash boundary (including the min/max identity
+carry), null keys, the 1-byte hash-prefix reject, a full table, and
+duplicate and missing probe keys."""
 
 import collections
 
 import numpy as np
 import pytest
 
-from presto_tpu.ops import pallas_groupby as P
-
-
-@pytest.mark.skipif(not P.available(), reason="pallas unavailable")
-@pytest.mark.parametrize("n,a,g", [(4096, 5, 8), (65536, 13, 8),
-                                   (8192, 3, 31)])
-def test_segment_sums_match_numpy(n, a, g):
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(0)
-    gid = rng.integers(0, g, n).astype(np.int32)
-    vals = rng.uniform(0, 1e5, (n, a))
-    hi = vals.astype(np.float32)
-    lo = (vals - hi.astype(np.float64)).astype(np.float32)
-    out = P.direct_segment_sums_pallas(
-        jnp.asarray(gid), jnp.asarray(hi), jnp.asarray(lo), g,
-        interpret=True)
-    ref = np.zeros((g, a))
-    np.add.at(ref, gid, vals)
-    err = np.abs(np.asarray(out) - ref) / np.maximum(np.abs(ref), 1)
-    # per-dot f32 rounding bounds the error (same bound as the einsum
-    # path); the compensated pairs keep cross-block accumulation exact
-    assert err.max() < 1e-6
-
-
-@pytest.mark.skipif(not P.available(), reason="pallas unavailable")
-def test_engine_results_identical_with_pallas_flag(monkeypatch):
-    """The engine must produce identical Q1-shape results whichever
-    reduction path is active (flag plumbing check; on CPU the pallas
-    gate also requires the TPU backend, so this exercises the gate)."""
-    import presto_tpu.ops.groupby as G
-
-    monkeypatch.setenv("PRESTO_TPU_PALLAS", "1")
-    from presto_tpu.localrunner import LocalQueryRunner
-
-    r = LocalQueryRunner.tpch(scale=0.01)
-    sql = ("select l_returnflag, l_linestatus, sum(l_quantity), count(*) "
-           "from lineitem group by l_returnflag, l_linestatus")
-    a = sorted(r.execute(sql).rows)
-    monkeypatch.setenv("PRESTO_TPU_PALLAS", "0")
-    b = sorted(r.execute(sql).rows)
-    assert a == b
-
-
-# ---------------------------------------------------------------------------
-# open-addressing hash table (ops/hashtable.py)
-# ---------------------------------------------------------------------------
 
 def _groupby_oracle(keys, valid, vals):
     ref_sum = collections.defaultdict(float)

@@ -293,6 +293,34 @@ LOOKUP_CASES = {
     "packed_two_channel": ([(1, 10), (1, 20), (2, 10), (2, 10), (3, -4)],
                            [(1, 10), (2, 10), (2, 20), (1, 20), (3, 10),
                             (0, 10), (1, None), (3, -4)]),
+    "packed_null_build": ([(1, 10), (None, 10), (2, None), (2, 20)],
+                          [(1, 10), (2, 20), (2, 10), (None, 10)]),
+}
+# cases only the operators see, whose keys leave the index for a reason
+# other than LOOKUP_CASES' "over_the_bound": (build rows, probe rows,
+# key type, the tier HashBuildOperator.finish picks on the CPU)
+_WIDE = 1 << 13       # two spans of _WIDE + 1: a product past the index
+OPERATOR_CASES = {
+    # codes of ONE dictionary (the operators' contract; both sides are
+    # coded into it below): the codes are the ids, so the index
+    "varchar": ([("ash",), ("elm",), ("elm",), (None,), ("oak",)],
+                [("elm",), ("fir",), (None,), ("ash",), ("oak",),
+                 ("oak",)], T.VARCHAR, "dense"),
+    # not an integer word: the open-addressing table, on every backend
+    "double": ([(0.5,), (-2.25,), (0.5,), (None,), (1e300,)],
+               [(0.5,), (1e300,), (0.25,), (None,), (-2.25,)],
+               T.DOUBLE, "hash"),
+    # the packed ids fit int64 and not the index: sorted ids, searched
+    "packed_over_the_bound": (
+        [(0, 0), (_WIDE, _WIDE), (5, -7), (5, -7), (_WIDE, 0)],
+        [(5, -7), (_WIDE, _WIDE), (0, 1), (_WIDE, 0), (0, 0), (5, None)],
+        T.BIGINT, "sorted"),
+    # a span of 2**62 and more would overflow (value - min + 2): no ids
+    # at all, the table compares the key words themselves
+    "span_past_the_ids": (
+        [(-(1 << 61),), (1 << 61,), (7,), (7,)],
+        [(7,), (1 << 61,), (-(1 << 61),), (0,), (None,)],
+        T.BIGINT, "hash"),
 }
 _DENSE_SIZES = {"bucket_edge_fits": _EDGE, "bucket_edge_over": 2 * _EDGE,
                 "over_the_bound": None}
@@ -412,13 +440,16 @@ def _ref_join_rows(bkeys, pkeys, kind):
 
 
 @pytest.mark.parametrize("kind", JOIN_KINDS)
-@pytest.mark.parametrize("case", sorted(LOOKUP_CASES))
+@pytest.mark.parametrize("case",
+                         sorted(LOOKUP_CASES) + sorted(OPERATOR_CASES))
 def test_join_operators_through_the_dense_index(case, kind):
     """The same cases through HashBuildOperator + LookupJoinOperator:
     the build publishes the index exactly where the span fits, the
     stand-alone probe kernels read it, and every join type answers what
-    a nested loop answers (NOT IN with a NULL in the build included)."""
-    from presto_tpu.batch import batch_from_pylist
+    a nested loop answers (NOT IN with a NULL in the build included).
+    OPERATOR_CASES are the keys the index cannot serve: each takes the
+    tier its type and span pick, with the same answers."""
+    from presto_tpu.batch import Batch, Dictionary, column_from_pylist
     from presto_tpu.exec.driver import Pipeline
     from presto_tpu.exec.joinop import (
         HashBuildOperatorFactory, LookupJoinOperatorFactory,
@@ -428,21 +459,32 @@ def test_join_operators_through_the_dense_index(case, kind):
     )
     from presto_tpu.exec.runner import execute_pipelines
 
-    bkeys, pkeys = LOOKUP_CASES[case]
+    if case in OPERATOR_CASES:
+        bkeys, pkeys, key_type, want_tier = OPERATOR_CASES[case]
+    else:
+        bkeys, pkeys = LOOKUP_CASES[case]
+        key_type = T.BIGINT
+        want_tier = "sorted" if case == "over_the_bound" else "dense"
     width = len((bkeys or pkeys)[0])
-    schema = [T.BIGINT] * (width + 1)          # keys..., row number
+    schema = [key_type] * width + [T.BIGINT]   # keys..., row number
     chans = list(range(width))
+    shared = [Dictionary(sorted({k[c] for k in bkeys + pkeys} - {None}))
+              if t.is_dictionary else None
+              for c, t in enumerate(schema[:width])] + [None]
+
+    def batch(keys):
+        rows = [k + (i,) for i, k in enumerate(keys)]
+        return Batch(tuple(
+            column_from_pylist(t, [r[c] for r in rows], shared[c])
+            for c, t in enumerate(schema)), len(rows))
+
     build = HashBuildOperatorFactory(chans, schema)
     bp = Pipeline([
-        ValuesOperatorFactory(
-            [batch_from_pylist(schema, [k + (i,)
-                                        for i, k in enumerate(bkeys)])]
-            if bkeys else []),
+        ValuesOperatorFactory([batch(bkeys)] if bkeys else []),
         build], name="build")
     out = OutputCollectorFactory()
     pp = Pipeline([
-        ValuesOperatorFactory([batch_from_pylist(
-            schema, [k + (j,) for j, k in enumerate(pkeys)])]),
+        ValuesOperatorFactory([batch(pkeys)]),
         LookupJoinOperatorFactory(
             build, chans, schema,
             "anti" if kind == "notin" else kind,
@@ -457,7 +499,6 @@ def test_join_operators_through_the_dense_index(case, kind):
     assert got == _ref_join_rows(bkeys, pkeys, kind)
     tiers = {s.operator.split(".")[-1]: s.kernel_tier
              for s in task.operator_stats if s.kernel_tier}
-    want_tier = "sorted" if case == "over_the_bound" else "dense"
     assert tiers == {"HashBuildOperator": want_tier,
                      "LookupJoinOperator": want_tier}
 

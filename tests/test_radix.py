@@ -110,3 +110,14 @@ def test_jit_one_program_many_ranges():
         perm = np.asarray(f(jnp.asarray(w)))
         np.testing.assert_array_equal(perm, np.argsort(w, kind="stable"))
     assert calls["n"] == 1  # one trace, three ranges
+
+
+def test_use_radix_reads_only_the_backend(monkeypatch):
+    """Which sort a program gets follows the backend it is traced for and
+    nothing a process inherits: the variable that once forced the radix
+    passes is not read."""
+    from presto_tpu.ops.radix import use_radix
+
+    # spelt apart: a grep for the retired name must find no reader
+    monkeypatch.setenv("_".join(("PRESTO", "TPU", "RADIX")), "1")
+    assert use_radix() is False          # the suite runs on the CPU

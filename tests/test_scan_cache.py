@@ -14,8 +14,6 @@ immutable table stays on the device after its first scan.
 import dataclasses as dc
 import gc
 import json
-import os
-import sys
 import threading
 import urllib.request
 
@@ -30,21 +28,14 @@ from presto_tpu.localrunner import LocalQueryRunner
 from presto_tpu.server.dqr import DistributedQueryRunner
 from presto_tpu import types as T
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from benchmark import check, manifest, refdata  # noqa: E402
+from tpch_reference import (
+    RTOL, STATEMENTS, compare, reference_answers, statement,
+)
 
 SCALE = 0.01
-RTOL = 1e-6
-STATEMENTS = ("q1", "q6", "q3")
 #: table scans by leaf tasks: two workers, each scanning its half of
 #: every table the statement reads
 LEAF_SCANS = {"q1": 2, "q6": 2, "q3": 6}
-
-
-def statement(name):
-    with open(manifest.path("statements", name + ".sql")) as f:
-        return f.read()
 
 
 def _fetch(uri):
@@ -54,25 +45,7 @@ def _fetch(uri):
 
 @pytest.fixture(scope="module")
 def want():
-    refs = {name: manifest.load_module("references", name)
-            for name in STATEMENTS}
-    wanted = {}
-    for ref in refs.values():
-        for table, cols in ref.COLUMNS.items():
-            wanted.setdefault(table, set()).update(cols)
-    columns, _nbytes = refdata.host_columns("tpch", SCALE, wanted)
-    out = {name: ref.reference(columns) for name, ref in refs.items()}
-    # the CPU engine folds Q6's ``0.06 + 0.01`` in IEEE f64, one ulp
-    # under the 0.07 the reference (and the chip) compare with, so here
-    # Q6 is held to the same numpy computation with the bounds folded so
-    sd, disc = columns["l_shipdate"], columns["l_discount"]
-    sel = ((sd >= refdata.days("1994-01-01"))
-           & (sd < refdata.days("1995-01-01"))
-           & (disc >= 0.06 - 0.01) & (disc <= 0.06 + 0.01)
-           & (columns["l_quantity"] < 24))
-    out["q6"] = [(float((columns["l_extendedprice"][sel]
-                         * disc[sel]).sum()),)]
-    return out
+    return reference_answers(SCALE)
 
 
 @pytest.fixture(scope="module")
@@ -116,7 +89,7 @@ def _stats(served, name, which):
 @pytest.mark.parametrize("name", STATEMENTS)
 def test_answers_equal_the_reference(served, want, name, which):
     got = served[name, which]
-    check.compare(got["rows"], want[name], RTOL)
+    compare(got["rows"], want[name], RTOL)
     assert not got["detail"].get("resultCached")
 
 
@@ -365,7 +338,7 @@ def test_two_queries_started_together_leave_one_kept_run(want):
             t.join(timeout=300)
         assert not errors and not any(t.is_alive() for t in threads)
         for who in (0, 1):
-            check.compare(rows[who], want["q1"], RTOL)
+            compare(rows[who], want["q1"], RTOL)
         for w in dqr.workers:       # a task each: its half of lineitem
             assert SCAN_CACHE.stats(
                 w.task_manager.registry.connectors())["entries"] == 1

@@ -45,6 +45,20 @@ class TestSessionProperties:
         with pytest.raises(SessionError):
             s.set_property("spill_partitions", "banana")
 
+    def test_every_session_property_names_a_config_field(self):
+        """A property whose field was deleted from EngineConfig fails
+        here, not in a user's session."""
+        import dataclasses
+
+        from presto_tpu.config import EngineConfig
+        from presto_tpu.session import SESSION_PROPERTIES
+
+        fields = {f.name for f in dataclasses.fields(EngineConfig)}
+        dangling = {name: field
+                    for name, (field, _parse) in SESSION_PROPERTIES.items()
+                    if field not in fields}
+        assert not dangling
+
 
 class TestAccessControl:
     def _runner(self, user: str):

@@ -111,27 +111,6 @@ class TestPlanDiff:
         assert catalog == "tpch"
 
 
-class TestFusionReport:
-    def test_report_smoke_check_mode(self, capsys):
-        """tools/fusion_report.py --execute --check is the CI smoke: it
-        plans + runs queries fused and unfused, asserts parity, and
-        fails when fusion regresses launch counts to zero coverage."""
-        import importlib
-        import os
-        import sys
-
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
-                                        "tools"))
-        fusion_report = importlib.import_module("fusion_report")
-        rc = fusion_report.main(
-            ["q6", "q3", "--scale", "0.002", "--execute", "--check"])
-        out = capsys.readouterr().out
-        assert rc == 0, out
-        assert "fused segments" in out
-        assert "parity=True" in out
-        assert "FusedSegment{" in out
-
-
 class TestExchangeReport:
     def test_boundary_modes_and_q3_collective_check(self, capsys):
         """tools/exchange_report.py renders one row per fragment

@@ -31,7 +31,7 @@ import threading
 import time
 
 # runnable from anywhere: `python tools/chaos_run.py` puts tools/ on the
-# path, not the repo root (same shim as fusion_report.py)
+# path, not the repo root
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
 if "--mode" in sys.argv and "mesh" in sys.argv and \

@@ -1,6 +1,5 @@
-"""Per-fragment-boundary exchange-mode report (PR 11 companion to
-tools/fusion_report.py: that tool diffs the physical dispatch structure,
-this one diffs the DATA PLANE each fragment boundary rides).
+"""Per-fragment-boundary exchange-mode report: the DATA PLANE each
+fragment boundary rides.
 
 For each query: the fragment DAG with one row per boundary —
 producer fragment -> consumer fragment, the producer's output

@@ -414,7 +414,7 @@ def run_qps(scale=0.003, levels=(1, 2, 4, 8), requests_per_client=4,
             hard_concurrency=8, per_user_limit=4, quiet=False,
             hot_repeat=False, result_cache=False):
     """Boot the cluster, run every concurrency level, return the report
-    dict (the bench_concurrent_qps payload).  ``hot_repeat`` drives the
+    dict.  ``hot_repeat`` drives the
     repeated-verbatim statement mix; ``result_cache`` turns the
     cross-query result cache on for the cluster (hits are reported per
     level beside the plan-cache numbers either way)."""
