@@ -13,8 +13,10 @@ misses) and by XLA's backend-compile event, which JAX raises around
 as well as for a compile, so the cold run's count is of programs built,
 not of cache misses.  The warm run must also scan nothing: every table
 scan of every leaf task is a hit of the device-resident scan cache
-(``scan_cache_hits`` / ``scan_cache_misses`` in ``queryStats``).  A
-worker's scan batch must sit on a TPU device.
+(``scan_cache_hits`` / ``scan_cache_misses`` in ``queryStats``), and a
+leaf task of Q1 or Q6 must hold its pre-reduced partial states on the
+device and flush them at most twice (``prereduce_batches_held`` /
+``prereduce_flushes``).  A worker's scan batch must sit on a TPU device.
 
 ``--chips 4``: only the collective data plane (``mesh_device_exchange``,
 four co-resident workers on one 4-device mesh), Q1 and Q3 at SF1 against
@@ -319,6 +321,12 @@ def one_chip(xla: XlaCompiles) -> None:
                 line[f"{temp}_scan_cache"] = [
                     int(stats["scan_cache_hits"]),
                     int(stats["scan_cache_misses"])]
+                # dispatched batches whose partial states stayed on the
+                # device, and their hand-overs to the sink (exec/fusion.py)
+                line[f"{temp}_prereduce_batches_held"] = int(
+                    stats["prereduce_batches_held"])
+                line[f"{temp}_prereduce_flushes"] = int(
+                    stats["prereduce_flushes"])
                 line[f"{temp}_max_rel_err"] = compare(
                     f"{name} {temp}", rows, want[name])
                 line["rows"] = len(rows)
@@ -331,6 +339,16 @@ def one_chip(xla: XlaCompiles) -> None:
                     f"{name}: the warm run compiled "
                     f"({line['warm_jit_compiles']} jit, {len(compiled)} "
                     f"XLA: {sorted(set(compiled))})")
+            # (held, flushes) of each task that held partials on the device
+            holding = [(ts["prereduce_batches_held"], ts["prereduce_flushes"])
+                       for tasks in (detail.get("taskStats") or {}).values()
+                       for ts in tasks if ts.get("prereduce_batches_held")]
+            if name in ("q1", "q6") and (
+                    not holding or any(f > 2 for _held, f in holding)):
+                raise AssertionError(
+                    f"{name}: a leaf task hands its pre-reduced partials to "
+                    f"the sink once, at most twice; the warm run's tasks "
+                    f"read (held, flushes) {holding}")
             hits, misses = line["warm_scan_cache"]
             if misses or not hits:
                 raise AssertionError(

@@ -140,6 +140,13 @@ class OperatorStats:
     # emitted partial states, not row batches — tests pin on this
     # instead of eyeballing operator chains.
     prereduce_rows: int = 0
+    # bounded pre-reduce (exec/fusion.py): dispatched batches whose
+    # partial states stayed on the device, and hand-overs of held
+    # partials to the consumer.  A task under partial_agg_max_bytes
+    # flushes once a segment; both 0 for the sort path and raw emission,
+    # which emit a batch a dispatch
+    prereduce_batches_held: int = 0
+    prereduce_flushes: int = 0
     # which kernel tier served this operator's group-by/join hot loop.
     # Group-by: "hash" (device-resident open-addressing,
     # ops/hashtable.py), "direct" (bounded-domain), "sort", "stream"
@@ -203,6 +210,8 @@ class TaskStats:
     jit_compiles: int = 0
     jit_compile_ns: int = 0
     prereduce_rows: int = 0
+    prereduce_batches_held: int = 0
+    prereduce_flushes: int = 0
     # the scan cache's account (OperatorStats.scan_cache_*), summed
     scan_cache_hits: int = 0
     scan_cache_misses: int = 0
@@ -259,7 +268,7 @@ class TaskStats:
         self.jit_dispatches += s.jit_dispatches
         self.jit_compiles += s.jit_compiles
         self.jit_compile_ns += s.jit_compile_ns
-        self.prereduce_rows += s.prereduce_rows
+        _add_prereduce(self, s)
         _add_scan_cache(self, s)
 
     def as_dict(self) -> Dict:
@@ -288,6 +297,8 @@ class StageStats:
     jit_compiles: int = 0
     jit_compile_ns: int = 0
     prereduce_rows: int = 0
+    prereduce_batches_held: int = 0
+    prereduce_flushes: int = 0
     # the scan cache's account (OperatorStats.scan_cache_*), summed
     scan_cache_hits: int = 0
     scan_cache_misses: int = 0
@@ -317,7 +328,7 @@ class StageStats:
         self.jit_dispatches += ts.jit_dispatches
         self.jit_compiles += ts.jit_compiles
         self.jit_compile_ns += ts.jit_compile_ns
-        self.prereduce_rows += ts.prereduce_rows
+        _add_prereduce(self, ts)
         _add_scan_cache(self, ts)
         self.peak_memory_bytes = max(self.peak_memory_bytes,
                                      ts.peak_memory_bytes)
@@ -346,6 +357,13 @@ def _add_host_and_xla(into, other) -> None:
     into.xla_cache_hits += other.xla_cache_hits
 
 
+def _add_prereduce(into, other) -> None:
+    """The in-segment pre-reduce's account, summed one level up."""
+    into.prereduce_rows += other.prereduce_rows
+    into.prereduce_batches_held += other.prereduce_batches_held
+    into.prereduce_flushes += other.prereduce_flushes
+
+
 def _add_scan_cache(into, other) -> None:
     """The scan cache's account, summed one level up."""
     into.scan_cache_hits += other.scan_cache_hits
@@ -372,6 +390,8 @@ class QueryStats:
     jit_compiles: int = 0
     jit_compile_ns: int = 0
     prereduce_rows: int = 0
+    prereduce_batches_held: int = 0
+    prereduce_flushes: int = 0
     # the scan cache's account (OperatorStats.scan_cache_*), summed
     scan_cache_hits: int = 0
     scan_cache_misses: int = 0
@@ -406,7 +426,7 @@ class QueryStats:
         self.jit_dispatches += st.jit_dispatches
         self.jit_compiles += st.jit_compiles
         self.jit_compile_ns += st.jit_compile_ns
-        self.prereduce_rows += st.prereduce_rows
+        _add_prereduce(self, st)
         _add_scan_cache(self, st)
         self.peak_memory_bytes = max(self.peak_memory_bytes,
                                      st.peak_memory_bytes)
@@ -443,6 +463,15 @@ def scan_cache_line(stats: Dict) -> str:
     return (f"scan cache: {stats.get('scan_cache_hits', 0)} hits "
             f"({stats.get('scan_cache_hit_bytes', 0) / (1 << 20):.1f} MiB "
             f"handed over), {stats.get('scan_cache_misses', 0)} misses")
+
+
+def prereduce_line(stats: Dict) -> str:
+    """EXPLAIN ANALYZE's line for the bounded pre-reduce's account of a
+    TaskStats / QueryStats dict: partial states kept on the device, a
+    dispatched batch each, and their hand-overs to the consumer."""
+    return (f"prereduce held: {stats.get('prereduce_batches_held', 0)} "
+            f"batches kept on the device, "
+            f"{stats.get('prereduce_flushes', 0)} flushes")
 
 
 def kernel_tier_lines(ops) -> List[str]:

@@ -562,6 +562,22 @@ class _ColView:
         self.dictionary = dictionary
 
 
+def _partition_ids(outs, out_meta, partition):
+    """The sink's partition id of every output row, inside a traced
+    program: ``partition`` is ``_partition_spec``'s (channels, n)."""
+    from presto_tpu.ops.hashing import (
+        partition_of, row_hash, value_hash_triple,
+    )
+
+    channels, nparts = partition
+    triples = []
+    for ch in channels:
+        v, valid = outs[ch]
+        typ, d = out_meta[ch]
+        triples.append(value_hash_triple(_ColView(v, valid, typ, d)))
+    return partition_of(row_hash(triples), nparts)
+
+
 class FusedSegmentOperator(Operator):
     """Executes a fused run of row-local stages as one jitted program per
     batch; optionally coalesces host scan batches first."""
@@ -600,6 +616,17 @@ class FusedSegmentOperator(Operator):
         # cost-based pre-reduce: flipped True when the observed
         # groups/rows ratio says per-batch grouping is not reducing
         self._raw_emit = False
+        # bounded pre-reduce: each dispatched batch's partial states stay
+        # on the device, (outs, count, parts) as the program returned
+        # them, until finish or partial_agg_max_bytes; _held_form says
+        # what the held partials share (None before the first)
+        self._held: List[tuple] = []
+        self._held_form: Optional[tuple] = None
+        self._held_meta: Optional[list] = None
+        self._partial_bytes = 0
+        self._held_bytes = 0
+        # held partials a dispatch of another form merged out of its way
+        self._ready: List[Batch] = []
         # host-coalescing path state
         self._acc: List[List[tuple]] = []          # per-flush batch parts
         self._acc_rows = 0
@@ -626,6 +653,8 @@ class FusedSegmentOperator(Operator):
             self._accumulate(batch)
 
     def get_output(self) -> Optional[Batch]:
+        if self._ready:
+            return self._emit(self._ready.pop())
         if self._coalesce:
             if self._acc_rows >= self._coalesce or (
                     self._finishing and self._acc_rows > 0):
@@ -637,15 +666,15 @@ class FusedSegmentOperator(Operator):
                 if passthrough:
                     return self._emit(batch.compact())
                 return self._emit(self._dispatch(batch))
-            if self._finishing and self._needs_default_row():
+        elif self._pending is not None:
+            batch, self._pending = self._pending, None
+            return self._emit(self._dispatch(batch))
+        if self._finishing:
+            if self._held:
+                return self._emit(self._flush_held())
+            if self._needs_default_row():
                 return self._emit(self._default_partial_batch())
-            return None
-        if self._pending is None:
-            if self._finishing and self._needs_default_row():
-                return self._emit(self._default_partial_batch())
-            return None
-        batch, self._pending = self._pending, None
-        return self._emit(self._dispatch(batch))
+        return None
 
     # a FINAL-merge segment flush below this many rows skips its own
     # dispatch: the rows pass through AS partial states (identity — the
@@ -674,7 +703,8 @@ class FusedSegmentOperator(Operator):
         """A global pre-reduce segment that dispatched nothing still owes
         one default partial row (count=0, other states NULL): the merge
         aggregation's count components re-aggregate with 'sum', and SUM
-        over zero partial rows is NULL where COUNT over empty is 0."""
+        over zero partial rows is NULL where COUNT over empty is 0.  A
+        held partial counts as emitted: it leaves at finish."""
         return (self.agg_spec is not None and self.agg_spec.global_
                 and not self._emitted_any)
 
@@ -693,7 +723,8 @@ class FusedSegmentOperator(Operator):
 
     def is_finished(self) -> bool:
         return self._finishing and self._pending is None \
-            and self._acc_rows == 0 and not self._needs_default_row()
+            and self._acc_rows == 0 and not self._held \
+            and not self._ready and not self._needs_default_row()
 
     # -- host coalescing (scan-adjacent segments) ------------------------
     def _accumulate(self, batch: Batch) -> None:
@@ -715,8 +746,14 @@ class FusedSegmentOperator(Operator):
             parts.append((vals, valid))
         self._acc.append(parts)
         self._acc_rows += n
+        self._charge_memory()
+
+    def _charge_memory(self) -> None:
+        """What the segment keeps between calls: the host rows it is
+        coalescing and the partials it holds on the device."""
         self.ctx.memory.set_bytes(
-            sum(v.nbytes for p in self._acc for v, _ in p))
+            sum(v.nbytes for p in self._acc for v, _ in p)
+            + self._held_bytes)
 
     def _flush(self) -> Batch:
         ncols = len(self._col_types)
@@ -756,8 +793,7 @@ class FusedSegmentOperator(Operator):
                 batch = batch.head(cut)
             self._full_cap = cap = next_bucket(self._coalesce,
                                                self._min_capacity)
-        self.ctx.memory.set_bytes(
-            sum(v.nbytes for p in self._acc for v, _ in p))
+        self._charge_memory()
         return batch.pad_rows(max(cap, self._full_cap))
 
     # -- dispatch --------------------------------------------------------
@@ -893,16 +929,16 @@ class FusedSegmentOperator(Operator):
                 )
 
                 _t0 = _time.perf_counter_ns()
-                built_fn, built_meta = self._compile(batch, df_shapes,
-                                                     probe_metas)
+                built_fn, *built_meta = self._compile(batch, df_shapes,
+                                                      probe_metas)
                 build_ns = _time.perf_counter_ns() - _t0
                 self.ctx.stats.jit_compile_ns += build_ns
                 record_compile(_SEG_KERNELS, build_ns)
                 entry = (timed_first_call(built_fn, self.ctx.stats,
-                                          _SEG_KERNELS), built_meta)
+                                          _SEG_KERNELS), *built_meta)
                 cache_put(_SEG_KERNELS, key, entry)
                 self.ctx.stats.jit_compiles += 1
-            fn, out_meta = entry
+            fn, out_meta, direct = entry
             self.ctx.stats.jit_dispatches += 1
             with activity("dispatch"):
                 outs, count, parts, etotals = fn(
@@ -926,18 +962,159 @@ class FusedSegmentOperator(Operator):
                     overflowed = True
             if not overflowed:
                 break
+        form = None
         if self.agg_spec is not None and not self._raw_emit:
             self.ctx.stats.prereduce_rows += batch.num_rows
+            form = self._bounded_form(out_meta, direct, outs)
+        if self._held and form != self._held_form:
+            merged = self._flush_held()
+            if merged is not None:
+                self._ready.append(merged)
+        if form is not None:
+            return self._hold(form, out_meta, outs, count, parts)
         with activity("device_wait"):
             n = int(count)
         self._observe_reduction(batch.num_rows, n)
         if n == 0:
             return None
+        return self._partial_batch(out_meta, outs, parts, n)
+
+    @staticmethod
+    def _partial_batch(out_meta, outs, parts, n: int) -> Batch:
         cols = tuple(Column(typ, v, valid, d)
                      for (typ, d), (v, valid) in zip(out_meta, outs))
         if parts is not None:
             cols = cols + (Column(T.INTEGER, parts),)
         return Batch(cols, n)
+
+    # -- held partials (bounded pre-reduce) ------------------------------
+    def _bounded_form(self, out_meta, direct, outs) -> Optional[tuple]:
+        """What a pre-reduced dispatch's partial shares with others it
+        can be held and merged with, or None where its size is not
+        bounded at trace time (the sort path) and it leaves as it comes.
+        Bounded are the global form (one row) and the direct path (at
+        most ``direct_groupby_max_domain`` rows, ``direct["doms"]`` as
+        the trace recorded them).  Two direct partials merge when their
+        key codes mean the same: equal domains and nullability, equal
+        dictionary content."""
+        if self.agg_spec.global_:
+            return ("global",)
+        if "doms" not in direct:
+            return None
+        k = len(self.agg_spec.group_channels)
+        return (direct["doms"],
+                tuple(valid is not None for _v, valid in outs[:k]),
+                tuple(None if d is None else (d.content_key(), len(d))
+                      for _t, d in out_meta[:k]))
+
+    def _hold(self, form, out_meta, outs, count, parts) -> Optional[Batch]:
+        """Keep one dispatch's partial on the device: nothing is read
+        back, so the next launch queues behind this one.  Reaching
+        ``partial_agg_max_bytes`` flushes what is held."""
+        if form != self._held_form:
+            # partials of one form are all as large: sized once
+            self._held_form, self._held_meta = form, out_meta
+            self._partial_bytes = sum(
+                a.nbytes for v, valid in outs for a in (v, valid)
+                if a is not None)
+        self._held.append((outs, count, parts))
+        self._held_bytes += self._partial_bytes
+        self._emitted_any = True
+        self.ctx.stats.prereduce_batches_held += 1
+        self._charge_memory()
+        if self._held_bytes >= self.ctx.config.partial_agg_max_bytes:
+            return self._flush_held()
+        return None
+
+    def _flush_held(self) -> Optional[Batch]:
+        """The held partials as one batch: the only hand-over to the
+        consumer, and the only reads from the device, of a task that
+        stayed under the limit."""
+        held, self._held = self._held, []
+        out_meta = self._held_meta
+        self._held_bytes = 0
+        self._charge_memory()
+        self.ctx.stats.prereduce_flushes += 1
+        if len(held) == 1:      # as it came, no launch added
+            outs, count, parts = held[0]
+        elif self.agg_spec.global_:
+            return self._concat_global(held, out_meta)
+        else:
+            outs, count, parts = self._merge_held(held, out_meta)
+        with activity("device_wait"):
+            n = int(count)
+        if n == 0:
+            return None
+        return self._partial_batch(out_meta, outs, parts, n)
+
+    def _concat_global(self, held, out_meta) -> Batch:
+        """Global partials are one row each and their counts host
+        constants: no merge program, the rows come to the host in one
+        overlapped transfer and concatenate there."""
+        import jax
+
+        with activity("device_wait"):
+            host = jax.device_get([(outs, parts)
+                                   for outs, _one, parts in held])
+        outs = tuple(
+            (np.concatenate([o[ci][0] for o, _p in host]),
+             None if host[0][0][ci][1] is None
+             else np.concatenate([o[ci][1] for o, _p in host]))
+            for ci in range(len(host[0][0])))
+        parts = (None if host[0][1] is None
+                 else np.concatenate([p for _o, p in host]))
+        return self._partial_batch(out_meta, outs, parts, len(held))
+
+    def _merge_held(self, held, out_meta):
+        """One program over all held partials (ops/groupby.py
+        ``merge_pre_reduced``), and the merged rows' partition ids where
+        the sink takes them precomputed.  The list pads to a bucketed
+        length with empty partials, so a task builds one or two shapes."""
+        agg = self.agg_spec
+        outs0, count0, _parts = held[0]
+        k_pad = next_bucket(len(held), 8)
+        key = ("merge", agg.key(), self._held_form, k_pad,
+               tuple((v.dtype.str, valid is not None)
+                     for v, valid in outs0),
+               self.partition_spec)
+        fn = cache_get(_SEG_KERNELS, key)
+        if fn is None:
+            from presto_tpu.kernelcache import timed_first_call
+
+            fn = timed_first_call(
+                self._compile_merge(out_meta, self._held_form[0]),
+                self.ctx.stats, _SEG_KERNELS)
+            cache_put(_SEG_KERNELS, key, fn)
+            self.ctx.stats.jit_compiles += 1
+        empty = (outs0, np.zeros((), count0.dtype))
+        args = tuple((outs, count) for outs, count, _p in held) \
+            + (empty,) * (k_pad - len(held))
+        self.ctx.stats.jit_dispatches += 1
+        with activity("dispatch"):
+            return fn(args)
+
+    def _compile_merge(self, out_meta, doms):
+        agg = self.agg_spec
+        k = len(agg.group_channels)
+        key_types = [typ for typ, _d in out_meta[:k]]
+        merge_prims = [MERGE_PRIM[a.prim] for a in agg.aggs]
+        out_dtypes = [a.out_type.np_dtype for a in agg.aggs]
+        partition = self.partition_spec
+
+        def kernel(held):
+            from presto_tpu.ops.groupby import merge_pre_reduced
+
+            key_outs, agg_outs, count = merge_pre_reduced(
+                held, key_types, doms, merge_prims, out_dtypes)
+            # a count state is always valid, as the batch's program left it
+            agg_outs = [(v, None if a.prim == "count" else valid)
+                        for a, (v, valid) in zip(agg.aggs, agg_outs)]
+            outs = tuple(key_outs) + tuple(agg_outs)
+            parts = (None if partition is None
+                     else _partition_ids(outs, out_meta, partition))
+            return outs, count, parts
+
+        return kernelcache.jit(kernel, "fused_segment_merge")
 
     def _observe_reduction(self, rows_in: int, groups_out: int) -> None:
         """Runtime half of the cost-based pre-reduce decision: when a
@@ -988,6 +1165,9 @@ class FusedSegmentOperator(Operator):
         agg = self.agg_spec
         max_domain = self._max_domain
         raw_emit = self._raw_emit
+        # filled while the program traces: the key domains where the
+        # pre-reduce took the direct path (its partial is bounded then)
+        direct: dict = {}
         if agg is not None:
             # partial schema: [key columns..., one state col per agg]
             key_meta = [out_meta[g] for g in agg.group_channels]
@@ -1172,6 +1352,8 @@ class FusedSegmentOperator(Operator):
                     # trace time: the sort fallback runs at the batch
                     # capacity, so per-batch groups can never overflow
                     use_direct = bounded and 0 < total <= max_domain
+                    if use_direct:
+                        direct["doms"] = tuple(doms)
                     key_outs, agg_outs, count = segment_pre_reduce(
                         keys, agg_ins, out_dtypes, num_rows, mask,
                         doms if use_direct else None, cap)
@@ -1188,25 +1370,13 @@ class FusedSegmentOperator(Operator):
             else:
                 outs = cur
                 count = num_rows
-            parts = None
-            if partition is not None:
-                from presto_tpu.ops.hashing import (
-                    partition_of, row_hash, value_hash_triple,
-                )
-
-                channels, nparts = partition
-                triples = []
-                for ch in channels:
-                    v, valid = outs[ch]
-                    typ, d = final_meta[ch]
-                    triples.append(value_hash_triple(
-                        _ColView(v, valid, typ, d)))
-                parts = partition_of(row_hash(triples), nparts)
+            parts = (None if partition is None
+                     else _partition_ids(outs, final_meta, partition))
             return outs, count, parts, tuple(etotals)
 
         name = _SEGMENT_PROGRAM[bool(self._probe_idx),
                                 agg is not None and not raw_emit]
-        return kernelcache.jit(kernel, name), list(final_meta)
+        return kernelcache.jit(kernel, name), list(final_meta), direct
 
 
 class FusedSegmentOperatorFactory(OperatorFactory):

@@ -47,6 +47,7 @@ PROGRAM_NAMES = (
     "fused_segment_probe",  #   named by what it absorbed: join probes,
     "fused_segment_agg",    #   a partial aggregation,
     "fused_segment_probe_agg",  # or both
+    "fused_segment_merge",  #   a task's held partials merged at finish
     "filter_project",       # exec/operators.py
     "dynamic_filter",       # exec/dynamicfilter.py
     "join_build_index",     # exec/joinop.py: sorted build-side key index

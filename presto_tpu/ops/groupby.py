@@ -403,7 +403,16 @@ def segment_pre_reduce(
         present, results = direct_grouped_aggregate(
             key_codes, doms, aggs, num_rows, live_mask=live_mask)
         domain = present.shape[0]
-        slots = jnp.nonzero(present, size=domain, fill_value=0)[0]
+        # the present slots first, ascending, then zeros: what
+        # jnp.nonzero(present, size=domain, fill_value=0) returns, in
+        # int32.  With 64-bit indices the chip's compiler spent 4-6 s on
+        # nonzero and 3 s on decode_direct_keys' emulated divisions, in
+        # every program that pre-reduces (compiled for a described v5e,
+        # PR 34)
+        rank = jnp.cumsum(present.astype(jnp.int32)) - 1
+        slots = jnp.zeros(domain, jnp.int32).at[
+            jnp.where(present, rank, domain)].set(
+                jnp.arange(domain, dtype=jnp.int32), mode="drop")
         num_groups = present.sum()
         decoded = decode_direct_keys(
             slots, [valid is not None for _v, valid, _t in key_columns],
@@ -431,6 +440,41 @@ def segment_pre_reduce(
         else:
             agg_outs.append((values.astype(dtype), cnt > 0))
     return key_outs, agg_outs, num_groups
+
+
+def merge_pre_reduced(held, key_types: Sequence[T.Type],
+                      doms: Sequence[int], merge_prims: Sequence[str],
+                      out_dtypes: Sequence):
+    """Merge partials that ``segment_pre_reduce``'s direct path emitted
+    for several batches into one partial of the same form, inside one
+    program (exec/fusion.py holds the partials on the device and calls
+    this once a task).
+
+    ``held``: per partial ``(cols, num_groups)``, ``cols`` the key pairs
+    then the state pairs as that path returned them, every array
+    ``domain`` rows long with the live groups first.  All partials come
+    from one key binding (same ``doms``, same nullability), so the key
+    codes mean the same in each.  A partial whose ``num_groups`` is 0
+    adds nothing: the caller pads the list with such to a bucketed
+    length.  ``merge_prims`` re-aggregate the states (count states sum).
+
+    Returns ``(key_outs, agg_outs, num_groups)`` as segment_pre_reduce
+    does: ``domain`` rows, the merged groups compacted to the front.
+    """
+    domain = held[0][0][0][0].shape[0]
+    live = jnp.concatenate([jnp.arange(domain) < n for _cols, n in held])
+    cols = [
+        (jnp.concatenate([c[ci][0] for c, _n in held]),
+         None if held[0][0][ci][1] is None
+         else jnp.concatenate([c[ci][1] for c, _n in held]))
+        for ci in range(len(held[0][0]))]
+    k = len(key_types)
+    keys = [(v, valid, t) for (v, valid), t in zip(cols[:k], key_types)]
+    aggs = [(prim, v, valid)
+            for prim, (v, valid) in zip(merge_prims, cols[k:])]
+    rows = live.shape[0]
+    return segment_pre_reduce(keys, aggs, out_dtypes, rows, live,
+                              list(doms), rows)
 
 
 def global_pre_reduce(

@@ -318,6 +318,8 @@ def _operator_entry(stats: Dict) -> Dict:
             "outputRows": stats.get("output_rows", 0),
             "jitDispatches": stats.get("jit_dispatches", 0),
             "kernelTier": stats.get("kernel_tier", ""),
+            # batches whose partial states a segment kept on the device
+            "prereduceHeld": stats.get("prereduce_batches_held", 0),
             # "hit" / "miss" on the scan of a cached table, else ""
             "scanCache": ("hit" if stats.get("scan_cache_hits")
                           else "miss" if stats.get("scan_cache_misses")

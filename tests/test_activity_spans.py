@@ -262,7 +262,7 @@ def test_task_spans_carry_operator_busy_time(served, name):
         for op in ops:
             assert set(op) == {"operator", "wallS", "inputRows",
                                "outputRows", "jitDispatches", "kernelTier",
-                               "scanCache"}
+                               "prereduceHeld", "scanCache"}
             assert op["wallS"] >= 0
         assert "jitCompileNs" not in task["attributes"]
     dispatched = sum(op["jitDispatches"]
@@ -270,6 +270,43 @@ def test_task_spans_carry_operator_busy_time(served, name):
                      for op in task["attributes"]["operators"])
     assert dispatched == \
         served[name]["detail"]["queryStats"]["jit_dispatches"]
+
+
+def test_q1_leaf_device_waits_do_not_grow_with_batches():
+    """Q1's leaf segment keeps each batch's partial states on the device
+    (exec/fusion.py), so a leaf task reads from the device when it hands
+    the merged partial to its sink and at no other time: the
+    ``device_wait`` brackets it records (the count, the partition ids,
+    the columns) are as many over 19 batches as over 3.  They were three
+    a batch."""
+    import dataclasses as dc
+
+    from presto_tpu.config import EngineConfig
+    from presto_tpu.server.dqr import DistributedQueryRunner
+
+    def leaf_tasks(scan_batch_rows):
+        cfg = dc.replace(EngineConfig(), scan_batch_rows=scan_batch_rows)
+        with DistributedQueryRunner.tpch(scale=0.05, n_workers=2,
+                                         config=cfg) as dqr:
+            client = dqr.new_client()
+            client.execute(QUERIES[1])
+            tree = _fetch(f"{dqr.coordinator.uri}/v1/query/"
+                          f"{client.last_query_id}/spans")
+        out = []
+        for task in _tasks(tree):
+            held = sum(op["prereduceHeld"]
+                       for op in task["attributes"]["operators"])
+            if held:
+                out.append((held, sum(
+                    c["attributes"]["count"] for c in task["children"]
+                    if c["name"] == "device_wait")))
+        return out
+
+    few, many = leaf_tasks(65536), leaf_tasks(8192)
+    assert len(few) == len(many) == 2
+    assert sum(held for held, _ in many) >= 5 * sum(h for h, _ in few)
+    assert [waits for _, waits in many] == [waits for _, waits in few]
+    assert all(0 < waits <= 3 for _, waits in many)
 
 
 def test_q3_span_tree_names_the_join_tier(served):

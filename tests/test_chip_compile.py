@@ -204,6 +204,30 @@ def test_segment_pre_reduce_direct_and_sorted(chip):
         chip.spec(SMALL, bool), chip.spec((), jnp.int64))
 
 
+def test_merge_of_held_partials(chip):
+    """The once-a-task merge of the partial states a segment held on the
+    device (exec/fusion.py, ops/groupby.merge_pre_reduced) at TPC-H Q1's
+    SF1 shape: 46 partials padded to 64, each the direct path's six-slot
+    domain of two dictionary keys, DOUBLE sums, a count state (BIGINT,
+    summed exactly) and a DOUBLE min."""
+    from presto_tpu.ops.groupby import merge_pre_reduced
+
+    f64, i64 = np.dtype("float64"), np.dtype("int64")
+    domain = 6
+    partial = (
+        ((chip.spec(domain, jnp.int32), None),
+         (chip.spec(domain, jnp.int32), None),
+         (chip.spec(domain, jnp.float64), chip.spec(domain, bool)),
+         (chip.spec(domain, jnp.int64), None),
+         (chip.spec(domain, jnp.float64), chip.spec(domain, bool))),
+        chip.spec((), jnp.int64))
+    chip.compile(
+        lambda held: merge_pre_reduced(
+            held, [T.VARCHAR, T.VARCHAR], [3, 2], ["sum", "sum", "min"],
+            [f64, i64, f64]),
+        (partial,) * 64)
+
+
 def test_hash_groupby_update(chip):
     """The device-resident GroupByHash accumulate: a 16K batch into a
     256K-slot table, BIGINT key, DOUBLE sum + count."""

@@ -297,6 +297,161 @@ def test_prereduce_sort_path_fallback():
     assert rows == _grouped(live, 0, aggs)
 
 
+# -- bounded pre-reduce: partial states held on the device ------------------
+
+HELD_BATCHES = 24
+
+
+def _held_chain(case):
+    """values (24 device batches) -> filter(x < 40) -> aggregation, and
+    the config that puts the fused segment in ``case``'s form: a
+    nullable dictionary key on the direct path, no key, the same key
+    with its domain over ``direct_groupby_max_domain`` (sort path), and
+    a key distinct in every row of a 2,048-row batch on the sort path
+    (flips to raw emission after the first batch)."""
+    from presto_tpu.batch import Batch, Column
+    from presto_tpu.exec.aggregation import (
+        AggChannel, GlobalAggregationOperatorFactory,
+        HashAggregationOperatorFactory,
+    )
+
+    raw = case == "raw-emit"
+    rows = 2048 if raw else 96
+    n_keys = rows if raw else 5
+    d = Dictionary([f"k{i}" for i in range(n_keys)])
+    rng = np.random.default_rng(7)
+    batches = []
+    for _ in range(HELD_BATCHES):
+        codes = (np.arange(rows) if raw
+                 else rng.integers(0, n_keys, rows)).astype(np.int32)
+        batches.append(Batch((
+            Column(T.VARCHAR, codes,
+                   None if raw else rng.random(rows) > 0.1, d),
+            # every row of the raw case passes the filter: a group a row
+            Column(T.BIGINT, rng.integers(-50, 40 if raw else 50, rows),
+                   None if raw else rng.random(rows) > 0.2),
+            Column(T.DOUBLE, rng.random(rows) * 100)), rows).to_device())
+    types = [batches[0].columns[0].type, T.BIGINT, T.DOUBLE]
+    fp = FilterProjectOperatorFactory(
+        B.comparison("<", B.ref(1, T.BIGINT), B.const(40, T.BIGINT)),
+        [B.ref(0, types[0]), B.ref(1, T.BIGINT), B.ref(2, T.DOUBLE)],
+        types)
+    aggs = [AggChannel("sum", 1, T.BIGINT),
+            AggChannel("count", 1, T.BIGINT),
+            AggChannel("count", None, T.BIGINT),
+            AggChannel("min", 2, T.DOUBLE),
+            AggChannel("max", 2, T.DOUBLE),
+            AggChannel("sum", 2, T.DOUBLE)]
+    if case == "global":
+        agg = GlobalAggregationOperatorFactory(aggs, types)
+    else:
+        agg = HashAggregationOperatorFactory([0], aggs, types)
+    cfg = _cfg(**({} if case in ("grouped-direct", "global")
+                  else {"direct_groupby_max_domain": 1}))
+    return [ValuesOperatorFactory(batches), fp, agg], cfg
+
+
+def _run_held(factories, cfg, fuse):
+    collector = OutputCollectorFactory()
+    chain = fuse_chain(list(factories), cfg) if fuse else list(factories)
+    task = execute_pipelines([Pipeline(chain + [collector], name="t")],
+                             cfg)
+    segment = [s for s in task.operator_stats
+               if "FusedSegment" in s.operator]
+    return collector.rows(), (segment[0] if fuse else None)
+
+
+@pytest.mark.parametrize("case, held, flush_bytes", [
+    ("grouped-direct", True, 1000), ("global", True, 150),
+    ("sort-path", False, 0), ("raw-emit", False, 0)])
+def test_bounded_prereduce_holds_partials_on_the_device(case, held,
+                                                        flush_bytes):
+    """A segment whose pre-reduce is bounded at trace time (direct
+    domain, or no key) keeps every dispatched batch's partial states on
+    the device and hands ONE batch to its consumer at finish; the sort
+    path and raw emission hand over a batch a dispatch, as ever.  The
+    answer is the unfused chain's either way, and also when a tiny
+    ``partial_agg_max_bytes`` forces the held partials out early."""
+    factories, cfg = _held_chain(case)
+    want, _ = _run_held(factories, cfg, fuse=False)
+    got, seg = _run_held(factories, cfg, fuse=True)
+    assert_rows_close(got, want)
+    assert len(want) == {"global": 1, "raw-emit": 2048}.get(case, 6)
+    if not held:
+        assert seg.output_batches == seg.jit_dispatches == HELD_BATCHES
+        assert (seg.prereduce_batches_held, seg.prereduce_flushes) == (0, 0)
+        # raw emission really engaged: one batch pre-reduced, no more
+        assert (seg.prereduce_rows == 2048) == (case == "raw-emit")
+        return
+    assert seg.output_batches == 1
+    assert (seg.prereduce_batches_held, seg.prereduce_flushes) == \
+        (HELD_BATCHES, 1)
+    # the grouped form adds one merge launch a flush, the global none
+    assert seg.jit_dispatches == HELD_BATCHES + (case != "global")
+    early, seg = _run_held(
+        factories, dc.replace(cfg, partial_agg_max_bytes=flush_bytes),
+        fuse=True)
+    assert_rows_close(early, want)
+    assert seg.prereduce_batches_held == HELD_BATCHES
+    assert 1 < seg.prereduce_flushes < HELD_BATCHES
+    assert seg.output_batches == seg.prereduce_flushes
+
+
+@pytest.mark.parametrize("name", ["q1", "q6"])
+def test_held_partials_through_a_coalescing_scan(want, name):
+    """Q1 (direct domain) and Q6 (global) over a scan cut into 30
+    batches: the segment that stages the scan holds all 30 and flushes
+    once, on the miss path and on the scan cache's hit path, with the
+    reference's answer."""
+    r = LocalQueryRunner.tpch(scale=0.01, config=_cfg(
+        scan_batch_rows=2048, task_concurrency=1))
+    for _pass in ("miss", "hit"):
+        jc = _run_statement(r, want, name)
+        ts = r._last_task.task_stats()
+        assert ts.prereduce_batches_held == 30, (_pass, ts)
+        assert ts.prereduce_flushes == 1
+        assert jc["dispatches"] == 30 + (name == "q1")
+    text = "\n".join(row[0] for row in r.execute(
+        "explain analyze " + tpch_reference.statement(name)).rows)
+    assert "prereduce held: 30 batches kept on the device, 1 flushes" \
+        in text
+
+
+def test_held_partials_of_two_key_bindings_do_not_mix():
+    """Partials merge only where their key codes mean the same: when a
+    batch arrives under a grown dictionary (another domain, another
+    program), what is held is merged out first and the answer stays the
+    unfused chain's."""
+    from presto_tpu.batch import Batch, Column
+    from presto_tpu.exec.aggregation import (
+        AggChannel, HashAggregationOperatorFactory,
+    )
+
+    small = Dictionary(["a", "b", "c"])
+    grown = Dictionary(["a", "b", "c", "d", "e"])
+    rng = np.random.default_rng(11)
+
+    def mk(d):
+        codes = rng.integers(0, len(d), 64).astype(np.int32)
+        return Batch((Column(T.VARCHAR, codes, None, d),
+                      Column(T.BIGINT, rng.integers(0, 9, 64))),
+                     64).to_device()
+
+    batches = [mk(small), mk(small), mk(small), mk(grown), mk(grown)]
+    types = [batches[0].columns[0].type, T.BIGINT]
+    fp = FilterProjectOperatorFactory(
+        None, [B.ref(0, types[0]), B.ref(1, T.BIGINT)], types)
+    agg = HashAggregationOperatorFactory(
+        [0], [AggChannel("sum", 1, T.BIGINT),
+              AggChannel("count", None, T.BIGINT)], types)
+    factories = [ValuesOperatorFactory(batches), fp, agg]
+    want, _ = _run_held(factories, _cfg(), fuse=False)
+    got, seg = _run_held(factories, _cfg(), fuse=True)
+    assert sorted(got) == sorted(want) and len(got) == 5
+    assert (seg.prereduce_batches_held, seg.prereduce_flushes,
+            seg.output_batches) == (5, 2, 2)
+
+
 def test_prereduce_global_empty_scan(runner_on):
     """Global pre-reduce over a scan whose filter kills every row: the
     per-batch partial row carries count=0, and the merge produces the
@@ -671,7 +826,9 @@ def test_cost_based_raw_emission_switch():
         [0], [AggChannel("sum", 1, T.DOUBLE),
               AggChannel("count", None, T.BIGINT)], types)
 
-    cfg = _cfg(direct_groupby_max_domain=1 << 14)
+    # on the sort path: a direct-domain pre-reduce is bounded, holds its
+    # partials on the device and so never reads the ratio back
+    cfg = _cfg(direct_groupby_max_domain=1 << 10)
     collector = OutputCollectorFactory()
     chain = fuse_chain(
         [ValuesOperatorFactory([mk(rows1).to_device(),
