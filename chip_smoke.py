@@ -16,7 +16,11 @@ scan of every leaf task is a hit of the device-resident scan cache
 (``scan_cache_hits`` / ``scan_cache_misses`` in ``queryStats``), and a
 leaf task of Q1 or Q6 must hold its pre-reduced partial states on the
 device and flush them at most twice (``prereduce_batches_held`` /
-``prereduce_flushes``).  A worker's scan batch must sit on a TPU device.
+``prereduce_flushes``).  In a warm Q3 the tasks of three stages (the
+``lineitem`` scan, the ``orders`` scan and the join) must end every
+dispatch of their segments without a compaction (``compactions_skipped``
+above 0, ``compactions`` 0); only ``customer``'s filter compacts.  A
+worker's scan batch must sit on a TPU device.
 
 ``--chips 4``: only the collective data plane (``mesh_device_exchange``,
 four co-resident workers on one 4-device mesh), Q1 and Q3 at SF1 against
@@ -327,6 +331,11 @@ def one_chip(xla: XlaCompiles) -> None:
                     stats["prereduce_batches_held"])
                 line[f"{temp}_prereduce_flushes"] = int(
                     stats["prereduce_flushes"])
+                # dispatches whose program compacted its rows at the end,
+                # and those that ended with a row mask and moved nothing
+                line[f"{temp}_compactions"] = [
+                    int(stats["compactions"]),
+                    int(stats["compactions_skipped"])]
                 line[f"{temp}_max_rel_err"] = compare(
                     f"{name} {temp}", rows, want[name])
                 line["rows"] = len(rows)
@@ -349,6 +358,26 @@ def one_chip(xla: XlaCompiles) -> None:
                     f"{name}: a leaf task hands its pre-reduced partials to "
                     f"the sink once, at most twice; the warm run's tasks "
                     f"read (held, flushes) {holding}")
+            # stage -> (compactions, skipped) of each of its tasks
+            moved = {stage: [(ts["compactions"], ts["compactions_skipped"])
+                             for ts in tasks]
+                     for stage, tasks in
+                     (detail.get("taskStats") or {}).items()}
+            skipping = [stage for stage, tasks in moved.items()
+                        if all(done == 0 and skipped > 0
+                               for done, skipped in tasks)]
+            compacting = [stage for stage, tasks in moved.items()
+                          if any(done for done, _skipped in tasks)]
+            # at SF1 both joins are partitioned and the second has a
+            # stage of its own; a rehearsal at a smaller scale broadcasts
+            # the builds and joins in lineitem's stage
+            if name == "q3" and (len(compacting) != 1 or len(skipping)
+                                 < (3 if SCALE >= 1.0 else 2)):
+                raise AssertionError(
+                    f"q3: only customer's filter compacts; the lineitem, "
+                    f"orders and join stages end their segments without "
+                    f"a compaction; the warm run's tasks read "
+                    f"(compactions, skipped) {moved}")
             hits, misses = line["warm_scan_cache"]
             if misses or not hits:
                 raise AssertionError(

@@ -147,6 +147,15 @@ class OperatorStats:
     # which emit a batch a dispatch
     prereduce_batches_held: int = 0
     prereduce_flushes: int = 0
+    # a fused segment's dispatches by how the program that ran ends
+    # (exec/fusion.py _compile, decided at trace time): it compacted its
+    # live rows to the front through ops/filter.py, or it ended with a
+    # row mask and moved nothing, because the live rows were a prefix
+    # already (an inner probe with no filter after it) or because the
+    # partitioned sink cuts them by id on the host.  Neither for a
+    # program with no mask at its end or one that pre-reduces
+    compactions: int = 0
+    compactions_skipped: int = 0
     # which kernel tier served this operator's group-by/join hot loop.
     # Group-by: "hash" (device-resident open-addressing,
     # ops/hashtable.py), "direct" (bounded-domain), "sort", "stream"
@@ -212,6 +221,8 @@ class TaskStats:
     prereduce_rows: int = 0
     prereduce_batches_held: int = 0
     prereduce_flushes: int = 0
+    compactions: int = 0
+    compactions_skipped: int = 0
     # the scan cache's account (OperatorStats.scan_cache_*), summed
     scan_cache_hits: int = 0
     scan_cache_misses: int = 0
@@ -299,6 +310,8 @@ class StageStats:
     prereduce_rows: int = 0
     prereduce_batches_held: int = 0
     prereduce_flushes: int = 0
+    compactions: int = 0
+    compactions_skipped: int = 0
     # the scan cache's account (OperatorStats.scan_cache_*), summed
     scan_cache_hits: int = 0
     scan_cache_misses: int = 0
@@ -358,10 +371,13 @@ def _add_host_and_xla(into, other) -> None:
 
 
 def _add_prereduce(into, other) -> None:
-    """The in-segment pre-reduce's account, summed one level up."""
+    """The fused segments' account (pre-reduce, end-of-segment
+    compaction), summed one level up."""
     into.prereduce_rows += other.prereduce_rows
     into.prereduce_batches_held += other.prereduce_batches_held
     into.prereduce_flushes += other.prereduce_flushes
+    into.compactions += other.compactions
+    into.compactions_skipped += other.compactions_skipped
 
 
 def _add_scan_cache(into, other) -> None:
@@ -392,6 +408,8 @@ class QueryStats:
     prereduce_rows: int = 0
     prereduce_batches_held: int = 0
     prereduce_flushes: int = 0
+    compactions: int = 0
+    compactions_skipped: int = 0
     # the scan cache's account (OperatorStats.scan_cache_*), summed
     scan_cache_hits: int = 0
     scan_cache_misses: int = 0
@@ -465,13 +483,17 @@ def scan_cache_line(stats: Dict) -> str:
             f"handed over), {stats.get('scan_cache_misses', 0)} misses")
 
 
-def prereduce_line(stats: Dict) -> str:
-    """EXPLAIN ANALYZE's line for the bounded pre-reduce's account of a
+def segment_line(stats: Dict) -> str:
+    """EXPLAIN ANALYZE's line for the fused segments' account of a
     TaskStats / QueryStats dict: partial states kept on the device, a
-    dispatched batch each, and their hand-overs to the consumer."""
+    dispatched batch each, and their hand-overs to the consumer; then
+    the dispatches that compacted their rows at the end and those that
+    had a row mask and moved nothing."""
     return (f"prereduce held: {stats.get('prereduce_batches_held', 0)} "
             f"batches kept on the device, "
-            f"{stats.get('prereduce_flushes', 0)} flushes")
+            f"{stats.get('prereduce_flushes', 0)} flushes; "
+            f"compactions: {stats.get('compactions', 0)} done, "
+            f"{stats.get('compactions_skipped', 0)} skipped")
 
 
 def kernel_tier_lines(ops) -> List[str]:

@@ -665,10 +665,10 @@ class FusedSegmentOperator(Operator):
                         batch = self._scan_fill.stage(batch)
                 if passthrough:
                     return self._emit(batch.compact())
-                return self._emit(self._dispatch(batch))
+                return self._dispatch(batch)
         elif self._pending is not None:
             batch, self._pending = self._pending, None
-            return self._emit(self._dispatch(batch))
+            return self._dispatch(batch)
         if self._finishing:
             if self._held:
                 return self._emit(self._flush_held())
@@ -691,12 +691,15 @@ class FusedSegmentOperator(Operator):
                 and not self.agg_spec.global_
                 and self._acc_rows < self._PASSTHROUGH_ROWS)
 
-    def _emit(self, out: Optional[Batch]) -> Optional[Batch]:
+    def _emit(self, out: Optional[Batch],
+              rows: Optional[int] = None) -> Optional[Batch]:
+        """Account for a batch that leaves: ``rows`` of it are live
+        where the program left dead rows among them for the sink."""
         if out is None:
             return None
         self._emitted_any = True
         self.ctx.stats.output_batches += 1
-        self.ctx.stats.output_rows += out.num_rows
+        self.ctx.stats.output_rows += out.num_rows if rows is None else rows
         return out
 
     def _needs_default_row(self) -> bool:
@@ -894,6 +897,8 @@ class FusedSegmentOperator(Operator):
         return next_bucket(max(capacity, 1))
 
     def _dispatch(self, batch: Batch) -> Optional[Batch]:
+        """One launch of the segment's program over ``batch``; what it
+        returns has been accounted for by ``_emit``."""
         snap = self._df_snapshot()
         if snap is None:
             return None      # empty build: nothing can survive the join
@@ -938,12 +943,18 @@ class FusedSegmentOperator(Operator):
                                           _SEG_KERNELS), *built_meta)
                 cache_put(_SEG_KERNELS, key, entry)
                 self.ctx.stats.jit_compiles += 1
-            fn, out_meta, direct = entry
+            fn, out_meta, traced = entry
             self.ctx.stats.jit_dispatches += 1
             with activity("dispatch"):
                 outs, count, parts, etotals = fn(
                     tuple(column_pairs(batch)), batch.num_rows, df_args,
                     probe_args)
+            # how the program that ran ends, as its trace recorded it
+            compaction = traced.get("compaction")
+            if compaction == "done":
+                self.ctx.stats.compactions += 1
+            elif compaction is not None:
+                self.ctx.stats.compactions_skipped += 1
             # expansion-overflow retry: bump the learned bucket for any
             # inner probe whose exact total exceeded its capacity and
             # re-dispatch (ops/join.py's host-retry policy, in-segment)
@@ -965,19 +976,23 @@ class FusedSegmentOperator(Operator):
         form = None
         if self.agg_spec is not None and not self._raw_emit:
             self.ctx.stats.prereduce_rows += batch.num_rows
-            form = self._bounded_form(out_meta, direct, outs)
+            form = self._bounded_form(out_meta, traced, outs)
         if self._held and form != self._held_form:
             merged = self._flush_held()
             if merged is not None:
                 self._ready.append(merged)
         if form is not None:
-            return self._hold(form, out_meta, outs, count, parts)
+            return self._emit(self._hold(form, out_meta, outs, count, parts))
         with activity("device_wait"):
             n = int(count)
         self._observe_reduction(batch.num_rows, n)
         if n == 0:
             return None
-        return self._partial_batch(out_meta, outs, parts, n)
+        # rows left for the sink to cut: every row of the program's
+        # output goes, and the partition ids say which n are live
+        rows = outs[0][0].shape[0] if compaction == "sink" else n
+        return self._emit(
+            self._partial_batch(out_meta, outs, parts, rows), n)
 
     @staticmethod
     def _partial_batch(out_meta, outs, parts, n: int) -> Batch:
@@ -988,21 +1003,21 @@ class FusedSegmentOperator(Operator):
         return Batch(cols, n)
 
     # -- held partials (bounded pre-reduce) ------------------------------
-    def _bounded_form(self, out_meta, direct, outs) -> Optional[tuple]:
+    def _bounded_form(self, out_meta, traced, outs) -> Optional[tuple]:
         """What a pre-reduced dispatch's partial shares with others it
         can be held and merged with, or None where its size is not
         bounded at trace time (the sort path) and it leaves as it comes.
         Bounded are the global form (one row) and the direct path (at
-        most ``direct_groupby_max_domain`` rows, ``direct["doms"]`` as
+        most ``direct_groupby_max_domain`` rows, ``traced["doms"]`` as
         the trace recorded them).  Two direct partials merge when their
         key codes mean the same: equal domains and nullability, equal
         dictionary content."""
         if self.agg_spec.global_:
             return ("global",)
-        if "doms" not in direct:
+        if "doms" not in traced:
             return None
         k = len(self.agg_spec.group_channels)
-        return (direct["doms"],
+        return (traced["doms"],
                 tuple(valid is not None for _v, valid in outs[:k]),
                 tuple(None if d is None else (d.content_key(), len(d))
                       for _t, d in out_meta[:k]))
@@ -1165,9 +1180,13 @@ class FusedSegmentOperator(Operator):
         agg = self.agg_spec
         max_domain = self._max_domain
         raw_emit = self._raw_emit
-        # filled while the program traces: the key domains where the
-        # pre-reduce took the direct path (its partial is bounded then)
-        direct: dict = {}
+        # filled while the program traces.  "doms": the key domains
+        # where the pre-reduce took the direct path (its partial is
+        # bounded then).  "compaction": how a program that ends with a
+        # row mask and emits rows left them: "done" (compacted through
+        # ops/filter.py), "prefix" or "sink" (not moved; the two cases
+        # above the branches that set them)
+        traced: dict = {}
         if agg is not None:
             # partial schema: [key columns..., one state col per agg]
             key_meta = [out_meta[g] for g in agg.group_channels]
@@ -1184,6 +1203,9 @@ class FusedSegmentOperator(Operator):
             from presto_tpu.ops.filter import selected_positions
 
             mask = None
+            # the mask is exactly an inner probe's row_valid, j < total:
+            # the expansion wrote its rows to the front, [0, num_rows)
+            prefix = False
             cur = tuple(cols)
             dfi = 0
             pri = 0
@@ -1195,6 +1217,7 @@ class FusedSegmentOperator(Operator):
                         fv, fvalid = cfilter.run(cur, num_rows, jnp)
                         m = fv if fvalid is None else fv & fvalid
                         mask = m if mask is None else mask & m
+                        prefix = False
                     cur = tuple(p.run(cur, num_rows, jnp) for p in cprojs)
                 elif prog[0] == "probe":
                     meta = prog[1]
@@ -1228,6 +1251,7 @@ class FusedSegmentOperator(Operator):
                     if mask is not None:
                         alive = alive & mask
                     jt = meta["join_type"]
+                    prefix = jt == "inner"
                     if jt == "semi":
                         mask = J.semi_mask(counts, live & alive,
                                            anti=False)
@@ -1278,7 +1302,9 @@ class FusedSegmentOperator(Operator):
                         if valid is not None:
                             m = m & valid
                         mask = m if mask is None else mask & m
+                        prefix = False
             cap = cur[0][0].shape[0]
+            live = None
             if agg is not None and raw_emit and not agg.global_:
                 # cost-based raw emission: the observed groups/rows
                 # ratio said grouping is not reducing — compact the
@@ -1288,7 +1314,7 @@ class FusedSegmentOperator(Operator):
                 m = (mask if mask is not None
                      else jnp.ones(cap, bool))
                 idx, count = selected_positions(m, None, num_rows, cap)
-                idx = idx.astype(jnp.int32)
+                traced["compaction"] = "done"
                 outs = []
                 for g in agg.group_channels:
                     v, valid = cur[g]
@@ -1353,12 +1379,30 @@ class FusedSegmentOperator(Operator):
                     # capacity, so per-batch groups can never overflow
                     use_direct = bounded and 0 < total <= max_domain
                     if use_direct:
-                        direct["doms"] = tuple(doms)
+                        traced["doms"] = tuple(doms)
                     key_outs, agg_outs, count = segment_pre_reduce(
                         keys, agg_ins, out_dtypes, num_rows, mask,
                         doms if use_direct else None, cap)
                     outs = tuple(key_outs) + tuple(agg_outs)
-            elif mask is not None:
+            elif mask is None:
+                outs = cur
+                count = num_rows
+            elif prefix:
+                # nothing masked a row since the last inner probe: the
+                # live rows are the first num_rows already
+                outs = cur
+                count = num_rows
+                traced["compaction"] = "prefix"
+            elif partition is not None:
+                # the consumer is the sink that cuts rows by partition
+                # id on the host (server/exchangeop.py): the rows stay
+                # where they are and the dead ones get the id one past
+                # the last partition, which no page takes
+                outs = cur
+                live = (jnp.arange(cap) < num_rows) & mask
+                count = live.sum()
+                traced["compaction"] = "sink"
+            else:
                 # ONE compaction for the whole segment: every stage's
                 # filter landed in the accumulated mask, so unselected
                 # rows were computed over (harmless, like padding rows)
@@ -1367,16 +1411,16 @@ class FusedSegmentOperator(Operator):
                 outs = tuple(
                     (v[idx], None if valid is None else valid[idx])
                     for v, valid in cur)
-            else:
-                outs = cur
-                count = num_rows
+                traced["compaction"] = "done"
             parts = (None if partition is None
                      else _partition_ids(outs, final_meta, partition))
+            if live is not None:
+                parts = jnp.where(live, parts, partition[1])
             return outs, count, parts, tuple(etotals)
 
         name = _SEGMENT_PROGRAM[bool(self._probe_idx),
                                 agg is not None and not raw_emit]
-        return kernelcache.jit(kernel, name), list(final_meta), direct
+        return kernelcache.jit(kernel, name), list(final_meta), traced
 
 
 class FusedSegmentOperatorFactory(OperatorFactory):

@@ -630,7 +630,6 @@ def _stream_probe(probe_pairs, build_pairs, sorted_ids, perm, mins,
         else:
             mask = J.semi_mask(counts, live, anti=False)
         idx, count = selected_positions(mask, None, num_rows, cap)
-        idx = idx.astype(jnp.int32)
         outs = tuple(
             (v[idx], None if valid is None else valid[idx])
             for v, valid in probe_pairs)
@@ -910,7 +909,6 @@ class LookupJoinOperator(Operator):
                         mask = J.semi_mask(counts, live, anti=False)
                 idx, count = selected_positions(mask, None, num_rows,
                                                 cap)
-                idx = idx.astype(jnp.int32)
                 outs = tuple(
                     (v[idx], None if valid is None else valid[idx])
                     for v, valid in probe_cols_pairs)

@@ -799,7 +799,7 @@ class LocalQueryRunner:
                 f"{s.prereduce_rows:>9}")
         from presto_tpu.exec.context import (
             host_and_xla_line, hot_operator_lines, kernel_tier_lines,
-            prereduce_line, scan_cache_line,
+            segment_line, scan_cache_line,
         )
 
         op_dicts = [dict(s.as_dict(), wall_ns=s.wall_ns + s.finish_wall_ns)
@@ -815,7 +815,7 @@ class LocalQueryRunner:
             f"prereduce rows: {jc['prereduce_rows']}")
         task_stats = task.task_stats().as_dict()
         lines.append(host_and_xla_line(task_stats))
-        lines.append(prereduce_line(task_stats))
+        lines.append(segment_line(task_stats))
         lines.append(scan_cache_line(task_stats))
         # queued-vs-execution split: same footer shape as the
         # distributed tier's _render_analyze (the single-process runner

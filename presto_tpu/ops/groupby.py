@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from presto_tpu import types as T
+from presto_tpu.ops.filter import selected_positions
 from presto_tpu.ops.keys import normalize_keys
 
 
@@ -403,17 +404,12 @@ def segment_pre_reduce(
         present, results = direct_grouped_aggregate(
             key_codes, doms, aggs, num_rows, live_mask=live_mask)
         domain = present.shape[0]
-        # the present slots first, ascending, then zeros: what
-        # jnp.nonzero(present, size=domain, fill_value=0) returns, in
-        # int32.  With 64-bit indices the chip's compiler spent 4-6 s on
-        # nonzero and 3 s on decode_direct_keys' emulated divisions, in
-        # every program that pre-reduces (compiled for a described v5e,
-        # PR 34)
-        rank = jnp.cumsum(present.astype(jnp.int32)) - 1
-        slots = jnp.zeros(domain, jnp.int32).at[
-            jnp.where(present, rank, domain)].set(
-                jnp.arange(domain, dtype=jnp.int32), mode="drop")
-        num_groups = present.sum()
+        # the present slots first, ascending, then zeros, in int32: with
+        # 64-bit indices the chip's compiler spent 3 s on
+        # decode_direct_keys' emulated divisions in every program that
+        # pre-reduces (compiled for a described v5e, PR 34)
+        slots, num_groups = selected_positions(present, None, domain,
+                                               domain)
         decoded = decode_direct_keys(
             slots, [valid is not None for _v, valid, _t in key_columns],
             doms)

@@ -2310,7 +2310,7 @@ class QueryExecution:
             host_and_xla_line as _host_and_xla_line,
             hot_operator_lines as _hot_operator_lines,
             kernel_tier_lines as _kernel_tier_lines,
-            prereduce_line as _prereduce_line,
+            segment_line as _segment_line,
             scan_cache_line as _scan_cache_line,
         )
         from presto_tpu.sql.plan import format_plan
@@ -2405,7 +2405,7 @@ class QueryExecution:
                 f"prereduce rows: {qs['prereduce_rows']}; "
                 f"trace token: {self.trace_token}")
             lines.append(_host_and_xla_line(qs))
-            lines.append(_prereduce_line(qs))
+            lines.append(_segment_line(qs))
             lines.append(_scan_cache_line(qs))
             lines.append(
                 f"serving: queued {qs.get('queued_s', 0.0):.3f} s, "

@@ -38,7 +38,9 @@ class PartitionedOutputOperator(Operator):
     When a fused upstream segment precomputed the partition ids
     (``precomputed``, exec/fusion.py), the ids arrive as an extra final
     int32 column and the per-batch hash dispatches are skipped — the
-    segment program already fused them.
+    segment program already fused them.  Such a segment may leave the
+    rows its filter dropped where they were, under the id ``n``: they
+    fall past the last partition's bound and into no page.
     """
 
     def __init__(self, ctx: OperatorContext, buffers: OutputBufferManager,
@@ -60,8 +62,8 @@ class PartitionedOutputOperator(Operator):
             # row accounting and serialization see the logical schema
             parts_col = batch.columns[-1]
             batch = Batch(batch.columns[:-1], batch.num_rows)
-        self.ctx.stats.input_rows += batch.num_rows
         if self.n == 1:
+            self.ctx.stats.input_rows += batch.num_rows
             self.buffers.enqueue(0, serialize_batch(batch))
             self.ctx.stats.output_rows += batch.num_rows
             return
@@ -87,8 +89,14 @@ class PartitionedOutputOperator(Operator):
         # one np.nonzero pass per partition: a single O(n log n) pass
         # regardless of fan-out, and rows stay in input order within a
         # partition (stable sort), exactly like the nonzero loop
+        # (16-bit keys: numpy's stable sort is then a radix sort)
+        if self.n < 1 << 16:
+            parts = parts.astype(np.uint16)
         order = np.argsort(parts, kind="stable")
         bounds = np.searchsorted(parts[order], np.arange(self.n + 1))
+        # the rows with a partition to go to: all of them, but for a
+        # precomputing segment's dead rows
+        self.ctx.stats.input_rows += int(bounds[self.n])
         for p in range(self.n):
             lo, hi = int(bounds[p]), int(bounds[p + 1])
             if lo == hi:

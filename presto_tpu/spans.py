@@ -320,6 +320,10 @@ def _operator_entry(stats: Dict) -> Dict:
             "kernelTier": stats.get("kernel_tier", ""),
             # batches whose partial states a segment kept on the device
             "prereduceHeld": stats.get("prereduce_batches_held", 0),
+            # a segment's dispatches that compacted their rows, and
+            # those that ended with a row mask and moved nothing
+            "compactions": stats.get("compactions", 0),
+            "compactionsSkipped": stats.get("compactions_skipped", 0),
             # "hit" / "miss" on the scan of a cached table, else ""
             "scanCache": ("hit" if stats.get("scan_cache_hits")
                           else "miss" if stats.get("scan_cache_misses")

@@ -262,7 +262,8 @@ def test_task_spans_carry_operator_busy_time(served, name):
         for op in ops:
             assert set(op) == {"operator", "wallS", "inputRows",
                                "outputRows", "jitDispatches", "kernelTier",
-                               "prereduceHeld", "scanCache"}
+                               "prereduceHeld", "compactions",
+                               "compactionsSkipped", "scanCache"}
             assert op["wallS"] >= 0
         assert "jitCompileNs" not in task["attributes"]
     dispatched = sum(op["jitDispatches"]

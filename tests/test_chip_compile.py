@@ -204,6 +204,45 @@ def test_segment_pre_reduce_direct_and_sorted(chip):
         chip.spec(SMALL, bool), chip.spec((), jnp.int64))
 
 
+@pytest.mark.parametrize("form", ["compacted", "left_for_the_sink"])
+def test_end_of_a_filter_segment(chip, form):
+    """The end of TPC-H Q3's lineitem segment at SF1 (exec/fusion.py
+    ``_compile``): one 64K batch of an int64 key, two DOUBLEs and a date
+    under a filter mask, then the partition ids.  Compacted through
+    ops/filter.py: one int32 scatter, no scatter-add (``jnp.nonzero``'s
+    was int64, ``fusion.7`` of PERF.md, PR 37).  Left for the sink that
+    cuts rows on the host: no scatter and no gather at all."""
+    from presto_tpu.ops.filter import selected_positions
+    from presto_tpu.ops.hashing import partition_of, row_hash
+
+    def kernel(okey, price, disc, ship, num_rows):
+        cols = (okey, price, disc, ship)
+        mask = ship > 9204
+        if form == "compacted":
+            idx, count = selected_positions(mask, None, num_rows, ROWS)
+            cols = tuple(v[idx] for v in cols)
+        else:
+            live = (jnp.arange(ROWS) < num_rows) & mask
+            count = live.sum()
+        parts = partition_of(row_hash([(cols[0], None, T.BIGINT)]), 2)
+        if form != "compacted":
+            parts = jnp.where(live, parts, 2)
+        return cols, count, parts
+
+    text = chip.compile(
+        kernel, chip.spec(ROWS, jnp.int64), chip.spec(ROWS, jnp.float64),
+        chip.spec(ROWS, jnp.float64), chip.spec(ROWS, jnp.int32),
+        chip.spec((), jnp.int64)).as_text()
+    ops = [line.split('op_name="')[1].split('"')[0].rsplit("/", 1)[-1]
+           for line in text.splitlines()
+           if " fusion(" in line and 'op_name="' in line]
+    assert "scatter-add" not in ops
+    if form == "compacted":
+        assert "scatter" in ops and "gather" in ops
+    else:
+        assert not {"scatter", "gather"} & set(ops), sorted(set(ops))
+
+
 def test_merge_of_held_partials(chip):
     """The once-a-task merge of the partial states a segment held on the
     device (exec/fusion.py, ops/groupby.merge_pre_reduced) at TPC-H Q1's

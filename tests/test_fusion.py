@@ -581,6 +581,19 @@ def test_memory_interning_shares_table_dictionaries():
 # partition-id fusion (exchange sink)
 # ---------------------------------------------------------------------------
 
+def _pages(buffers, n):
+    """Every partition's serialized pages, as the consumers fetch them."""
+    out = {}
+    for p in range(n):
+        out[p], token = [], 0
+        while True:
+            pages, token, done = buffers.get_pages(p, token, 1 << 30)
+            out[p].extend(bytes(pg) for pg in pages)
+            if done:
+                break
+    return out
+
+
 def test_precomputed_partition_matches_eager():
     """A segment feeding a hash-partitioned sink precomputes partition
     ids inside the fused program; the buffers must receive exactly the
@@ -609,20 +622,182 @@ def test_precomputed_partition_matches_eager():
         else:
             chain = chain + [sink]
         execute_pipelines([Pipeline(chain, name="t")], _cfg())
-        out = {}
-        for p in range(4):
-            rows = []
-            token = 0
-            while True:
-                pages, token, done = buffers.get_pages(p, token, 100)
-                for pg in pages:
-                    rows.extend(deserialize_batch(pg).to_pylist())
-                if done:
-                    break
-            out[p] = sorted(rows)
-        return out
+        return {p: sorted(r for pg in pages
+                          for r in deserialize_batch(pg).to_pylist())
+                for p, pages in _pages(buffers, 4).items()}
 
     assert run(True) == run(False)
+
+
+# ---------------------------------------------------------------------------
+# the compaction at the end of a segment's program: done through
+# ops/filter.py, or skipped where the live rows are a prefix already or
+# the partitioned sink cuts them on the host
+# ---------------------------------------------------------------------------
+
+def _segment_stats(task):
+    return [s for s in task.operator_stats
+            if s.operator.endswith("FusedSegmentOperator")]
+
+
+_SINK_BATCHES = {
+    # (key, value) rows; the filter keeps value < 1000
+    "mixed": [(i * 7919 % 1013, i if i % 3 else 5000 + i)
+              for i in range(700)],
+    "all_dead": [(i, 2000 + i) for i in range(300)],
+    # one key, so one partition takes every live row
+    "one_partition": [(42, i if i % 2 else 9000) for i in range(500)],
+}
+
+
+@pytest.mark.parametrize("n_partitions", [2, 4])
+@pytest.mark.parametrize("kind", sorted(_SINK_BATCHES))
+def test_partitioned_sink_cuts_an_uncompacted_segment(kind, n_partitions):
+    """A filter segment into the partitioned sink leaves its rows where
+    they are and gives the dead ones the id one past the last partition:
+    the pages are, byte for byte, those of the same rows compacted by
+    the filter operator and hashed by the sink."""
+    from presto_tpu.server.buffers import OutputBufferManager
+    from presto_tpu.server.exchangeop import PartitionedOutputOperatorFactory
+
+    rows = _SINK_BATCHES[kind]
+    batch = batch_from_pylist([T.BIGINT, T.BIGINT], rows)
+    live = [r for r in rows if r[1] < 1000]
+    fp = FilterProjectOperatorFactory(
+        B.comparison("<", B.ref(1, T.BIGINT), B.const(1000, T.BIGINT)),
+        [B.ref(0, T.BIGINT), B.ref(1, T.BIGINT)], [T.BIGINT, T.BIGINT])
+
+    def run(fuse: bool):
+        buffers = OutputBufferManager(n_partitions)
+        sink = PartitionedOutputOperatorFactory(buffers, [0], n_partitions)
+        chain = [ValuesOperatorFactory([batch.to_device()]), fp, sink]
+        if fuse:
+            chain = fuse_chain(chain, _cfg())
+            assert isinstance(chain[1], FusedSegmentOperatorFactory)
+            assert sink.precomputed is True
+        task = execute_pipelines([Pipeline(chain, name="t")], _cfg())
+        return _pages(buffers, n_partitions), task
+
+    fused, task = run(True)
+    eager, _task = run(False)
+    assert fused == eager
+    assert sum(len(v) for v in fused.values()) == (
+        0 if kind == "all_dead" else 1 if kind == "one_partition"
+        else n_partitions)
+    (seg,) = _segment_stats(task)
+    assert (seg.compactions, seg.compactions_skipped) == (0, 1)
+    assert seg.output_rows == len(live)
+    (sink_stats,) = [s for s in task.operator_stats
+                     if s.operator.endswith("PartitionedOutputOperator")]
+    assert (sink_stats.input_rows, sink_stats.output_rows) == \
+        ((len(live), len(live)) if live else (0, 0))
+
+
+@pytest.mark.parametrize("consumer", ["operator", "task_output"])
+def test_segment_into_anything_else_still_compacts(consumer):
+    """What decides is the consumer the lowering saw: a segment that
+    feeds an operator or an un-partitioned sink hands over its live rows
+    at the front, through the helper."""
+    from presto_tpu.serde import deserialize_batch
+    from presto_tpu.server.buffers import OutputBufferManager
+    from presto_tpu.server.exchangeop import TaskOutputOperatorFactory
+
+    batch, fps = _three_stage_chain()
+    collector = OutputCollectorFactory()
+    buffers = OutputBufferManager(1)
+    tail = (collector if consumer == "operator"
+            else TaskOutputOperatorFactory(buffers))
+    chain = fuse_chain([ValuesOperatorFactory([batch.to_device()])] + fps
+                       + [tail], _cfg())
+    assert chain[1].partition_spec is None
+    task = execute_pipelines([Pipeline(chain, name="t")], _cfg())
+    (seg,) = _segment_stats(task)
+    assert (seg.compactions, seg.compactions_skipped) == (1, 0)
+    assert seg.output_rows == 1
+    if consumer == "operator":
+        got = collector.rows()
+    else:
+        got = [r for pg in _pages(buffers, 1)[0]
+               for r in deserialize_batch(pg).to_pylist()]
+    assert got == [(33, 30)]
+
+
+def _probe_chain(filter_after: bool):
+    """(build pipeline, probe chain, probe batch): 40 probe rows, a
+    filter before the probe, a build side with a duplicate key and keys
+    the probe never asks for; optionally a filter after the probe."""
+    from presto_tpu.exec.joinop import (
+        HashBuildOperatorFactory, LookupJoinOperatorFactory,
+    )
+
+    build_rows = [(k, 100 + k) for k in range(0, 30, 2)] + [(4, 999)]
+    build = HashBuildOperatorFactory([0], [T.BIGINT, T.BIGINT])
+    build_pipeline = Pipeline(
+        [ValuesOperatorFactory([batch_from_pylist(
+            [T.BIGINT, T.BIGINT], build_rows).to_device()]), build],
+        name="build")
+    probe = batch_from_pylist([T.BIGINT, T.BIGINT],
+                              [(i % 20, i) for i in range(40)])
+    t2 = [T.BIGINT, T.BIGINT]
+    chain = [
+        FilterProjectOperatorFactory(
+            B.comparison(">", B.ref(1, T.BIGINT), B.const(3, T.BIGINT)),
+            [B.ref(0, T.BIGINT), B.ref(1, T.BIGINT)], t2),
+        LookupJoinOperatorFactory(build, [0], t2, "inner"),
+    ]
+    if filter_after:
+        t4 = t2 + t2
+        chain.append(FilterProjectOperatorFactory(
+            B.comparison("<", B.ref(3, T.BIGINT), B.const(900, T.BIGINT)),
+            [B.ref(i, T.BIGINT) for i in range(4)], t4))
+    return build_pipeline, chain, probe
+
+
+@pytest.mark.parametrize("filter_after,want_counts",
+                         [(False, (0, 1)), (True, (1, 0))],
+                         ids=["probe_last", "filter_after_probe"])
+def test_probe_segment_compacts_only_after_a_later_mask(filter_after,
+                                                        want_counts):
+    """An inner probe writes its rows to the front: with nothing masking
+    them afterwards the program ends there (a skip); a filter after the
+    probe brings the compaction back.  Either way the rows are the
+    unfused operators'."""
+    results = {}
+    for fused in (False, True):
+        build_pipeline, chain, probe = _probe_chain(filter_after)
+        collector = OutputCollectorFactory()
+        chain = [ValuesOperatorFactory([probe.to_device()])] + chain
+        if fused:
+            chain = fuse_chain(chain, _cfg())
+            assert [type(f).__name__ for f in chain] == [
+                "ValuesOperatorFactory", "FusedSegmentOperatorFactory"]
+        task = execute_pipelines(
+            [build_pipeline, Pipeline(chain + [collector], name="probe")],
+            _cfg())
+        results[fused] = collector.rows()
+    assert results[True] == results[False]      # same rows, same order
+    keep = {k: [100 + k] + ([999] if k == 4 else [])
+            for k in range(0, 30, 2)}
+    assert sorted(results[True]) == sorted(
+        (i % 20, i, i % 20, b) for i in range(4, 40)
+        for b in keep.get(i % 20, [])
+        if not (filter_after and b >= 900))
+    (seg,) = _segment_stats(task)
+    assert (seg.compactions, seg.compactions_skipped) == want_counts
+    assert seg.output_rows == len(results[True])
+
+
+def test_explain_analyze_reports_compactions(runner_on):
+    """Q3 on one node: the lineitem segment ends in its probe of the
+    orders build and the orders segment in its probe of customer's (two
+    skips a batch pair); customer's filter feeds the build operator and
+    compacts."""
+    text = "\n".join(r[0] for r in runner_on.execute(
+        "explain analyze " + QUERIES[3]).rows)
+    ts = runner_on._last_task.task_stats()
+    assert ts.compactions >= 1 and ts.compactions_skipped >= 2
+    assert (f"compactions: {ts.compactions} done, "
+            f"{ts.compactions_skipped} skipped") in text
 
 
 # ---------------------------------------------------------------------------

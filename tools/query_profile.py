@@ -91,7 +91,7 @@ def profile_live(args) -> int:
     import threading
     import time
 
-    from presto_tpu.exec.context import prereduce_line
+    from presto_tpu.exec.context import segment_line
     from presto_tpu.server.dqr import DistributedQueryRunner
     from presto_tpu.spans import render_span_tree, validate_span_tree
 
@@ -142,7 +142,7 @@ def profile_live(args) -> int:
               f"({qs.get('jit_compile_ns', 0) / 1e6:.1f} ms compile)  "
               f"retries: {q.stage_retry_rounds} stage / "
               f"{q.recovery_rounds} leaf")
-        print(prereduce_line(qs))
+        print(segment_line(qs))
         print()
         for line in stage_table(q.stage_stats):
             print(line)
