@@ -1,9 +1,13 @@
 """Helpers for the readers of host activity: what a query's tasks were
 doing on the host, from the ``activity`` children of the task spans in its
 span tree (``run['spans']``; recorded by ``presto_tpu/spans.py``, kinds in
-``spans.ACTIVITY_KINDS``).  A tree from a program that records none (an
-older commit, the collective plane) has no such child, and every function
-here then finds nothing to read."""
+``spans.ACTIVITY_KINDS``).  A query of the collective plane has no task
+threads: its query thread records for the length of the ``execute`` phase
+(``lock_wait``, ``dispatch``, ``device_wait``), and the intervals hang under
+the root fragment's task span, so they are read here like any task's (the
+``mesh.*`` readers of ``mesh4w.repeat-q1``).  A tree from a program that
+records none (a commit before the recorder; a mesh query before PR 31) has
+no such child, and every function here then finds nothing to read."""
 
 from __future__ import annotations
 

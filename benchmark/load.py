@@ -14,6 +14,14 @@ import threading
 import time
 
 CLIENT_TIMEOUT_S = 900.0   # a statement's first execution compiles
+# The benchmark's clients do not sleep between polls.  The coordinator's GET
+# is a long poll (it waits up to 0.5 s for the rows), so it is the wait, as
+# in Presto's own StatementClientV1: a wall is then the time the server took
+# to within a round trip.  With the shipped client's default of 50 ms before
+# every GET, a query that ends while the client sleeps is seen when the
+# sleep ends, and walls stand on a grid (0.55-0.60 s, 1.10-1.15 s, ... are
+# dead bands; Q1 at SF1 ended in the first: PERF.md section 3).
+POLL_INTERVAL_S = 0.0
 
 
 def orders(traffic: dict, seed: int) -> list:
@@ -31,6 +39,14 @@ def orders(traffic: dict, seed: int) -> list:
     return out
 
 
+def bench_client(new_client, user: str):
+    """A client of the benchmark's own: the program's, polling at
+    ``POLL_INTERVAL_S``.  Every client the harness makes comes from here."""
+    client = new_client(user=user)
+    client.poll_interval_s = POLL_INTERVAL_S
+    return client
+
+
 def execute(client, name: str, sql: str, who: int) -> dict:
     """One operation: the sample that every metric and check reads."""
     op = {"statement": name, "client": who, "start": time.time(),
@@ -44,6 +60,8 @@ def execute(client, name: str, sql: str, who: int) -> dict:
     op["wall_s"] = time.perf_counter() - t0
     op["end"] = op["start"] + op["wall_s"]
     op["query_id"] = client.last_query_id
+    # the POST's answer and every GET's (the client keeps one entry each)
+    op["responses"] = len(client.stats_history)
     return op
 
 
@@ -54,7 +72,7 @@ def closed_loop(new_client, statements: dict, traffic: dict, seed: int,
     samples in order of completion)."""
     samples: list = []
     lock = threading.Lock()
-    clients = [new_client(user=f"bench-{i}")
+    clients = [bench_client(new_client, f"bench-{i}")
                for i in range(traffic["clients"])]
     start = time.time()
     deadline = time.perf_counter() + seconds

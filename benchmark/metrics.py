@@ -36,6 +36,17 @@ def percentile(values: list, q: float) -> float | None:
     return v[lo] + (v[hi] - v[lo]) * (pos - lo)
 
 
+def distribution(values: list) -> dict | None:
+    """How the values lie: their count, the least, the quartiles and the
+    greatest.  A log then shows a grid, a second mode or a window of seven
+    samples without a side script.  None for no values."""
+    if not values:
+        return None
+    return {"n": len(values), "min": min(values),
+            "q1": percentile(values, 25.0), "median": percentile(values, 50.0),
+            "q3": percentile(values, 75.0), "max": max(values)}
+
+
 def completed_per_hour(samples: list, window_start: float) -> float | None:
     """Statements completed per hour, over the time from the window's start
     to the last completion (all the work over all the time: the statements

@@ -119,7 +119,7 @@ def warm_up(cell: dict, runner, xla: observe.XlaCompiles) -> dict:
     (compiled, or loaded from the persistent cache: JAX raises the same
     event for both).  Returns {statement: {first_exec_s, warm_wall_s,
     executions, ops}}."""
-    client = runner.new_client(user="bench-warmup")
+    client = load.bench_client(runner.new_client, "bench-warmup")
     out = {}
     for name, sql in cell["statements"].items():
         ops, built = [], None
@@ -278,21 +278,27 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
         emit({"phase": "failed_operation", "statement": op["statement"],
               "query_id": op["query_id"], "why": op["why_failed"]})
     good = [op for op in samples if op["ok"]]
-    by_statement = {
-        name: [op["wall_s"] for op in good if op["statement"] == name]
-        for name in cell["statements"]}
+    max_rel_err = max((op.get("max_rel_err", 0.0)
+                       for op in warm_ops + samples), default=0.0)
+    by_statement = {name: [op for op in good if op["statement"] == name]
+                    for name in cell["statements"]}
+    walls = {n: [op["wall_s"] for op in ops]
+             for n, ops in by_statement.items()}
     emit({"phase": "window", "seconds": seconds,
-          "samples": {n: len(w) for n, w in by_statement.items()},
+          "samples": {n: len(w) for n, w in walls.items()},
           "median_wall_s": {n: statistics.median(w) if w else None
-                            for n, w in by_statement.items()},
+                            for n, w in walls.items()},
+          "wall_s": {n: metrics.distribution(w) for n, w in walls.items()},
+          "responses_per_query": {
+              n: metrics.distribution([op["responses"] for op in ops])
+              for n, ops in by_statement.items()},
           "all_statements_samples": len(good),
           "xla_builds_in_setup": len(setup_events),
           "xla_build_seconds_in_setup": sum(e[2] for e in setup_events),
           "persistent_cache_hits_in_setup": setup_hits,
           "xla_builds_in_window": len(window_events),
           "window_compiled": sorted({e[1] for e in window_events}),
-          "max_rel_err": max((op.get("max_rel_err", 0.0)
-                              for op in warm_ops + samples), default=None)})
+          "max_rel_err": max_rel_err})
 
     peak = observe.memory_peak_bytes(devices)
     run = {
@@ -331,6 +337,13 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
         device["window_s"] = reduced["window_s"]
         result["breakdown"] = {"device_ops": reduced["device_ops"],
                                "idle_gaps": reduced["idle_gaps"]}
+    # what ``correct`` compared, each number beside its limit (the key comes
+    # last in the line; main() repeats it on standard error)
+    result["compared"] = {
+        "failed_operations": {"value": len(failed), "limit": 0,
+                              "why": [op["why_failed"] for op in failed[:3]]},
+        "max_rel_err": {"value": max_rel_err,
+                        "limit": config["guarantees"]["double_rtol"]}}
     return result
 
 
@@ -381,6 +394,10 @@ def main(argv=None) -> int:
                       keep_trace=args.keep_trace,
                       process_start=PROCESS_START)
     emit(result)
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']} (limit {c['limit']})"
+              + "".join(f"\n  {why}" for why in c.get("why", ())),
+              file=sys.stderr)
     return 0
 
 

@@ -3,7 +3,10 @@
 # set another seed), then one traced run: what a benchmark PR measures its
 # bounds from.  Run on the chip, all in one call:
 #   chiprun [--chips 4] --timeout 3000 -- bash benchmark/tests/measure_sets.sh <cell> <N> <seconds>
-# It stops at the first run that fails or is not correct.
+# It stops at the first run that fails or is not correct.  Its last lines are
+# benchmark/spread.py's: for each metric each set's median and spread (less
+# the run farthest from the median), the mean of the two spreads, which may be
+# at most half of the metric's bound, and the second median over the first.
 # Every run's output lands in $BENCH_OUT/sets/<cell>/set{A,B}/run<i>.out
 # (BENCH_OUT defaults to chiprun_out, which the chip tool brings back), the
 # traced run's in $BENCH_OUT/sets/<cell>/traced.out.
@@ -18,6 +21,7 @@ for set in A B; do
       > "$out/set$set/run$i.out" 2> "$out/set$set/run$i.err"
     rc=$?
     echo "set$set run$i seed=$seed rc=$rc $(tail -n 1 "$out/set$set/run$i.out" | cut -c1-400)"
+    grep -h '"phase": "window"' "$out/set$set/run$i.out" | cut -c1-900
     if [ "$rc" != 0 ] || ! tail -n 1 "$out/set$set/run$i.out" | grep -q '"correct": true'; then
       # a cell that does not run, or answers wrongly, is not worth more chip time
       tail -n 20 "$out/set$set/run$i.out" "$out/set$set/run$i.err" | cut -c1-600
@@ -32,3 +36,4 @@ echo "traced rc=$? $(tail -n 1 "$out/traced.out" | cut -c1-3000)"
 rm -f "$out"/trace/*.xplane.pb   # the raw trace is too large to bring back
 grep -h '"phase": "\(warmup\|window\|trace\)"' "$out/traced.out" | cut -c1-700
 tail -n 3 "$out/traced.err"
+python3 benchmark/spread.py "$out/setA" "$out/setB"

@@ -174,8 +174,10 @@ def test_join_cell_traced_reads_the_new_metrics():
     for name in SPAN_METRICS:
         assert name in got and got[name]["value"] >= 0.0, name
         assert got[name]["unit"] == "s"
-    for name in ("scan.generate_s", "ops.dispatch_s", "exchange.serde_s",
-                 "exchange.wait_s", "xla.trace_lower_s.setup"):
+    # scan.generate_s is not among them: since tables stay on the device after
+    # their first scan (PR 32) only set-up generates, and a window query reads 0
+    for name in ("ops.dispatch_s", "exchange.serde_s", "exchange.wait_s",
+                 "xla.trace_lower_s.setup"):
         assert got[name]["value"] > 0.0, name
     # the CPU has no device plane: nothing to attribute idle time of
     assert "trace.idle_attributed" not in got

@@ -37,6 +37,17 @@ def test_percentile_interpolates_and_counts():
     assert metrics.percentile([1.0, 2.0], 50.0) == pytest.approx(1.5)
 
 
+def test_distribution_is_count_extremes_and_quartiles():
+    got = metrics.distribution([5.0, 1.0, 3.0, 2.0, 4.0])
+    assert got == {"n": 5, "min": 1.0, "q1": 2.0, "median": 3.0, "q3": 4.0,
+                   "max": 5.0}
+    # a grid shows: two thirds of the samples on one value pull a quartile
+    # and the median onto it
+    grid = metrics.distribution([0.52, 0.54, 0.61, 0.61, 0.61, 0.61])
+    assert grid["median"] == grid["q3"] == 0.61 and grid["n"] == 6
+    assert metrics.distribution([]) is None
+
+
 def test_throughput_runs_to_the_last_completion():
     # 5 statements, the last one ends 26 s after the window's start
     assert metrics.completed_per_hour(SAMPLES, 0.0) == \
@@ -54,6 +65,43 @@ def test_orders_same_work_for_every_seed():
         assert sorted(order) == ["q1", "q3", "q6"]
     with pytest.raises(ValueError):
         load.orders({**traffic, "loop": "open"}, 1)
+
+
+class StubClient:
+    """What ``load`` touches of a StatementClient, with the program's
+    default between polls."""
+
+    def __init__(self, user):
+        self.user, self.poll_interval_s = user, 0.05
+        self.last_query_id, self.stats_history = None, []
+        self.polled_at = []
+
+    def execute(self, sql, timeout_s):
+        self.polled_at.append(self.poll_interval_s)
+        self.last_query_id = f"{self.user}-{len(self.polled_at)}"
+        self.stats_history = [{"state": "QUEUED"}, {"state": "FINISHED"}]
+        return [{"name": "x"}], [[1]]
+
+
+def test_the_generators_clients_do_not_sleep_between_polls():
+    made = []
+
+    def new_client(user):
+        made.append(StubClient(user))
+        return made[-1]
+
+    traffic = {"statements": ["a", "b"], "clients": 3, "loop": "closed",
+               "order": "cycle"}
+    _start, samples = load.closed_loop(
+        new_client, {"a": "select 1", "b": "select 2"}, traffic, 7, 0.05)
+    assert load.POLL_INTERVAL_S == 0.0
+    assert [c.user for c in made] == ["bench-0", "bench-1", "bench-2"]
+    for c in made:        # set before its first statement, and left so
+        assert c.polled_at and set(c.polled_at) == {0.0}
+    assert {s["client"] for s in samples} == {0, 1, 2}
+    assert all(s["responses"] == 2 and s["rows"] == [(1,)] for s in samples)
+    # the warm-up's client comes from the same helper
+    assert load.bench_client(new_client, "bench-warmup").poll_interval_s == 0.0
 
 
 def test_compare_exact_keys_and_double_tolerance():

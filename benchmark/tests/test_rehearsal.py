@@ -2,6 +2,7 @@
 ``run.py`` calls, with the scale as an argument (the command itself refuses
 to run without a TPU)."""
 
+import json
 import os
 import subprocess
 import sys
@@ -57,9 +58,25 @@ def test_command_refuses_without_a_tpu():
     assert "needs a TPU" in out.stderr
 
 
-def test_join_cell_end_to_end():
+def test_join_cell_end_to_end(capsys):
     cell, result = rehearse("http2w.join", trace=False)
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()
+             if x.startswith("{")]
+    window = [x for x in lines if x.get("phase") == "window"][0]
+    walls = window["wall_s"]["q3"]
+    assert walls["n"] == window["samples"]["q3"] == result["attempted"]
+    assert (walls["min"] <= walls["q1"] <= walls["median"] <= walls["q3"]
+            <= walls["max"])
+    assert walls["median"] == pytest.approx(window["median_wall_s"]["q3"])
+    # a POST and at least one GET each; no sleep between them
+    assert window["responses_per_query"]["q3"]["min"] >= 2
     assert result["correct"] and result["failed"] == 0
+    # what was compared, beside its limit, comes last in the line
+    assert list(result)[-1] == "compared"
+    assert result["compared"]["failed_operations"] == {
+        "value": 0, "limit": 0, "why": []}
+    assert (0.0 <= result["compared"]["max_rel_err"]["value"]
+            <= result["compared"]["max_rel_err"]["limit"] == 1e-6)
     assert result["attempted"] >= 1
     assert set(result["metrics"]) == {m["name"] for m in cell["end_to_end"]}
     assert result["metrics"]["query_geomean_s"]["value"] > 0
@@ -91,8 +108,48 @@ def test_a_wrong_answer_is_a_failed_operation():
     _cell, result = rehearse("http2w.scan-agg", trace=False, seconds=1.5)
     assert not result["correct"]
     assert 0 < result["failed"] <= result["attempted"]
+    said = result["compared"]["failed_operations"]
+    assert said["value"] >= result["failed"] and said["limit"] == 0
+    assert "differs from the reference" in said["why"][0]
     # q6 has no good sample, so the geometric mean has nothing to stand on
     assert "query_geomean_s" not in result["metrics"]
+
+
+def test_an_answer_altered_where_it_is_produced_fails_the_run(monkeypatch):
+    """The rest of a run with the timed path broken underneath: every other
+    answer of the window's client has one DOUBLE moved by a part in 10,000
+    (a hundred times ``double_rtol``, what a lower precision would cost).
+    Those operations fail and the run is not correct; the warm-up's client
+    is left alone."""
+    from benchmark import load
+
+    made = load.bench_client
+
+    def altering(new_client, user):
+        client = made(new_client, user)
+        if user == "bench-warmup":
+            return client
+        execute, calls = client.execute, []
+
+        def altered(sql, **kw):
+            columns, data = execute(sql, **kw)
+            calls.append(1)
+            if len(calls) % 2 == 0:
+                col = [i for i, v in enumerate(data[0])
+                       if isinstance(v, float)][0]
+                data[0][col] *= 1.0 + 1e-4
+            return columns, data
+
+        client.execute = altered
+        return client
+
+    monkeypatch.setattr(load, "bench_client", altering)
+    _cell, result = rehearse("http2w.join", trace=False, seconds=4.0)
+    assert result["attempted"] >= 2
+    assert not result["correct"]
+    assert result["failed"] == result["attempted"] // 2
+    said = result["compared"]["failed_operations"]
+    assert said["value"] == result["failed"] and "rel 1.0" in said["why"][0]
 
 
 def test_mesh_cell_on_four_virtual_devices():
