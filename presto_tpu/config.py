@@ -335,27 +335,6 @@ class EngineConfig:
     # event bus (trace token, queued/execution split, top hot
     # operator).  0 disables.
     slow_query_log_threshold_s: float = 60.0
-    # --- device-resident hash tier (ops/hashtable.py, SURVEY §3.4 "hot
-    # five" / §7 step 5): HashAggregationOperator accumulates unbounded-key
-    # aggregations into an open-addressing table resident on the device
-    # across batches; the thresholds below say when (exec/README.md).
-    # first table capacity (slots, power of two); the rehash ladder
-    # doubles from here while fill exceeds 1/2
-    hash_groupby_init_slots: int = 1 << 13
-    # rows below which an aggregation stays on the materialize+sort
-    # tier: per-batch claim-loop insertion has fixed round costs that
-    # only amortize on large many-batch inputs, while one sort of a
-    # small input is cheap.  The operator accumulates batches until the
-    # threshold crosses, then drains them into resident hash state and
-    # streams from there (memory stays bounded exactly where it
-    # matters).
-    hash_groupby_min_rows: int = 1 << 17
-    # rehash ceiling: above this many slots the operator stops growing
-    # the table, carries the accumulated on-device state over EXACTLY
-    # (merge-prim re-aggregation at finish) and falls back to the sort
-    # path for the remaining input — the "configured fraction of device
-    # memory" guard (4M slots ~ a few hundred MB of state at Q1 widths)
-    hash_groupby_max_slots: int = 1 << 22
     # Join lookup source (exec/joinop.py HashBuildOperator.finish):
     # integer keys whose live span fits the direct-address index take it;
     # unpackable (VARCHAR, wide multi-channel) keys always build the

@@ -238,217 +238,17 @@ class HashAggregationOperator(Operator):
         self._done = False
         self._spiller = None
         self._accumulated_bytes = 0
-        self._accumulated_rows = 0
-        # device-resident GroupByHash tier (ops/hashtable.py): state
-        # arrays live on device ACROSS batches; None until the first
-        # batch decides eligibility, False when ineligible
-        self._hash_decided = False
-        self._hash_state = None
-        self._hash_cap = 0
-        self._hash_groups = 0
-        self._hash_key_meta = None   # [(type, dictionary)] per key col
-        # partial-state batches carried over an overflow-to-sort
-        # fallback (merge-prim re-aggregated at finish, exactly once)
-        self._carried: List[Batch] = []
 
     def add_input(self, batch: Batch) -> None:
         self.ctx.stats.input_batches += 1
         self.ctx.stats.input_rows += batch.num_rows
-        if self._hash_state is not None:
-            if self._hash_accumulate(batch):
-                return
-            # table hit the rehash ceiling: state was extracted into
-            # self._carried; THIS batch falls through to the sort tier
-        elif (self._spiller is None and not self._hash_decided
-                and self._accumulated_rows + batch.num_rows
-                >= self.ctx.config.hash_groupby_min_rows):
-            # the engagement threshold crossed: small inputs never pay
-            # the claim-loop's fixed round costs (one sort at finish is
-            # cheaper), large ones drain what accumulated so far into
-            # resident state and stream from here with bounded memory
-            self._hash_decided = True
-            if self._hash_eligible(batch):
-                self._hash_begin(batch)
-                pending, self._batches = self._batches, []
-                self._accumulated_bytes = 0
-                self._accumulated_rows = 0
-                self.ctx.memory.free()
-                for b in pending + [batch]:
-                    if self._hash_state is None \
-                            or not self._hash_accumulate(b):
-                        self._append_sort_tier(b)
-                return
-        self._append_sort_tier(batch)
-
-    def _append_sort_tier(self, batch: Batch) -> None:
         self._batches.append(batch)
         self.ctx.memory.reserve(batch.size_bytes)
         self._accumulated_bytes += batch.size_bytes
-        self._accumulated_rows += batch.num_rows
         cfg = self.ctx.config
         if (cfg.spill_enabled and self.group_channels
                 and self._accumulated_bytes > cfg.spill_threshold_bytes):
             self._spill_accumulated()
-
-    # -- device-resident hash tier ---------------------------------------
-    def _hash_eligible(self, batch: Batch) -> bool:
-        """First-batch decision for the resident GroupByHash tier: device
-        prims only, no min/max over dictionary inputs (their resident
-        state would be interning codes), keys not already served by the
-        bounded-domain direct path (which is faster where it applies),
-        and grouping actually present."""
-        if not self.group_channels or _has_collect(self.aggs):
-            return False
-        for a in self.aggs:
-            if a.prim not in ("sum", "count", "min", "max"):
-                return False
-            if (a.prim in ("min", "max") and a.channel is not None
-                    and batch.columns[a.channel].dictionary is not None):
-                return False
-        if self._direct_domains(batch) is not None:
-            return False
-        return True
-
-    def _agg_acc_dtype(self, a: AggChannel, batch: Batch):
-        import numpy as np
-
-        if a.channel is None or a.prim == "count":
-            return None
-        return np.asarray(batch.columns[a.channel].values).dtype
-
-    def _hash_begin(self, batch: Batch) -> None:
-        from presto_tpu.ops.hashtable import groupby_init
-
-        cfg = self.ctx.config
-        cap = int(cfg.hash_groupby_init_slots)
-        key_cols = [batch.columns[c] for c in self.group_channels]
-        # every key column is declared nullable in the resident state:
-        # validity presence may differ batch-to-batch (an all-valid
-        # batch arrives with valid=None) and the table's word layout
-        # must stay fixed
-        import numpy as np
-
-        key_dtypes = [np.asarray(c.values).dtype for c in key_cols]
-        self._hash_key_meta = [(c.type, c.dictionary) for c in key_cols]
-        agg_specs = [(a.prim, self._agg_acc_dtype(a, batch))
-                     for a in self.aggs]
-        self._hash_state = groupby_init(
-            cap, 2 * len(key_cols), key_dtypes,
-            [True] * len(key_cols), agg_specs)
-        self._hash_cap = cap
-        self.ctx.stats.kernel_tier = "hash"
-
-    def _hash_inputs(self, batch: Batch):
-        import jax.numpy as jnp
-
-        key_cols = [(batch.columns[c].values, batch.columns[c].valid,
-                     batch.columns[c].type) for c in self.group_channels]
-        agg_ins = []
-        for a in self.aggs:
-            if a.channel is None:
-                agg_ins.append(("count", None, None))
-            else:
-                col = batch.columns[a.channel]
-                agg_ins.append((a.prim, col.values, col.valid))
-        return key_cols, agg_ins, jnp.asarray(batch.num_rows)
-
-    def _hash_accumulate(self, batch: Batch) -> bool:
-        """Fold one batch into resident state; returns False when the
-        rehash ladder hit its ceiling (state carried, caller falls back
-        to the sort tier for this and later batches)."""
-        from presto_tpu.ops.groupby import (
-            hash_groupby_rehash_jit, hash_groupby_update_jit,
-        )
-
-        cfg = self.ctx.config
-        max_slots = int(cfg.hash_groupby_max_slots)
-        batch = batch.to_device()
-        key_cols, agg_ins, n = self._hash_inputs(batch)
-        while True:
-            state2, ng, ok = hash_groupby_update_jit(
-                self._hash_state, key_cols, agg_ins, n)
-            self.ctx.stats.jit_dispatches += 1
-            with activity("device_wait"):
-                placed, groups = bool(ok), int(ng)
-            if placed:
-                self._hash_state = state2
-                self._hash_groups = groups
-                # proactive rehash past 1/2 fill keeps probe chains
-                # short for the NEXT batch (the rehash() trigger of
-                # MultiChannelGroupByHash.java:286)
-                if (self._hash_groups * 2 > self._hash_cap
-                        and self._hash_cap * 2 <= max_slots):
-                    self._hash_state, _ = hash_groupby_rehash_jit(
-                        self._hash_state, self._hash_cap * 2,
-                        [a.prim for a in self.aggs])
-                    self._hash_cap *= 2
-                    self.ctx.stats.jit_dispatches += 1
-                return True
-            # placement failed (table effectively full); nothing was
-            # accumulated, so rehash-and-retry is exactly-once
-            if self._hash_cap * 2 > max_slots:
-                self._hash_overflow_to_sort()
-                return False
-            self._hash_state, _ = hash_groupby_rehash_jit(
-                self._hash_state, self._hash_cap * 2,
-                [a.prim for a in self.aggs])
-            self._hash_cap *= 2
-            self.ctx.stats.jit_dispatches += 1
-
-    def _hash_overflow_to_sort(self) -> None:
-        """The overflow rung of the ladder: snapshot the accumulated
-        on-device state as a partial-state batch (keys + per-agg value
-        columns, valid iff the group saw a non-null input) and drop to
-        the sort tier.  The finish-time merge re-aggregates the carried
-        partials with merge prims, so no group is dropped or counted
-        twice however the input straddled the fallback seam."""
-        out = self._hash_extract_batch()
-        if out is not None and out.num_rows > 0:
-            self._carried.append(out)
-        self._hash_state = None
-        self._hash_cap = 0
-        self.ctx.stats.kernel_tier = "hash+sort"
-
-    def _hash_extract_batch(self) -> Optional[Batch]:
-        import numpy as np
-
-        from presto_tpu.ops.hashtable import groupby_extract
-
-        if self._hash_state is None:
-            return None
-        n, key_outs, agg_outs = groupby_extract(self._hash_state)
-        n = int(n)
-        if n == 0:
-            return None
-        cols = []
-        for (vals, valid), (typ, dictionary) in zip(key_outs,
-                                                    self._hash_key_meta):
-            cols.append(Column(typ, vals, valid, dictionary))
-        for a, (acc, cnt) in zip(self.aggs, agg_outs):
-            if a.prim == "count":
-                cols.append(Column(a.out_type, acc.astype("int64")))
-            else:
-                cols.append(Column(a.out_type,
-                                   acc.astype(a.out_type.np_dtype),
-                                   cnt > 0))
-        self.ctx.stats.jit_dispatches += 1
-        return Batch(tuple(cols), n)
-
-    def _merge_partials(self, parts: List[Batch]) -> Optional[Batch]:
-        """Merge-prim re-aggregation of partial-state batches (keys +
-        one state column per aggregation) — the Step.FINAL half of the
-        overflow seam.  Exact: each input row entered exactly one
-        partial."""
-        k = len(self.group_channels)
-        merge_aggs = [AggChannel(MERGE_PRIM[a.prim], k + i, a.out_type)
-                      for i, a in enumerate(self.aggs)]
-        types = ([self.input_types[c] for c in self.group_channels]
-                 + [a.out_type for a in self.aggs])
-        mctx = OperatorContext(self.ctx.task, f"{self.ctx.name}.merge")
-        sub = HashAggregationOperator(
-            mctx, list(range(k)), merge_aggs, types)
-        sub._hash_decided = True     # merge runs on the sort tier
-        return sub._compute_batches(parts)
 
     def _spill_accumulated(self) -> None:
         """Revoke: hash-partition accumulated rows to the spill tier
@@ -472,14 +272,7 @@ class HashAggregationOperator(Operator):
             return
         super().finish()
         outs: List[Batch] = []
-        if self._hash_state is not None:
-            # the steady state of the resident tier: groups come
-            # straight off the device table, no materialized input
-            out = self._hash_extract_batch()
-            if out is not None:
-                outs.append(out)
-            self._hash_state = None
-        elif self._spiller is not None:
+        if self._spiller is not None:
             self._spill_accumulated()
             for p in range(self.ctx.config.spill_partitions):
                 part = list(self._spiller.partition(p))
@@ -494,12 +287,6 @@ class HashAggregationOperator(Operator):
             out = self._compute_batches(self._batches)
             if out is not None:
                 outs.append(out)
-        if self._carried:
-            # overflow seam: merge the carried on-device state with the
-            # sort-tier results so every group lands exactly once
-            merged = self._merge_partials(self._carried + outs)
-            outs = [merged] if merged is not None else []
-            self._carried = []
         self._outputs.extend(outs)
         self._batches = []
         self.ctx.memory.free()
@@ -589,10 +376,9 @@ class HashAggregationOperator(Operator):
             return None  # grouped aggregation of zero rows -> zero rows
         doms = self._direct_domains(data)
         if doms is not None:
-            self.ctx.stats.kernel_tier = \
-                self.ctx.stats.kernel_tier or "direct"
+            self.ctx.stats.kernel_tier = "direct"
             return self._compute_direct(data, doms)
-        self.ctx.stats.kernel_tier = self.ctx.stats.kernel_tier or "sort"
+        self.ctx.stats.kernel_tier = "sort"
         key_cols = [(data.columns[c].values, data.columns[c].valid,
                      data.columns[c].type) for c in self.group_channels]
         agg_ins = []

@@ -157,9 +157,8 @@ class OperatorStats:
     compactions: int = 0
     compactions_skipped: int = 0
     # which kernel tier served this operator's group-by/join hot loop.
-    # Group-by: "hash" (device-resident open-addressing,
-    # ops/hashtable.py), "direct" (bounded-domain), "sort", "stream"
-    # (clustered), "hash+sort" (overflow seam crossed mid-query).
+    # Group-by: "direct" (bounded-domain) or "sort"; a streaming
+    # aggregation (clustered keys) reports none.
     # Join build and probe (absorbed or stand-alone): "dense"
     # (direct-address index, ops/join.py), "sorted", "hash"; a segment
     # that absorbed probes of two tiers reads "dense+hash".  Surfaced by

@@ -58,8 +58,6 @@ PROGRAM_NAMES = (
     "join_probe_residual",  #   probe with a residual filter fused in
     "groupby_sort",         # ops/groupby.py: lexsort + segment reduce
     "groupby_clustered",    #   clustered (streaming) keys
-    "groupby_hash",         #   open-addressing table update
-    "groupby_rehash",       #   rehash of a grown table
     "aggregate_global",     #   no keys
     "sort",                 # ops/sort.py
     "device_append",        # exec/operator.py: device_concat

@@ -22,22 +22,16 @@ Aggregation primitives are sum/count/min/max (planner decomposes
 avg/stddev/... into these, mirroring the partial/final Step split of
 HashAggregationOperator.Step:61).
 
-Three grouping tiers now coexist, chosen per operator/batch:
+Two grouping tiers coexist here, chosen per operator (a group-by whose
+keys arrive clustered streams through ``clustered_aggregate`` instead,
+exec/streamagg.py):
 
 - **direct** (``direct_grouped_aggregate``): bounded key domains
   (dictionary codes/booleans) — the BigintGroupByHash special-case role
   (GroupByHash.java:30-43); fastest where it applies.
-- **hash** (``hash_groupby_update_jit`` over ``ops/hashtable.py``): the
-  faithful ``MultiChannelGroupByHash`` role — open-addressing linear
-  probing with the 1-byte hash-prefix reject (PagesHash.java:49) and
-  capacity-doubling rehash (MultiChannelGroupByHash.java:273-286),
-  vectorized as a data-parallel claim loop.  Group state stays ON
-  DEVICE across batches, so nothing re-sorts and input batches are
-  never retained (unbounded keys past ``hash_groupby_min_rows``).
-- **sort** (``grouped_aggregate``): the exact, rehash-free fallback —
-  also the overflow target when the hash table would exceed
-  ``hash_groupby_max_slots`` (accumulated state carries over via
-  merge-prim re-aggregation, exec/aggregation.py).
+- **sort** (``grouped_aggregate``): every other key, exact and
+  rehash-free: one sort of what the operator accumulated
+  (exec/aggregation.py).
 """
 
 from __future__ import annotations
@@ -612,67 +606,6 @@ def clustered_aggregate_jit(key_columns, aggs, num_rows,
         tuple(v for _, v, _ in key_columns),
         tuple(v for v, _ in [(a[1], a[2]) for a in aggs]),
         tuple(v for _, v in [(a[1], a[2]) for a in aggs]), num_rows)
-
-
-def hash_groupby_update_jit(state, key_columns, aggs, num_rows,
-                            live_mask=None):
-    """ops.hashtable.groupby_update as one cached jitted program: the
-    per-batch accumulate of the device-resident GroupByHash tier (the
-    MultiChannelGroupByHash.putIfAbsent + GroupedAccumulator step,
-    MultiChannelGroupByHash.java:273).  State arrays ride as traced
-    arguments, so one compiled program serves every batch of the same
-    (batch capacity, table capacity) pair."""
-    key_types = tuple(t for _, _, t in key_columns)
-    kvalid = tuple(v is not None for _, v, _ in key_columns)
-    prims = tuple(p for p, _, _ in aggs)
-    avalid = tuple(v is not None for _, _, v in aggs)
-    aval_present = tuple(v is not None for _, v, _ in aggs)
-    cap_rows = key_columns[0][0].shape[0]
-    table_cap = state[2].shape[0]
-    key = ("hash_update", key_types, kvalid, prims, avalid,
-           aval_present, cap_rows, table_cap, live_mask is not None)
-
-    def build():
-        def kernel(st, kvals, kvalids, avals, avalids, n, lm):
-            from presto_tpu.ops.hashtable import groupby_update
-
-            kc = [(kvals[i], kvalids[i], key_types[i])
-                  for i in range(len(key_types))]
-            ag = [(prims[i], avals[i], avalids[i])
-                  for i in range(len(prims))]
-            return groupby_update(st, kc, ag, n, live_mask=lm)
-
-        return kernelcache.jit(kernel, "groupby_hash")
-
-    return _dispatch(
-        key, build, state,
-        tuple(v for v, _, _ in key_columns),
-        tuple(v for _, v, _ in key_columns),
-        tuple(v for _, v, _ in aggs),
-        tuple(v for _, _, v in aggs), num_rows, live_mask)
-
-
-def hash_groupby_rehash_jit(state, new_cap: int, prims=()):
-    """ops.hashtable.groupby_rehash as a cached jitted program (one per
-    (old capacity, new capacity, state layout) pair)."""
-    table_cap = state[2].shape[0]
-    n_words = len(state[0])
-    kspec = tuple((kv.dtype.name, kvalid is not None)
-                  for kv, kvalid in state[3])
-    aspec = tuple(acc.dtype.name for acc, _ in state[4])
-    prims = tuple(prims)
-    key = ("hash_rehash", table_cap, new_cap, n_words, kspec, aspec,
-           prims)
-
-    def build():
-        def kernel(st):
-            from presto_tpu.ops.hashtable import groupby_rehash
-
-            return groupby_rehash(st, new_cap, prims)
-
-        return kernelcache.jit(kernel, "groupby_rehash")
-
-    return _dispatch(key, build, state)
 
 
 def global_aggregate_jit(aggs, num_rows):

@@ -107,9 +107,6 @@ SESSION_PROPERTIES: Dict[str, Tuple[str, Callable[[str], Any]]] = {
     "result_cache_max_entry_bytes": ("result_cache_max_entry_bytes",
                                      int),
     "query_queue_timeout_s": ("query_queue_timeout_s", float),
-    "hash_groupby_init_slots": ("hash_groupby_init_slots", int),
-    "hash_groupby_max_slots": ("hash_groupby_max_slots", int),
-    "hash_groupby_min_rows": ("hash_groupby_min_rows", int),
     "device_join_probe_max_build_rows": (
         "device_join_probe_max_build_rows", int),
     "prereduce_max_group_fraction": (

@@ -267,21 +267,17 @@ def test_merge_of_held_partials(chip):
         (partial,) * 64)
 
 
-def test_hash_groupby_update(chip):
-    """The device-resident GroupByHash accumulate: a 16K batch into a
-    256K-slot table, BIGINT key, DOUBLE sum + count."""
-    from presto_tpu.ops.hashtable import groupby_init, groupby_update
+def test_grouped_aggregate_sort_tier(chip):
+    """The program that serves every unbounded GROUP BY (ops/groupby.py
+    ``grouped_aggregate``: radix sort, run boundaries, segment reduce):
+    a 16K batch, BIGINT key, DOUBLE sum + count, a group a row."""
+    from presto_tpu.ops.groupby import grouped_aggregate
 
-    cap = 1 << 18
-    state = jax.eval_shape(lambda: groupby_init(
-        cap, 1, [jnp.int64], [False],
-        [("sum", jnp.float64), ("count", None)]))
-    state = jax.tree.map(lambda s: chip.spec(s.shape, s.dtype), state)
     chip.compile(
-        lambda st, k, v, n: groupby_update(
-            st, [(k, None, T.BIGINT)],
-            [("sum", v, None), ("count", None, None)], n),
-        state, chip.spec(SMALL, jnp.int64), chip.spec(SMALL, jnp.float64),
+        lambda k, v, n: grouped_aggregate(
+            [(k, None, T.BIGINT)],
+            [("sum", v, None), ("count", v, None)], n, SMALL),
+        chip.spec(SMALL, jnp.int64), chip.spec(SMALL, jnp.float64),
         chip.spec((), jnp.int64))
 
 

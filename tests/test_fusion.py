@@ -865,8 +865,8 @@ def test_task_info_reports_kernel_caches():
 
 
 # ---------------------------------------------------------------------------
-# device-resident hash tier (PR 10): probe-in-segment, FINAL-merge
-# fusion, cost-based pre-reduce, and the overflow seam
+# probe-in-segment, FINAL-merge fusion, cost-based pre-reduce, and
+# which tier serves a group-by
 # ---------------------------------------------------------------------------
 
 def _plan_chains(runner, sql, cfg):
@@ -1024,70 +1024,40 @@ def test_cost_based_raw_emission_switch():
     assert task.jit_counters()["prereduce_rows"] == n
 
 
-def test_hash_groupby_overflow_seam_exact(runner_on):
-    """The unfused-fallback seam (satellite): a capacity bucket forced
-    to overflow mid-query carries the accumulated on-device state over
-    exactly — no double count, no dropped group — and the operator
-    reports the seam crossing."""
-    sql = ("select l_partkey, sum(l_extendedprice), count(*), "
-           "min(l_quantity), max(l_tax) from lineitem group by l_partkey")
-    want = runner_on.execute(sql).rows
-    r = LocalQueryRunner.tpch(scale=0.01, config=_cfg(
-        hash_groupby_init_slots=64, hash_groupby_max_slots=256,
-        hash_groupby_min_rows=0))
-    got = r.execute(sql).rows
-    assert_rows_close(got, want)
-    tiers = [s.kernel_tier for s in r._last_task.operator_stats
-             if s.kernel_tier]
-    assert "hash+sort" in tiers
-
-
 def _group_tiers(task):
     return [s.kernel_tier for s in task.operator_stats if s.kernel_tier]
 
 
-def _count_by(column):
+def _count_by(column, scale=0.01):
     """``select column, count(*) from lineitem group by column`` by a
     ``collections.Counter`` over the generated column."""
     values = tpch_reference.host_columns(
-        0.01, {"lineitem": [column]})[column]
+        scale, {"lineitem": [column]})[column]
     return sorted(collections.Counter(values.tolist()).items())
 
 
-def test_hash_groupby_tier_engages_on_unbounded_keys():
-    """An unbounded key past the row threshold accumulates in the
-    device-resident table from its first batch (threshold 0), and
-    answers as a plain count does."""
-    r = LocalQueryRunner.tpch(scale=0.01,
-                              config=_cfg(hash_groupby_min_rows=0))
-    res = r.execute(
-        "select l_partkey, count(*) from lineitem group by l_partkey")
-    assert _group_tiers(r._last_task) == ["hash"]
-    assert sorted(res.rows) == _count_by("l_partkey")
-
-
-# key column, what the config sets to reach the tier at 60 K rows, the
-# tier the aggregation reports, whether it streams
+# key column, the scale (lineitem has 60 K rows at 0.01), the tier the
+# aggregation reports, whether it streams
 GROUPBY_TIERS = {
     # dictionary codes: a bounded domain
-    "direct": ("l_shipmode", {}, "direct", False),
-    # unbounded, past hash_groupby_min_rows part of the way through
-    "hash": ("l_partkey", {"hash_groupby_min_rows": 1 << 14}, "hash",
-             False),
-    # unbounded, under the default threshold of 1 << 17 rows
-    "sort": ("l_partkey", {}, "sort", False),
+    "direct": ("l_shipmode", 0.01, "direct", False),
+    # unbounded, not clustered
+    "sort": ("l_partkey", 0.01, "sort", False),
+    # the same past 131,072 rows (180 K), fed a scan batch at a time:
+    # the rows seen change nothing
+    "sort-large": ("l_partkey", 0.03, "sort", False),
     # the scan's sort key: rows arrive clustered, no tier at all
-    "streaming": ("l_orderkey", {}, "", True),
+    "streaming": ("l_orderkey", 0.01, "", True),
 }
 
 
 @pytest.mark.parametrize("case", sorted(GROUPBY_TIERS))
 def test_groupby_tier_follows_the_input(case):
     """Both sides of every choice the aggregation makes alone: which
-    tier serves a GROUP BY follows the key's type, the rows seen and the
-    scan's order, and every tier counts as a Counter does."""
-    column, knobs, tier, streams = GROUPBY_TIERS[case]
-    r = LocalQueryRunner.tpch(scale=0.01, config=_cfg(**knobs))
+    tier serves a GROUP BY follows the key's type and the scan's order,
+    and every tier counts as a Counter does."""
+    column, scale, tier, streams = GROUPBY_TIERS[case]
+    r = LocalQueryRunner.tpch(scale=scale)
     res = r.execute(
         f"select {column}, count(*) from lineitem group by {column}")
     stats = r._last_task.operator_stats
@@ -1095,4 +1065,127 @@ def test_groupby_tier_follows_the_input(case):
                for s in stats) == streams
     tiers = set(_group_tiers(r._last_task))
     assert tiers == ({tier} if tier else set()), tiers
-    assert sorted(res.rows) == _count_by(column)
+    assert sorted(res.rows) == _count_by(column, scale)
+
+
+def test_group_by_over_a_spill_seam_exact(tmp_path, monkeypatch):
+    """An unbounded GROUP BY whose input passes the spill threshold part
+    of the way: rows accumulated before and after the first spill land
+    in the same groups exactly once (sum, count, min, max against
+    ``collections`` over the generated columns)."""
+    from presto_tpu.exec.aggregation import HashAggregationOperator
+
+    spills = []
+    spill = HashAggregationOperator._spill_accumulated
+    monkeypatch.setattr(
+        HashAggregationOperator, "_spill_accumulated",
+        lambda op: (spills.append(len(op._batches)), spill(op))[1])
+    r = LocalQueryRunner.tpch(scale=0.01, config=_cfg(
+        scan_batch_rows=8192, spill_threshold_bytes=1 << 20,
+        spill_partitions=4, spill_path=str(tmp_path)))
+    got = r.execute(
+        "select l_partkey, sum(l_extendedprice), count(*), "
+        "min(l_quantity), max(l_tax) from lineitem group by l_partkey").rows
+    assert set(_group_tiers(r._last_task)) == {"sort"}
+    # batches were held before the first spill and more came after it
+    assert len(spills) >= 2 and spills[0] > 1 and sum(spills[1:]) > 0
+    c = tpch_reference.host_columns(0.01, {"lineitem": [
+        "l_partkey", "l_extendedprice", "l_quantity", "l_tax"]})
+    want = {}
+    for k, price, qty, tax in zip(c["l_partkey"].tolist(),
+                                  c["l_extendedprice"].tolist(),
+                                  c["l_quantity"].tolist(),
+                                  c["l_tax"].tolist()):
+        s, n, lo, hi = want.get(k, (0.0, 0, np.inf, -np.inf))
+        want[k] = (s + price, n + 1, min(lo, qty), max(hi, tax))
+    assert_rows_close(got, [(k,) + v for k, v in want.items()])
+
+
+def _key_value_batches(keys, valid, values, batch_rows):
+    """Host batches of (BIGINT key, DOUBLE value, DOUBLE -value),
+    ``batch_rows`` each."""
+    from presto_tpu.batch import Batch, Column
+
+    return [Batch((Column(T.BIGINT, keys[lo:lo + batch_rows],
+                          None if valid is None
+                          else valid[lo:lo + batch_rows]),
+                   Column(T.DOUBLE, values[lo:lo + batch_rows]),
+                   Column(T.DOUBLE, -values[lo:lo + batch_rows])),
+                  min(batch_rows, len(keys) - lo))
+            for lo in range(0, len(keys), batch_rows)]
+
+
+def _sort_tier_cases():
+    rng = np.random.default_rng(7)
+    n = 8192
+    cases = {}
+    # 4,096 groups whose keys agree in their low 20 bits: nothing
+    # about a key's bits matters to a sort
+    cases["keys_collide_in_their_low_bits"] = (
+        rng.integers(0, 4096, n) << 20, None,
+        rng.uniform(-100, 100, n), n, {})
+    # three rows in ten have a NULL key: one group
+    cases["null_keys_form_one_group"] = (
+        rng.integers(0, 64, n), rng.random(n) > 0.3, np.ones(n), 1024, {})
+    # the input spills part of the way (the threshold is reached after
+    # a few batches); groups 1000.. are first seen after that, all
+    # values positive for the min and, negated, negative for the max:
+    # a cell that started at 0 would show
+    cases["minmax_identities_over_a_spill"] = (
+        np.concatenate([np.arange(n) % 400, 1000 + np.arange(n) % 400]),
+        None, np.arange(2 * n, dtype=np.float64) + 100.0, 1024,
+        {"spill_threshold_bytes": 64 << 10, "spill_partitions": 4})
+    # more than 131,072 rows of an unbounded key in 141 small batches
+    big = 141_000
+    cases["many_small_batches_past_131072_rows"] = (
+        rng.integers(0, 50_000, big), None, rng.uniform(0, 1, big), 1000,
+        {})
+    return cases
+
+
+SORT_TIER_CASES = _sort_tier_cases()
+
+
+@pytest.mark.parametrize("case", sorted(SORT_TIER_CASES))
+def test_sort_tier_serves_every_unbounded_group_by(case, tmp_path):
+    """Nothing about a key's bits, its NULLs, a spill part of the way or
+    the rows and batches seen changes the tier or the answer: sum,
+    count, min and max of the negated value a group, against
+    ``collections``; one tier, ``sort``."""
+    from presto_tpu.exec.aggregation import (
+        AggChannel, HashAggregationOperator,
+    )
+    from presto_tpu.exec.context import (
+        OperatorContext, QueryContext, TaskContext,
+    )
+
+    keys, valid, values, batch_rows, knobs = SORT_TIER_CASES[case]
+    cfg = _cfg(spill_path=str(tmp_path), **knobs)
+    ctx = OperatorContext(TaskContext(QueryContext(cfg)), "agg")
+    op = HashAggregationOperator(
+        ctx, [0],
+        [AggChannel("sum", 1, T.DOUBLE), AggChannel("count", None, T.BIGINT),
+         AggChannel("min", 1, T.DOUBLE), AggChannel("max", 2, T.DOUBLE)],
+        [T.BIGINT, T.DOUBLE, T.DOUBLE])
+    for batch in _key_value_batches(keys, valid, values, batch_rows):
+        op.add_input(batch)
+    spilled = op._spiller is not None
+    op.finish()
+    got = {}
+    while not op.is_finished():
+        out = op.get_output()
+        for k, s, c, lo, hi in out.to_pylist():
+            assert k not in got
+            got[k] = (s, c, lo, hi)
+    want = {}
+    for i, k in enumerate(keys.tolist()):
+        k = k if valid is None or valid[i] else None
+        v = float(values[i])
+        s, c, lo, hi = want.get(k, (0.0, 0, np.inf, -np.inf))
+        want[k] = (s + v, c + 1, min(lo, v), max(hi, -v))
+    assert set(got) == set(want)
+    for k, (s, c, lo, hi) in want.items():
+        assert got[k][0] == pytest.approx(s, rel=1e-9, abs=1e-7)
+        assert got[k][1:] == (c, lo, hi), k
+    assert ctx.stats.kernel_tier == "sort"
+    assert spilled == ("spill_threshold_bytes" in knobs)
