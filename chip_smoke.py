@@ -19,8 +19,11 @@ device and flush them at most twice (``prereduce_batches_held`` /
 ``prereduce_flushes``).  In a warm Q3 the tasks of three stages (the
 ``lineitem`` scan, the ``orders`` scan and the join) must end every
 dispatch of their segments without a compaction (``compactions_skipped``
-above 0, ``compactions`` 0); only ``customer``'s filter compacts.  A
-worker's scan batch must sit on a TPU device.
+above 0, ``compactions`` 0); only ``customer``'s filter compacts.  In a
+warm Q1 no final task may spend 0.08 s in ``HashAggregationOperator`` and
+``OrderByOperator`` together (``warm_final_stage_s``: each finish is one
+staging, one named program and one read; 0.28 s when they ran eagerly).
+A worker's scan batch must sit on a TPU device.
 
 ``--chips 4``: only the collective data plane (``mesh_device_exchange``,
 four co-resident workers on one 4-device mesh), Q1 and Q3 at SF1 against
@@ -291,6 +294,24 @@ def query_detail(runner, qid: str) -> dict:
         return json.loads(resp.read())
 
 
+FINISH_OPERATORS = ("HashAggregationOperator", "OrderByOperator")
+FINAL_STAGE_LIMIT_S = 0.08
+
+
+def final_stage_s(runner, qid: str) -> float:
+    """The longest a task spent in its GROUP BY's and ORDER BY's finish
+    (the operators' ``wallS`` in /v1/query/{id}/spans, added a task)."""
+    with urllib.request.urlopen(
+            f"{runner.coordinator.uri}/v1/query/{qid}/spans",
+            timeout=30) as resp:
+        tree = json.loads(resp.read())
+    return max(
+        sum(op["wallS"] for op in task["attributes"]["operators"]
+            if op["operator"].rsplit(".", 1)[-1] in FINISH_OPERATORS)
+        for stage in tree["children"] if stage["kind"] == "stage"
+        for task in stage["children"])
+
+
 def run_query(runner, client, xla: XlaCompiles, sql: str):
     before = len(xla.names)
     t0 = time.perf_counter()
@@ -342,7 +363,17 @@ def one_chip(xla: XlaCompiles) -> None:
                 if detail.get("resultCached"):
                     raise AssertionError(f"{name}: served from the result "
                                          "cache, not from the device")
+            if name == "q1":
+                line["warm_final_stage_s"] = final_stage_s(
+                    runner, client.last_query_id)
             emit(line)
+            if line.get("warm_final_stage_s", 0.0) >= FINAL_STAGE_LIMIT_S:
+                raise AssertionError(
+                    f"q1: a finish is one staging, one named program and one "
+                    f"read; a final task of the warm run spent "
+                    f"{line['warm_final_stage_s']:.3f} s in "
+                    f"{' + '.join(FINISH_OPERATORS)} (limit "
+                    f"{FINAL_STAGE_LIMIT_S} s; 0.28 s when they ran eagerly)")
             if line["warm_jit_compiles"] or compiled:
                 raise AssertionError(
                     f"{name}: the warm run compiled "

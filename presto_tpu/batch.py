@@ -51,6 +51,14 @@ def next_bucket(n: int, minimum: int = 1024) -> int:
     return 1 << (cap - 1).bit_length()
 
 
+def padded_table(table: np.ndarray) -> np.ndarray:
+    """A host lookup table (one entry a dictionary code) zero-padded to a
+    power-of-two length: the program that gathers through it is compiled
+    for that length, and a dictionary that grows must not make a program
+    a length."""
+    return np.pad(table, (0, next_bucket(len(table), 8) - len(table)))
+
+
 import itertools as _itertools
 
 # process-unique monotonic dictionary identities: kernel caches key
@@ -271,6 +279,17 @@ class Column:
         return Column(self.type, np.asarray(self.values), valid,
                       self.dictionary, kids)
 
+    def _arrays(self):
+        """The column's arrays and its children's, as one pytree."""
+        return (self.values, self.valid,
+                tuple(c._arrays() for c in self.children))
+
+    def _with_arrays(self, arrays) -> "Column":
+        values, valid, kids = arrays
+        return Column(self.type, values, valid, self.dictionary,
+                      tuple(c._with_arrays(k)
+                            for c, k in zip(self.children, kids)))
+
     def to_pylist(self, num_rows: int) -> List[Any]:
         col = self.to_numpy()
         vals = col.values[:num_rows]
@@ -372,30 +391,34 @@ class Batch:
         return self.head(self.num_rows)
 
     def to_numpy(self) -> "Batch":
-        # one device_wait for the batch, not one per column
-        if any(c.on_device() for c in self.columns):
-            with activity("device_wait"):
-                cols = tuple(c._to_numpy() for c in self.columns)
-        else:
-            cols = tuple(c._to_numpy() for c in self.columns)
-        return Batch(cols, self.num_rows)
+        if not any(c.on_device() for c in self.columns):
+            return Batch(tuple(c._to_numpy() for c in self.columns),
+                         self.num_rows)
+        import jax
+
+        # one read and one device_wait for the batch: device_get starts
+        # every array's copy before it waits for the first
+        with activity("device_wait"):
+            arrays = jax.device_get([c._arrays() for c in self.columns])
+        return Batch(tuple(c._with_arrays(a)
+                           for c, a in zip(self.columns, arrays)),
+                     self.num_rows)
 
     def to_device(self) -> "Batch":
         import jax
 
-        cols = []
         with activity("stage_h2d"):
-            for c in self.columns:
-                if c.children:
-                    # nested columns stay host-side (offsets bookkeeping);
-                    # device compute operates on their flattened children
-                    cols.append(c.to_numpy())
-                    continue
-                values = jax.device_put(c.values)
-                valid = (None if c.valid is None
-                         else jax.device_put(c.valid))
-                cols.append(Column(c.type, values, valid, c.dictionary))
-        return Batch(tuple(cols), self.num_rows)
+            # one put for the batch, not one an array; nested columns stay
+            # host-side (offsets bookkeeping): device compute operates on
+            # their flattened children
+            put = iter(jax.device_put([(c.values, c.valid)
+                                       for c in self.columns
+                                       if not c.children]))
+            cols = tuple(
+                c.to_numpy() if c.children
+                else Column(c.type, *next(put), c.dictionary)
+                for c in self.columns)
+        return Batch(cols, self.num_rows)
 
     # -- interop ---------------------------------------------------------
     def to_pylist(self) -> List[Tuple[Any, ...]]:

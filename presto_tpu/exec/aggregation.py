@@ -51,7 +51,9 @@ def _apply_post_projections(batch: Batch, stages) -> Batch:
         ExprCompiler, batch_pairs, result_column,
     )
 
-    batch = batch.compact().to_numpy()
+    # one read of the padded batch, cut on the host: compact() on device
+    # arrays is a slice whose shape is the row count, a program a count
+    batch = batch.to_numpy().compact()
     for projections in stages:
         compiler = ExprCompiler({i: c.dictionary
                                  for i, c in enumerate(batch.columns)
@@ -65,25 +67,39 @@ def _apply_post_projections(batch: Batch, stages) -> Batch:
     return batch
 
 
-def _minmax_dict_input(a: "AggChannel", col):
-    """min/max over a dictionary column reduce *lexicographic ranks* (codes
-    are interning order, not sort order); the returned postprocess maps the
-    winning rank back to a code and reattaches the dictionary."""
-    import jax.numpy as jnp
-    import numpy as np
+def _agg_inputs(aggs: Sequence[AggChannel], data: Batch):
+    """The finish programs' aggregation inputs (ops/groupby.py): per
+    aggregate ``(prim, values, valid, tables)`` over ``data``'s columns.
+    count(*) has no values; min/max over a dictionary column takes the
+    dictionary's rank tables, the program reduces ranks."""
+    from presto_tpu.ops.groupby import dictionary_rank_tables
 
-    if a.prim not in ("min", "max") or col.dictionary is None:
-        return col.values, None
-    ranks = col.dictionary.sort_ranks()          # code -> rank
-    order = np.argsort(ranks).astype(col.values.dtype)  # rank -> code
-    vals = jnp.asarray(ranks)[col.values]
-    dictionary = col.dictionary
+    ins = []
+    ranked = {}      # channel -> its tables: min and max share them
+    for a in aggs:
+        if a.channel is None:
+            ins.append(("count", None, None, None))
+            continue
+        col = data.columns[a.channel]
+        tables = None
+        if a.prim in ("min", "max") and col.dictionary is not None:
+            if a.channel not in ranked:
+                ranked[a.channel] = dictionary_rank_tables(col.dictionary)
+            tables = ranked[a.channel]
+        ins.append((a.prim, col.values, col.valid, tables))
+    return ins
 
-    def post(agg_ranks):
-        codes = jnp.asarray(order)[jnp.clip(agg_ranks, 0, len(order) - 1)]
-        return codes, dictionary
 
-    return vals, post
+def _agg_columns(aggs: Sequence[AggChannel], data: Batch, agg_outs):
+    """The finish programs' ``(values, valid)`` outputs as Columns; a
+    min/max over a dictionary column keeps its input's dictionary."""
+    cols = []
+    for a, (values, valid) in zip(aggs, agg_outs):
+        dictionary = None
+        if a.channel is not None and a.prim in ("min", "max"):
+            dictionary = data.columns[a.channel].dictionary
+        cols.append(Column(a.out_type, values, valid, dictionary))
+    return cols
 
 
 _HOST_PRIMS = ("collect", "collect_merge", "hll", "hll_merge",
@@ -293,7 +309,10 @@ class HashAggregationOperator(Operator):
 
     def _direct_domains(self, data: Batch) -> Optional[List[int]]:
         """Per-key domain sizes when every key column is bounded (dictionary
-        codes / booleans) and the packed domain is small; else None."""
+        codes / booleans) and the packed domain is small; else None.  A
+        domain is rounded up to a power of two: the finish program is
+        compiled for it, and a dictionary that grows must not make a
+        program a length."""
         doms = []
         for c in self.group_channels:
             col = data.columns[c]
@@ -308,61 +327,15 @@ class HashAggregationOperator(Operator):
             total *= d + (1 if data.columns[c].valid is not None else 0)
         if not doms or total > self.ctx.config.direct_groupby_max_domain:
             return None
-        return doms
-
-    def _compute_direct(self, data: Batch, doms: List[int]) -> Batch:
-        """Gather-free fast path (see ops.groupby.direct_grouped_aggregate)."""
-        import jax.numpy as jnp
-
-        from presto_tpu.ops.groupby import (
-            decode_direct_keys, direct_grouped_aggregate,
-        )
-
-        key_cols = [data.columns[c] for c in self.group_channels]
-        key_codes = [(c.values, c.valid) for c in key_cols]
-        agg_ins = []
-        posts = []
-        for a in self.aggs:
-            if a.channel is None:
-                agg_ins.append(("count", None, None))  # count(*): no values
-                posts.append(None)
-            else:
-                col = data.columns[a.channel]
-                vals, post = _minmax_dict_input(a, col)
-                agg_ins.append((a.prim, vals, col.valid))
-                posts.append(post)
-        n = jnp.asarray(data.num_rows)
-        present, results = direct_grouped_aggregate(
-            key_codes, doms, agg_ins, n)
-        domain = present.shape[0]
-        slots = jnp.nonzero(present, size=domain, fill_value=0)[0]
-        num_groups = int(present.sum())
-        decoded = decode_direct_keys(
-            slots, [c.valid is not None for c in key_cols], doms)
-        cols = []
-        for src, (codes, valid) in zip(key_cols, decoded):
-            cols.append(Column(src.type, codes.astype(src.values.dtype),
-                               valid, src.dictionary))
-        for a, post, (values, cnt) in zip(self.aggs, posts, results):
-            if a.prim == "count":
-                cols.append(Column(a.out_type, values[slots].astype("int64")))
-            else:
-                vals = values[slots]
-                if post is not None:
-                    vals, dictionary = post(vals)
-                else:
-                    dictionary = None
-                cols.append(Column(a.out_type,
-                                   vals.astype(a.out_type.np_dtype),
-                                   cnt[slots] > 0, dictionary))
-        self.ctx.stats.output_rows += num_groups
-        return Batch(tuple(cols), num_groups)
+        return [next_bucket(d, 1) for d in doms]
 
     def _compute_batches(self, batches: List[Batch]) -> Optional[Batch]:
-        import jax
-        import jax.numpy as jnp
+        """Stage the accumulated rows once, launch one named program
+        (``groupby_direct`` / ``groupby_sort``), read ``num_groups``: the
+        output columns stay on the device, padded."""
+        import numpy as np
 
-        from presto_tpu.ops.groupby import grouped_aggregate_jit
+        from presto_tpu.ops.groupby import grouped_finish_jit
 
         if _has_collect(self.aggs):
             out = host_aggregate(batches, self.group_channels, self.aggs,
@@ -375,55 +348,28 @@ class HashAggregationOperator(Operator):
         if data is None:
             return None  # grouped aggregation of zero rows -> zero rows
         doms = self._direct_domains(data)
-        if doms is not None:
-            self.ctx.stats.kernel_tier = "direct"
-            return self._compute_direct(data, doms)
-        self.ctx.stats.kernel_tier = "sort"
-        key_cols = [(data.columns[c].values, data.columns[c].valid,
-                     data.columns[c].type) for c in self.group_channels]
-        agg_ins = []
-        posts = []
-        for a in self.aggs:
-            if a.channel is None:
-                col = data.columns[0]
-                agg_ins.append(("count", jnp.zeros_like(
-                    col.values, shape=(data.capacity,)), None))
-                posts.append(None)
-            else:
-                col = data.columns[a.channel]
-                vals, post = _minmax_dict_input(a, col)
-                agg_ins.append((a.prim, vals, col.valid))
-                posts.append(post)
-        n = jnp.asarray(data.num_rows)
+        self.ctx.stats.kernel_tier = "sort" if doms is None else "direct"
+        key_cols = [data.columns[c] for c in self.group_channels]
+        keys = [(c.values, c.valid, c.type) for c in key_cols]
+        agg_ins = _agg_inputs(self.aggs, data)
+        out_dtypes = [a.out_type.np_dtype for a in self.aggs]
+        # the direct tier's output is its packed domain: it cannot overflow
         group_cap = next_bucket(1, min(max(data.num_rows, 1), 1 << 16))
         while True:
-            gi, ng, results = grouped_aggregate_jit(key_cols, agg_ins, n,
-                                                    group_cap)
+            self.ctx.stats.jit_dispatches += 1
+            key_outs, agg_outs, ng = grouped_finish_jit(
+                keys, agg_ins, out_dtypes, np.int32(data.num_rows), doms,
+                group_cap)
             with activity("device_wait"):
                 num_groups = int(ng)
-            if num_groups <= group_cap:
+            if doms is not None or num_groups <= group_cap:
                 break
             group_cap = next_bucket(num_groups)
-        cols = []
-        for c in self.group_channels:
-            src = data.columns[c]
-            values = src.values[gi]
-            valid = None if src.valid is None else src.valid[gi]
-            cols.append(Column(src.type, values, valid, src.dictionary))
-        for a, post, (values, cnt) in zip(self.aggs, posts, results):
-            if a.prim == "count":
-                cols.append(Column(a.out_type, values.astype("int64")))
-            else:
-                if post is not None:
-                    values, dictionary = post(values)
-                else:
-                    dictionary = None
-                cols.append(Column(a.out_type,
-                                   values.astype(a.out_type.np_dtype),
-                                   cnt > 0, dictionary))
-        out = Batch(tuple(cols), num_groups)
+        cols = [Column(c.type, values, valid, c.dictionary)
+                for c, (values, valid) in zip(key_cols, key_outs)]
+        cols += _agg_columns(self.aggs, data, agg_outs)
         self.ctx.stats.output_rows += num_groups
-        return out
+        return Batch(tuple(cols), num_groups)
 
     def get_output(self) -> Optional[Batch]:
         if not self._outputs:
@@ -477,10 +423,10 @@ class GlobalAggregationOperator(Operator):
         if self._finishing:
             return
         super().finish()
-        import jax.numpy as jnp
+        import jax
         import numpy as np
 
-        from presto_tpu.ops.groupby import global_aggregate_jit
+        from presto_tpu.ops.groupby import global_finish_jit
 
         if _has_collect(self.aggs):
             self._output = host_aggregate(self._batches, [], self.aggs,
@@ -506,33 +452,15 @@ class GlobalAggregationOperator(Operator):
                                        np.zeros(1, bool), dictionary))
             self._output = Batch(tuple(cols), 1)
             return
-        agg_ins = []
-        posts = []
-        for a in self.aggs:
-            if a.channel is None:
-                agg_ins.append(("count", data.columns[0].values, None))
-                posts.append(None)
-            else:
-                col = data.columns[a.channel]
-                vals, post = _minmax_dict_input(a, col)
-                agg_ins.append((a.prim, vals, col.valid))
-                posts.append(post)
-        results = global_aggregate_jit(agg_ins, jnp.asarray(data.num_rows))
-        for a, post, (value, cnt) in zip(self.aggs, posts, results):
-            if a.prim == "count":
-                cols.append(Column(a.out_type,
-                                   np.asarray([int(value)], np.int64)))
-            else:
-                nonempty = int(cnt) > 0
-                dictionary = None
-                if post is not None:
-                    value, dictionary = post(jnp.asarray([value]))
-                    value = np.asarray(value)[0]
-                cols.append(Column(
-                    a.out_type,
-                    np.asarray([value], a.out_type.np_dtype),
-                    None if nonempty else np.zeros(1, bool), dictionary))
-        self._output = Batch(tuple(cols), 1)
+        self.ctx.stats.jit_dispatches += 1
+        agg_outs = global_finish_jit(
+            _agg_inputs(self.aggs, data),
+            [a.out_type.np_dtype for a in self.aggs],
+            np.int32(data.num_rows))
+        with activity("device_wait"):   # the one read: a row
+            agg_outs = jax.device_get(agg_outs)
+        self._output = Batch(
+            tuple(_agg_columns(self.aggs, data, agg_outs)), 1)
 
     def get_output(self) -> Optional[Batch]:
         out, self._output = self._output, None

@@ -157,8 +157,6 @@ def _device_append(live: Sequence[Batch],
                    min_capacity: int) -> Optional[Batch]:
     """The device half of device_concat; None when an input needs the
     host path."""
-    import jax.numpy as jnp
-
     first = live[0]
     if not first.columns:
         return None
@@ -174,25 +172,23 @@ def _device_append(live: Sequence[Batch],
         return first
     has_valid = tuple(any(b.columns[ci].valid is not None for b in live)
                       for ci in range(len(first.columns)))
-    outs = tuple(
-        (jnp.zeros((out_cap,) + c.values.shape[1:], c.values.dtype),
-         jnp.zeros(out_cap, bool) if hv else None)
-        for c, hv in zip(first.columns, has_valid))
+    outs = None      # the first append makes the zeroed bucket itself
     offset = 0
     for b in live:
         ins = tuple(
             (c.values, None if not hv else c.valid if c.valid is not None
              else np.ones(b.capacity, bool))
             for c, hv in zip(b.columns, has_valid))
-        key = (out_cap, b.capacity, has_valid,
+        key = (out_cap, b.capacity, has_valid, outs is None,
                tuple((c.values.dtype.str, c.values.shape[1:])
                      for c in b.columns))
         program = cache_get(_APPEND_PROGRAMS, key)
         if program is None:
+            kernel = (_append_kernel if outs is not None
+                      else _first_append_kernel(out_cap))
             program = timed_first_call(
-                kernelcache.jit(_append_kernel, "device_append",
-                                donate_argnums=0), None,
-                _APPEND_PROGRAMS)
+                kernelcache.jit(kernel, "device_append", donate_argnums=0),
+                None, _APPEND_PROGRAMS)
             cache_put(_APPEND_PROGRAMS, key, program)
         with activity("dispatch"):
             outs = program(outs, ins, np.int32(offset),
@@ -229,6 +225,21 @@ def _append_kernel(outs, ins, offset, num_rows):
         return lax.dynamic_update_slice(out, merged, (start,) + tail)
 
     return jax.tree_util.tree_map(append, outs, ins)
+
+
+def _first_append_kernel(out_cap: int):
+    """``_append_kernel`` for the first input: ``out_cap`` zeroed rows of
+    every array are made inside the program (``jnp.zeros`` outside it is
+    a program and a launch an array)."""
+    import jax
+    import jax.numpy as jnp
+
+    def kernel(_no_outs, ins, offset, num_rows):
+        outs = jax.tree_util.tree_map(
+            lambda x: jnp.zeros((out_cap,) + x.shape[1:], x.dtype), ins)
+        return _append_kernel(outs, ins, offset, num_rows)
+
+    return kernel
 
 
 def column_pairs(batch: Batch) -> List[Tuple[object, object]]:

@@ -11,10 +11,10 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Optional, Sequence
 
-from presto_tpu import types as T
-from presto_tpu.batch import Batch, Column
+from presto_tpu.batch import Batch, Column, next_bucket, padded_table
 from presto_tpu.exec.context import OperatorContext
 from presto_tpu.exec.operator import Operator, OperatorFactory, device_concat
+from presto_tpu.spans import activity
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,12 +45,15 @@ class OrderByOperator(Operator):
         if self.ctx.should_spill(self._accumulated_bytes):
             self._spill_run()
 
-    def _sort_batches(self, batches: List[Batch]) -> Optional[Batch]:
-        """Device sort of the concatenated batches (one run)."""
-        import jax.numpy as jnp
+    def _sort_batches(self, batches: List[Batch],
+                      limit: Optional[int] = None) -> Optional[Batch]:
+        """Device sort of the concatenated batches (one run): staged
+        once, one named program (``order_by``), nothing read.  The
+        columns come back padded, cut inside the program to ``limit``'s
+        capacity bucket; the first ``num_rows`` are the answer."""
         import numpy as np
 
-        from presto_tpu.ops.sort import sort_permutation
+        from presto_tpu.ops.sort import sorted_columns
 
         data = device_concat(batches, self.ctx.config.min_batch_capacity)
         if data is None:
@@ -58,27 +61,30 @@ class OrderByOperator(Operator):
         keys = []
         for s in self.specs:
             c = data.columns[s.channel]
-            if c.type.is_dictionary:
-                # order by lexicographic rank, computed host-side over the
-                # dictionary (strings never sort on device)
-                ranks = c.dictionary.sort_ranks()
-                values = jnp.asarray(ranks)[c.values]
-                keys.append((values, c.valid, T.INTEGER, s.descending,
-                             s.nulls_first))
-            else:
-                keys.append((c.values, c.valid, c.type, s.descending,
-                             s.nulls_first))
-        perm = sort_permutation(keys, jnp.asarray(data.num_rows))
+            # a dictionary column orders by lexicographic rank, computed
+            # host-side over the dictionary (strings never sort on device)
+            ranks = (padded_table(c.dictionary.sort_ranks())
+                     if c.type.is_dictionary else None)
+            keys.append((s.channel, c.type, s.descending, s.nulls_first,
+                         ranks))
+        n = data.num_rows if limit is None else min(limit, data.num_rows)
+        out_capacity = data.capacity if limit is None else min(
+            data.capacity,
+            next_bucket(limit, self.ctx.config.min_batch_capacity))
+        self.ctx.stats.jit_dispatches += 1
+        outs, perm = sorted_columns(
+            keys, [None if c.children else (c.values, c.valid)
+                   for c in data.columns],
+            np.int32(data.num_rows), out_capacity)
         cols = []
-        for c in data.columns:
-            if c.children:       # nested columns gather host-side
-                cols.append(c.to_numpy().take(np.asarray(perm)))
+        for c, out in zip(data.columns, outs):
+            if out is None:      # nested columns gather host-side
+                with activity("device_wait"):
+                    perm = np.asarray(perm)
+                cols.append(c.to_numpy().take(perm))
             else:
-                cols.append(Column(
-                    c.type, c.values[perm],
-                    None if c.valid is None else c.valid[perm],
-                    c.dictionary))
-        return Batch(tuple(cols), data.num_rows)
+                cols.append(Column(c.type, *out, c.dictionary))
+        return Batch(tuple(cols), n)
 
     def _spill_run(self) -> None:
         """External sort: sort the accumulated chunk on device, spill it as
@@ -97,7 +103,7 @@ class OrderByOperator(Operator):
         spiller = FileSpiller(self.ctx.config.spill_path,
                               tag=f"sort-{self.ctx.name}")
         step = max(1, self.ctx.config.scan_batch_rows)
-        run = run.compact().to_numpy()
+        run = run.to_numpy().compact()
         for lo in range(0, run.num_rows, step):
             hi = min(lo + step, run.num_rows)
             spiller.spill(run.take(np.arange(lo, hi)))
@@ -108,14 +114,12 @@ class OrderByOperator(Operator):
             return
         super().finish()
         if not self._runs:
-            out = self._sort_batches(self._batches)
+            out = self._sort_batches(self._batches, self.limit)
             self._batches = []
             self.ctx.memory.free()
             if out is not None:
-                n = out.num_rows if self.limit is None else min(
-                    self.limit, out.num_rows)
-                self._outputs.append(out.head(n))
-                self.ctx.stats.output_rows += n
+                self._outputs.append(out)
+                self.ctx.stats.output_rows += out.num_rows
             return
         if self._batches:
             self._spill_run()

@@ -56,10 +56,12 @@ PROGRAM_NAMES = (
     "join_probe_count",     #   match total before an expanding probe
     "join_probe",           #   the streaming probe, every tier
     "join_probe_residual",  #   probe with a residual filter fused in
-    "groupby_sort",         # ops/groupby.py: lexsort + segment reduce
-    "groupby_clustered",    #   clustered (streaming) keys
-    "aggregate_global",     #   no keys
-    "sort",                 # ops/sort.py
+    "groupby_direct",       # ops/groupby.py: a GROUP BY's finish over
+    "groupby_sort",         #   bounded key domains; over any keys
+    "aggregate_global",     #   an ungrouped aggregation's finish
+    "groupby_clustered",    #   clustered (streaming) keys, a batch
+    "sort",                 # ops/sort.py: a sort's permutation
+    "order_by",             #   ORDER BY's finish: every column sorted
     "device_append",        # exec/operator.py: device_concat
     "mesh_step",            # parallel/steps.py
     "mesh_program",         # parallel/sqlmesh.py: the SPMD query program

@@ -41,10 +41,15 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from presto_tpu import kernelcache
 from presto_tpu import types as T
+from presto_tpu.batch import padded_table
+from presto_tpu.kernelcache import cache_get, cache_put, new_cache
 from presto_tpu.ops.filter import selected_positions
 from presto_tpu.ops.keys import normalize_keys
+from presto_tpu.spans import activity
 
 
 # One aggregation input: (prim, values, valid|None) with prim in
@@ -525,20 +530,33 @@ def global_aggregate(aggs: Sequence[AggIn], num_rows: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Jitted wrappers with a global program cache
+# An operator's finish: one cached program
 # ---------------------------------------------------------------------------
 # The kernels above are pure functions of traced arrays plus static
-# metadata (types, prims, capacities).  Callers in the operator layer run
-# once per finish; without jit every jnp op dispatches eagerly — dozens
-# of device dispatches per aggregation.  These wrappers jit the whole
-# kernel and share the compiled program across queries
-# (AccumulatorCompiler cache role).
-
-from presto_tpu import kernelcache
-from presto_tpu.kernelcache import cache_get, cache_put, new_cache
-from presto_tpu.spans import activity
+# metadata (types, prims, capacities).  An accumulating operator
+# (exec/aggregation.py, exec/streamagg.py) calls one of these wrappers
+# once it has its input: the whole finish, from the staged columns to the
+# output columns, is one named program shared across queries (the
+# AccumulatorCompiler cache role).  Nothing around the call is a jnp op:
+# run eagerly, each would be an XLA program and a launch of its own.
+#
+# One aggregation input here: ``(prim, values|None, valid|None, tables)``;
+# ``values`` None is count(*).  ``tables`` is None, or for min/max over
+# a dictionary column the host pair ``(ranks, order)`` of
+# ``dictionary_rank_tables``: codes are interning order, not sort order,
+# so the program reduces ranks and maps the winner back to a code.
 
 _AGG_PROGRAMS = new_cache("aggregation")
+
+_GROUPED_PROGRAM = {True: "groupby_direct", False: "groupby_sort"}
+
+
+def dictionary_rank_tables(dictionary):
+    """``(ranks, order)``: code -> lexicographic rank and rank -> code,
+    each a ``padded_table``."""
+    ranks = dictionary.sort_ranks()
+    order = np.argsort(ranks).astype(np.int32)
+    return padded_table(ranks), padded_table(order)
 
 
 def _dispatch(key, build, *args):
@@ -552,32 +570,78 @@ def _dispatch(key, build, *args):
         return fn(*args)
 
 
-def grouped_aggregate_jit(key_columns, aggs, num_rows,
+def _agg_signature(aggs, out_dtypes):
+    """What of the aggregation inputs a program is compiled for."""
+    return tuple(
+        (prim, values is not None, valid is not None,
+         None if tables is None else len(tables[0]), np.dtype(dtype).str)
+        for (prim, values, valid, tables), dtype in zip(aggs, out_dtypes))
+
+
+def _agg_arguments(aggs):
+    return (tuple(a[1] for a in aggs), tuple(a[2] for a in aggs),
+            tuple(a[3] for a in aggs))
+
+
+def _ranked(prims, avals, avalids, tables):
+    """The kernels' ``(prim, values, valid)`` triples, dictionary codes
+    looked up as ranks."""
+    return [(prim, values if table is None else table[0][values], valid)
+            for prim, values, valid, table
+            in zip(prims, avals, avalids, tables)]
+
+
+def _coded(agg_outs, tables):
+    """Winning ranks back to dictionary codes.  An empty group's rank is
+    the reduction's identity: clipped, and invalid anyway."""
+    return [(values, valid) if table is None else
+            (table[1][jnp.clip(values, 0, table[1].shape[0] - 1)], valid)
+            for (values, valid), table in zip(agg_outs, tables)]
+
+
+def grouped_finish_kernel(key_types, prims, out_dtypes,
+                          doms: Optional[Sequence[int]],
                           group_capacity: int):
-    """grouped_aggregate as one cached jitted program."""
+    """The traced body of ``grouped_finish_jit``: ``segment_pre_reduce``
+    over the accumulated rows, the direct tier when ``doms`` is given
+    and the sort tier at ``group_capacity`` otherwise, between the two
+    rank lookups."""
+    def kernel(kvals, kvalids, avals, avalids, tables, n):
+        key_outs, agg_outs, num_groups = segment_pre_reduce(
+            list(zip(kvals, kvalids, key_types)),
+            _ranked(prims, avals, avalids, tables), out_dtypes, n, None,
+            doms, group_capacity)
+        return key_outs, _coded(agg_outs, tables), num_groups
+
+    return kernel
+
+
+def grouped_finish_jit(key_columns, aggs, out_dtypes, num_rows,
+                       doms: Optional[Sequence[int]], group_capacity: int):
+    """A grouped aggregation's finish as one cached jitted program: what
+    comes back is every output column, the groups first, and
+    ``num_groups`` (``segment_pre_reduce``'s result).  On the sort tier
+    ``num_groups`` may exceed ``group_capacity``: the caller runs it
+    again at a larger one."""
     key_types = tuple(t for _, _, t in key_columns)
-    kvalid = tuple(v is not None for _, v, _ in key_columns)
-    prims = tuple(p for p, _, _ in aggs)
-    avalid = tuple(v is not None for _, _, v in aggs)
-    cap = key_columns[0][0].shape[0]
-    key = ("grouped", key_types, kvalid, prims, avalid, cap,
-           group_capacity)
+    prims = tuple(a[0] for a in aggs)
+    out_dtypes = tuple(out_dtypes)
+    doms = None if doms is None else tuple(doms)
+    key = ("grouped", key_types,
+           tuple(v is not None for _, v, _ in key_columns),
+           _agg_signature(aggs, out_dtypes), key_columns[0][0].shape[0],
+           doms, group_capacity)
 
     def build():
-        def kernel(kvals, kvalids, avals, avalids, n):
-            kc = [(kvals[i], kvalids[i], key_types[i])
-                  for i in range(len(key_types))]
-            ag = [(prims[i], avals[i], avalids[i])
-                  for i in range(len(prims))]
-            return grouped_aggregate(kc, ag, n, group_capacity)
-
-        return kernelcache.jit(kernel, "groupby_sort")
+        name = _GROUPED_PROGRAM[doms is not None]
+        return kernelcache.jit(
+            grouped_finish_kernel(key_types, prims, out_dtypes, doms,
+                                  group_capacity), name)
 
     return _dispatch(
         key, build, tuple(v for v, _, _ in key_columns),
-        tuple(v for _, v, _ in key_columns),
-        tuple(v for _, v, _ in aggs),
-        tuple(v for _, _, v in aggs), num_rows)
+        tuple(v for _, v, _ in key_columns), *_agg_arguments(aggs),
+        num_rows)
 
 
 def clustered_aggregate_jit(key_columns, aggs, num_rows,
@@ -608,21 +672,21 @@ def clustered_aggregate_jit(key_columns, aggs, num_rows,
         tuple(v for _, v in [(a[1], a[2]) for a in aggs]), num_rows)
 
 
-def global_aggregate_jit(aggs, num_rows):
-    """global_aggregate as one cached jitted program."""
-    prims = tuple(p for p, _, _ in aggs)
-    avalid = tuple(v is not None for _, _, v in aggs)
-    cap = aggs[0][1].shape[0] if aggs else 0
-    key = ("global", prims, avalid, cap)
+def global_finish_jit(aggs, out_dtypes, num_rows):
+    """An ungrouped aggregation's finish as one cached jitted program:
+    per aggregate the one-row output column ``(values [1], valid [1])``
+    of ``global_pre_reduce`` (a count's ``valid`` is None)."""
+    prims = tuple(a[0] for a in aggs)
+    out_dtypes = tuple(out_dtypes)
+    caps = tuple(None if a[1] is None else a[1].shape[0] for a in aggs)
+    key = ("global", _agg_signature(aggs, out_dtypes), caps)
 
     def build():
-        def kernel(avals, avalids, n):
-            ag = [(prims[i], avals[i], avalids[i])
-                  for i in range(len(prims))]
-            return global_aggregate(ag, n)
+        def kernel(avals, avalids, tables, n):
+            agg_outs = global_pre_reduce(
+                _ranked(prims, avals, avalids, tables), out_dtypes, n, None)
+            return _coded(agg_outs, tables)
 
         return kernelcache.jit(kernel, "aggregate_global")
 
-    return _dispatch(
-        key, build, tuple(v for _, v, _ in aggs),
-        tuple(v for _, _, v in aggs), num_rows)
+    return _dispatch(key, build, *_agg_arguments(aggs), num_rows)

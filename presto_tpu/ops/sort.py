@@ -66,11 +66,61 @@ def _sort_kernel(spec, radix: bool):
         keys = [(v, valid, typ, desc, nulls_first)
                 for v, valid, (typ, desc, nulls_first)
                 in zip(values, valids, spec)]
-        if radix:
-            from presto_tpu.ops.radix import radix_sort_permutation
+        return _permutation(keys, num_rows, radix)
 
-            return radix_sort_permutation(keys, num_rows)
-        return _lexsort_permutation(keys, num_rows)
+    return kernel
+
+
+def _permutation(keys: Sequence[SortKey], num_rows: jax.Array,
+                 radix: bool) -> jax.Array:
+    if radix:
+        from presto_tpu.ops.radix import radix_sort_permutation
+
+        return radix_sort_permutation(keys, num_rows)
+    return _lexsort_permutation(keys, num_rows)
+
+
+def sorted_columns(keys, columns, num_rows, out_capacity: int):
+    """ORDER BY's finish as ONE cached jitted program (``order_by``): the
+    permutation of ``sort_permutation`` and every column gathered
+    through its first ``out_capacity`` entries (a TopN's limit, rounded
+    up to a capacity bucket by the caller, gathers no more).
+
+    ``keys``: per sort key ``(channel, type, descending, nulls_first,
+    ranks)``; ``ranks`` is None, or for a dictionary column its host
+    code -> lexicographic rank table, which the program gathers through
+    (strings never sort on device; pad the table to a bucketed length).
+    ``columns``: per channel a ``(values, valid|None)`` pair, or None for
+    a column that stays behind.  Returns ``(columns, perm)``, ``perm``
+    int32, for what the caller gathers itself."""
+    from presto_tpu.ops.radix import use_radix
+
+    spec = tuple(key[:4] for key in keys)
+    radix = use_radix()
+    key = ("columns", spec, radix, out_capacity)
+    program = cache_get(_SORT_PROGRAMS, key)
+    if program is None:
+        program = timed_first_call(
+            kernelcache.jit(_columns_kernel(spec, radix, out_capacity),
+                            "order_by"), None, _SORT_PROGRAMS)
+        cache_put(_SORT_PROGRAMS, key, program)
+    with activity("dispatch"):
+        return program(tuple(columns), tuple(key[4] for key in keys),
+                       num_rows)
+
+
+def _columns_kernel(spec, radix: bool, out_capacity: int):
+    def kernel(columns, tables, num_rows):
+        keys = []
+        for (channel, typ, desc, nulls_first), ranks in zip(spec, tables):
+            values, valid = columns[channel]
+            if ranks is not None:
+                values, typ = ranks[values], T.INTEGER
+            keys.append((values, valid, typ, desc, nulls_first))
+        perm = _permutation(keys, num_rows, radix)
+        # i32 gather indices are ~5x cheaper on TPU
+        perm = perm[:out_capacity].astype(jnp.int32)
+        return jax.tree_util.tree_map(lambda x: x[perm], columns), perm
 
     return kernel
 

@@ -204,6 +204,59 @@ def test_segment_pre_reduce_direct_and_sorted(chip):
         chip.spec(SMALL, bool), chip.spec((), jnp.int64))
 
 
+def _fused_ops(compiled):
+    """The last word of every fusion's op_name in a compiled program."""
+    return [line.split('op_name="')[1].split('"')[0].rsplit("/", 1)[-1]
+            for line in compiled.as_text().splitlines()
+            if " fusion(" in line and 'op_name="' in line]
+
+
+def test_grouped_finish_direct(chip):
+    """``HashAggregationOperator``'s finish on the direct tier
+    (``groupby_direct``, ops/groupby.py) at the shape of TPC-H Q1's final
+    step: 1,024 staged rows, two dictionary keys (domains 3 and 2 in
+    their buckets, one nullable), DOUBLE sums, a count and a min over a
+    dictionary column through its rank tables.  The present slots are
+    compacted through ops/filter.py: no scatter-add (the ``bincount`` of
+    the eager ``jnp.nonzero`` it replaces was the unnamed
+    ``jit_scatter-add`` of the ledger's PR 40 line; an integer sum would
+    keep ``direct_grouped_aggregate``'s exact one)."""
+    from presto_tpu.ops.groupby import grouped_finish_kernel
+
+    rows = 1024
+    f64, i64, i32 = (np.dtype(d) for d in ("float64", "int64", "int32"))
+    kernel = grouped_finish_kernel(
+        (T.VARCHAR, T.VARCHAR), ("sum", "sum", "count", "min"),
+        (f64, f64, i64, i32), (4, 2), rows)
+    codes, real, mask = (chip.spec(rows, d)
+                         for d in (jnp.int32, jnp.float64, bool))
+    table = chip.spec(8, jnp.int32)
+    ops = _fused_ops(chip.compile(
+        kernel, (codes, codes), (mask, None), (real, real, None, codes),
+        (mask, mask, None, mask), (None, None, None, (table, table)),
+        chip.spec((), jnp.int32)))
+    assert "scatter-add" not in ops and "scatter" in ops
+
+
+def test_order_by_finish(chip):
+    """``OrderByOperator``'s finish (``order_by``, ops/sort.py) at the
+    shape of TPC-H Q1's: 1,024 staged rows ordered by two dictionary
+    keys through their rank tables (radix passes on the chip), ten
+    columns gathered through the permutation."""
+    from presto_tpu.ops.sort import _columns_kernel
+
+    rows = 1024
+    kernel = _columns_kernel(
+        ((0, T.VARCHAR, False, False), (1, T.VARCHAR, False, False)),
+        True, rows)
+    codes, real, mask = (chip.spec(rows, d)
+                         for d in (jnp.int32, jnp.float64, bool))
+    table = chip.spec(8, jnp.int32)
+    chip.compile(
+        kernel, ((codes, None), (codes, mask)) + ((real, mask),) * 8,
+        (table, table), chip.spec((), jnp.int32))
+
+
 @pytest.mark.parametrize("form", ["compacted", "left_for_the_sink"])
 def test_end_of_a_filter_segment(chip, form):
     """The end of TPC-H Q3's lineitem segment at SF1 (exec/fusion.py
@@ -229,13 +282,10 @@ def test_end_of_a_filter_segment(chip, form):
             parts = jnp.where(live, parts, 2)
         return cols, count, parts
 
-    text = chip.compile(
+    ops = _fused_ops(chip.compile(
         kernel, chip.spec(ROWS, jnp.int64), chip.spec(ROWS, jnp.float64),
         chip.spec(ROWS, jnp.float64), chip.spec(ROWS, jnp.int32),
-        chip.spec((), jnp.int64)).as_text()
-    ops = [line.split('op_name="')[1].split('"')[0].rsplit("/", 1)[-1]
-           for line in text.splitlines()
-           if " fusion(" in line and 'op_name="' in line]
+        chip.spec((), jnp.int64)))
     assert "scatter-add" not in ops
     if form == "compacted":
         assert "scatter" in ops and "gather" in ops
@@ -309,15 +359,18 @@ def test_device_concat_append(chip):
     """exec/operator.device_concat keeps device batches on the device: one
     append program per (output bucket, input bucket) pair, here a join's
     64K-capacity output into a 16K bucket and into a 1M one, over the
-    64-bit column types (BIGINT, DOUBLE with a validity mask)."""
-    from presto_tpu.exec.operator import _append_kernel
+    64-bit column types (BIGINT, DOUBLE with a validity mask); the first
+    append of a concat makes the zeroed bucket inside the program."""
+    from presto_tpu.exec.operator import _append_kernel, _first_append_kernel
 
+    ins = ((chip.spec(ROWS, jnp.int64), None),
+           (chip.spec(ROWS, jnp.float64), chip.spec(ROWS, bool)))
     for out_rows in (SMALL, 1 << 20):
         chip.compile(
             _append_kernel,
             ((chip.spec(out_rows, jnp.int64), None),
              (chip.spec(out_rows, jnp.float64), chip.spec(out_rows, bool))),
-            ((chip.spec(ROWS, jnp.int64), None),
-             (chip.spec(ROWS, jnp.float64), chip.spec(ROWS, bool))),
-            chip.spec((), jnp.int32), chip.spec((), jnp.int32))
+            ins, chip.spec((), jnp.int32), chip.spec((), jnp.int32))
+        chip.compile(_first_append_kernel(out_rows), None, ins,
+                     chip.spec((), jnp.int32), chip.spec((), jnp.int32))
 

@@ -185,19 +185,22 @@ def test_operator_tier_matches_plain_reference(runner_on, want, name):
 
 
 def test_q1_dispatch_reduction(runner_on, want):
-    """Q1 at SF0.01 is ONE jit launch (filter, projections and the
-    per-batch aggregation in one segment over the coalesced scan), with
-    the reference's answer."""
-    assert _run_statement(runner_on, want, "q1")["dispatches"] == 1
+    """Q1 at SF0.01 is ONE jit launch over the scan (filter, projections
+    and the per-batch aggregation in one segment over the coalesced
+    scan) and one for each finish (the merge aggregation's
+    ``groupby_direct``, ORDER BY's ``order_by``), with the reference's
+    answer."""
+    assert _run_statement(runner_on, want, "q1")["dispatches"] == 3
 
 
 def test_q6_q3_parity_and_strictly_fewer(runner_on, want):
-    """Q6 is one launch, Q3 four (a segment a table, those over orders
-    and lineitem each with a join probe absorbed, and the projection
-    after the aggregation): a probe or an aggregation that left its
-    segment shows here as a larger count."""
-    assert _run_statement(runner_on, want, "q6")["dispatches"] == 1
-    assert _run_statement(runner_on, want, "q3")["dispatches"] == 4
+    """Q6 is one launch and its global finish, Q3 four (a segment a
+    table, those over orders and lineitem each with a join probe
+    absorbed, and the projection after the aggregation) and the finishes
+    of its GROUP BY and its ORDER BY: a probe or an aggregation that
+    left its segment shows here as a larger count."""
+    assert _run_statement(runner_on, want, "q6")["dispatches"] == 2
+    assert _run_statement(runner_on, want, "q3")["dispatches"] == 6
 
 
 def test_explain_analyze_reports_jit_counters(runner_on):
@@ -402,7 +405,9 @@ def test_held_partials_through_a_coalescing_scan(want, name):
     """Q1 (direct domain) and Q6 (global) over a scan cut into 30
     batches: the segment that stages the scan holds all 30 and flushes
     once, on the miss path and on the scan cache's hit path, with the
-    reference's answer."""
+    reference's answer.  Beside the segment's launches: Q1's merge
+    program, and one launch for each operator's finish (two in Q1, one
+    in Q6)."""
     r = LocalQueryRunner.tpch(scale=0.01, config=_cfg(
         scan_batch_rows=2048, task_concurrency=1))
     for _pass in ("miss", "hit"):
@@ -410,7 +415,7 @@ def test_held_partials_through_a_coalescing_scan(want, name):
         ts = r._last_task.task_stats()
         assert ts.prereduce_batches_held == 30, (_pass, ts)
         assert ts.prereduce_flushes == 1
-        assert jc["dispatches"] == 30 + (name == "q1")
+        assert jc["dispatches"] == 30 + (3 if name == "q1" else 1)
     text = "\n".join(row[0] for row in r.execute(
         "explain analyze " + tpch_reference.statement(name)).rows)
     assert "prereduce held: 30 batches kept on the device, 1 flushes" \
@@ -492,10 +497,11 @@ def test_q1_prereduce_dispatch_pin(runner_on):
 
 def test_q6_prereduce_single_dispatch(runner_on):
     """Q6-class scan->global-agg pipelines collapse to ONE dispatch per
-    coalesced batch: at SF0.01 the whole query is a single launch."""
+    coalesced batch: at SF0.01 the whole query is a single launch and
+    the global aggregation's finish."""
     runner_on.execute(QUERIES[6])
     jc = runner_on._last_task.jit_counters()
-    assert jc["dispatches"] == 1, jc
+    assert jc["dispatches"] == 2, jc
     assert jc["prereduce_rows"] > 50_000, jc
 
 
