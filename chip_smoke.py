@@ -4,19 +4,21 @@
 Default (one chip): a real coordinator and two workers on ephemeral HTTP
 ports in this one process (``DistributedQueryRunner.tpch``), TPC-H SF1
 generated from the connector's fixed hash streams, Q1 / Q6 / Q3 submitted
-over HTTP through ``presto_tpu.client`` twice each (cold, then warm), every
-result compared with a plain numpy computation over the same generated
-columns that shares no code with the engine.  The warm run of each query
-must build nothing, by the engine's own ``jit_compiles`` (kernel-cache
-misses) and by XLA's backend-compile event, which JAX raises around
-``compile_or_get_cached``: it fires for a load from the persistent cache
-as well as for a compile, so the cold run's count is of programs built,
-not of cache misses.  The warm run must also scan nothing: every table
-scan of every leaf task is a hit of the device-resident scan cache
-(``scan_cache_hits`` / ``scan_cache_misses`` in ``queryStats``), and a
-leaf task of Q1 or Q6 must hold its pre-reduced partial states on the
-device and flush them at most twice (``prereduce_batches_held`` /
-``prereduce_flushes``).  In a warm Q3 the tasks of three stages (the
+over HTTP through ``presto_tpu.client`` three times each (cold, hit,
+warm), every result compared with a plain numpy computation over the same
+generated columns that shares no code with the engine.  The warm run of
+each query must build nothing, by the engine's own ``jit_compiles``
+(kernel-cache misses) and by XLA's backend-compile event, which JAX raises
+around ``compile_or_get_cached``: it fires for a load from the persistent
+cache as well as for a compile, so the cold run's count is of programs
+built, not of cache misses.  The hit run, the first to find every table
+kept, may build the programs of a kept run (``fused_segment_run``,
+``scan_run_stack``) and nothing else.  The warm run must also scan
+nothing: every table scan of every leaf task is a hit of the
+device-resident scan cache (``scan_cache_hits`` / ``scan_cache_misses``
+in ``queryStats``), and a leaf task of Q1 or Q6 must pre-reduce its kept
+run in run launches and hold no partial state batch by batch
+(``run_launches`` at least 1, ``prereduce_batches_held`` 0).  In a warm Q3 the tasks of three stages (the
 ``lineitem`` scan, the ``orders`` scan and the join) must end every
 dispatch of their segments without a compaction (``compactions_skipped``
 above 0, ``compactions`` 0); only ``customer``'s filter compacts.  In a
@@ -87,6 +89,8 @@ SCALE = 1.0  # TPC-H SF1, the first pinned config of BASELINE.json
 RTOL = 1e-6  # DOUBLE to 1e-6, as benchmark/check.py compares
 CLIENT_TIMEOUT_S = 900.0  # a cold query compiles for minutes on the chip
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: what the first hit of a kept run may build (the event's ``fun_name``)
+KEPT_RUN_PROGRAMS = ("jit(fused_segment_run)", "jit(scan_run_stack)")
 
 
 def emit(obj: dict) -> None:
@@ -336,7 +340,7 @@ def one_chip(xla: XlaCompiles) -> None:
         client = runner.new_client(user="chip_smoke")
         for name, sql in (("q1", Q1), ("q6", Q6), ("q3", Q3)):
             line = {"phase": "query", "query": name, "workers": 2}
-            for temp in ("cold", "warm"):
+            for temp in ("cold", "hit", "warm"):
                 rows, wall, detail, compiled = run_query(
                     runner, client, xla, sql)
                 stats = detail.get("queryStats") or {}
@@ -352,6 +356,9 @@ def one_chip(xla: XlaCompiles) -> None:
                     stats["prereduce_batches_held"])
                 line[f"{temp}_prereduce_flushes"] = int(
                     stats["prereduce_flushes"])
+                # launches that pre-reduced a kept run, and its batches
+                line[f"{temp}_run_launches"] = [
+                    int(stats["run_launches"]), int(stats["run_batches"])]
                 # dispatches whose program compacted its rows at the end,
                 # and those that ended with a row mask and moved nothing
                 line[f"{temp}_compactions"] = [
@@ -363,6 +370,13 @@ def one_chip(xla: XlaCompiles) -> None:
                 if detail.get("resultCached"):
                     raise AssertionError(f"{name}: served from the result "
                                          "cache, not from the device")
+                if temp == "hit":
+                    others = [c for c in compiled
+                              if c not in KEPT_RUN_PROGRAMS]
+                    if others:
+                        raise AssertionError(
+                            f"{name}: the first hit built programs that "
+                            f"are not a kept run's: {sorted(set(others))}")
             if name == "q1":
                 line["warm_final_stage_s"] = final_stage_s(
                     runner, client.last_query_id)
@@ -379,16 +393,17 @@ def one_chip(xla: XlaCompiles) -> None:
                     f"{name}: the warm run compiled "
                     f"({line['warm_jit_compiles']} jit, {len(compiled)} "
                     f"XLA: {sorted(set(compiled))})")
-            # (held, flushes) of each task that held partials on the device
-            holding = [(ts["prereduce_batches_held"], ts["prereduce_flushes"])
-                       for tasks in (detail.get("taskStats") or {}).values()
-                       for ts in tasks if ts.get("prereduce_batches_held")]
+            # (run launches, held) of each task that pre-reduced a scan
+            leaves = [(ts["run_launches"], ts["prereduce_batches_held"])
+                      for tasks in (detail.get("taskStats") or {}).values()
+                      for ts in tasks if ts.get("scan_cache_hits")]
             if name in ("q1", "q6") and (
-                    not holding or any(f > 2 for _held, f in holding)):
+                    not leaves or any(launches < 1 or held
+                                      for launches, held in leaves)):
                 raise AssertionError(
-                    f"{name}: a leaf task hands its pre-reduced partials to "
-                    f"the sink once, at most twice; the warm run's tasks "
-                    f"read (held, flushes) {holding}")
+                    f"{name}: a leaf task pre-reduces its kept run in run "
+                    f"launches and holds nothing batch by batch; the warm "
+                    f"run's tasks read (run launches, held) {leaves}")
             # stage -> (compactions, skipped) of each of its tasks
             moved = {stage: [(ts["compactions"], ts["compactions_skipped"])
                              for ts in tasks]

@@ -147,6 +147,11 @@ class OperatorStats:
     # which emit a batch a dispatch
     prereduce_batches_held: int = 0
     prereduce_flushes: int = 0
+    # a segment fed by a scan-cache hit that covers the kept run in run
+    # launches (exec/fusion.py _plan_run): the launches, each over many
+    # batches with no partial held, and the batches they covered
+    run_launches: int = 0
+    run_batches: int = 0
     # a fused segment's dispatches by how the program that ran ends
     # (exec/fusion.py _compile, decided at trace time): it compacted its
     # live rows to the front through ops/filter.py, or it ended with a
@@ -220,6 +225,8 @@ class TaskStats:
     prereduce_rows: int = 0
     prereduce_batches_held: int = 0
     prereduce_flushes: int = 0
+    run_launches: int = 0
+    run_batches: int = 0
     compactions: int = 0
     compactions_skipped: int = 0
     # the scan cache's account (OperatorStats.scan_cache_*), summed
@@ -309,6 +316,8 @@ class StageStats:
     prereduce_rows: int = 0
     prereduce_batches_held: int = 0
     prereduce_flushes: int = 0
+    run_launches: int = 0
+    run_batches: int = 0
     compactions: int = 0
     compactions_skipped: int = 0
     # the scan cache's account (OperatorStats.scan_cache_*), summed
@@ -375,6 +384,8 @@ def _add_prereduce(into, other) -> None:
     into.prereduce_rows += other.prereduce_rows
     into.prereduce_batches_held += other.prereduce_batches_held
     into.prereduce_flushes += other.prereduce_flushes
+    into.run_launches += other.run_launches
+    into.run_batches += other.run_batches
     into.compactions += other.compactions
     into.compactions_skipped += other.compactions_skipped
 
@@ -407,6 +418,8 @@ class QueryStats:
     prereduce_rows: int = 0
     prereduce_batches_held: int = 0
     prereduce_flushes: int = 0
+    run_launches: int = 0
+    run_batches: int = 0
     compactions: int = 0
     compactions_skipped: int = 0
     # the scan cache's account (OperatorStats.scan_cache_*), summed
@@ -485,12 +498,15 @@ def scan_cache_line(stats: Dict) -> str:
 def segment_line(stats: Dict) -> str:
     """EXPLAIN ANALYZE's line for the fused segments' account of a
     TaskStats / QueryStats dict: partial states kept on the device, a
-    dispatched batch each, and their hand-overs to the consumer; then
-    the dispatches that compacted their rows at the end and those that
-    had a row mask and moved nothing."""
+    dispatched batch each, and their hand-overs to the consumer; the
+    launches that pre-reduced a kept run and the batches they covered;
+    then the dispatches that compacted their rows at the end and those
+    that had a row mask and moved nothing."""
     return (f"prereduce held: {stats.get('prereduce_batches_held', 0)} "
             f"batches kept on the device, "
             f"{stats.get('prereduce_flushes', 0)} flushes; "
+            f"run launches: {stats.get('run_launches', 0)} over "
+            f"{stats.get('run_batches', 0)} batches; "
             f"compactions: {stats.get('compactions', 0)} done, "
             f"{stats.get('compactions_skipped', 0)} skipped")
 

@@ -73,6 +73,7 @@ string fallbacks), and nested input/output types.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
@@ -578,17 +579,40 @@ def _partition_ids(outs, out_meta, partition):
     return partition_of(row_hash(triples), nparts)
 
 
+@dataclasses.dataclass
+class _RunPlan:
+    """How a segment covers its hit's kept run: the run program, what it
+    is called with (the stacked view, exec/scancache.py), the live rows
+    of each real batch, and the first batch of each launch to come."""
+
+    fn: object
+    columns: tuple
+    num_rows: object
+    df_args: tuple
+    out_meta: list
+    chunk: int
+    rows: List[int]
+    starts: "collections.deque"
+
+
 class FusedSegmentOperator(Operator):
     """Executes a fused run of row-local stages as one jitted program per
     batch; optionally coalesces host scan batches first."""
 
     def __init__(self, ctx: OperatorContext, stages: Sequence,
                  coalesce_rows: int, partition_spec, min_batch_capacity,
-                 agg_spec: Optional[PreReduceSpec] = None, scan_fill=None):
+                 agg_spec: Optional[PreReduceSpec] = None, scan_fill=None,
+                 scan_hit=None):
         super().__init__(ctx)
         # the scan cache's record of what this segment stages for its
         # scan (exec/scancache.py ScanFill); None off a cached table
         self._scan_fill = scan_fill
+        # the kept run of a scan-cache hit that feeds this segment
+        # (exec/scancache.py ScanHit), until _run_plan looks at it; then
+        # _run is what covers the run in run launches, or None where the
+        # hit's batches come one by one through the scan operator
+        self._scan_hit = scan_hit
+        self._run: Optional[_RunPlan] = None
         self.stages = list(stages)
         self.partition_spec = partition_spec
         self.agg_spec = agg_spec
@@ -636,7 +660,7 @@ class FusedSegmentOperator(Operator):
 
     # -- protocol --------------------------------------------------------
     def needs_input(self) -> bool:
-        if self._finishing:
+        if self._finishing or self._run_plan() is not None:
             return False
         if self._coalesce:
             return self._acc_rows < self._coalesce
@@ -653,6 +677,8 @@ class FusedSegmentOperator(Operator):
             self._accumulate(batch)
 
     def get_output(self) -> Optional[Batch]:
+        if self._run_plan() is not None:
+            return self._launch_run() if self._run.starts else None
         if self._ready:
             return self._emit(self._ready.pop())
         if self._coalesce:
@@ -725,6 +751,8 @@ class FusedSegmentOperator(Operator):
         return Batch(tuple(cols), 1)
 
     def is_finished(self) -> bool:
+        if self._run_plan() is not None:
+            return not self._run.starts
         return self._finishing and self._pending is None \
             and self._acc_rows == 0 and not self._held \
             and not self._ready and not self._needs_default_row()
@@ -1111,6 +1139,26 @@ class FusedSegmentOperator(Operator):
             return fn(args)
 
     def _compile_merge(self, out_meta, doms):
+        merge = self._merge_fn(out_meta, doms)
+
+        def kernel(held):
+            import jax.numpy as jnp
+
+            cols = tuple(
+                (jnp.stack([outs[ci][0] for outs, _n in held]),
+                 None if held[0][0][ci][1] is None
+                 else jnp.stack([outs[ci][1] for outs, _n in held]))
+                for ci in range(len(held[0][0])))
+            return merge(cols, jnp.stack([n for _outs, n in held]))
+
+        return kernelcache.jit(kernel, "fused_segment_merge")
+
+    def _merge_fn(self, out_meta, doms):
+        """The merge of direct partials stacked a partial a row (``[k,
+        domain]`` pairs, ``int[k]`` group counts) into one partial of the
+        same form (ops/groupby.py ``merge_pre_reduced``), with the merged
+        rows' partition ids where the sink takes them precomputed; traced
+        inside ``fused_segment_merge`` and ``fused_segment_run``."""
         agg = self.agg_spec
         k = len(agg.group_channels)
         key_types = [typ for typ, _d in out_meta[:k]]
@@ -1118,11 +1166,11 @@ class FusedSegmentOperator(Operator):
         out_dtypes = [a.out_type.np_dtype for a in agg.aggs]
         partition = self.partition_spec
 
-        def kernel(held):
+        def merge(cols, counts):
             from presto_tpu.ops.groupby import merge_pre_reduced
 
             key_outs, agg_outs, count = merge_pre_reduced(
-                held, key_types, doms, merge_prims, out_dtypes)
+                cols, counts, key_types, doms, merge_prims, out_dtypes)
             # a count state is always valid, as the batch's program left it
             agg_outs = [(v, None if a.prim == "count" else valid)
                         for a, (v, valid) in zip(agg.aggs, agg_outs)]
@@ -1131,7 +1179,172 @@ class FusedSegmentOperator(Operator):
                      else _partition_ids(outs, out_meta, partition))
             return outs, count, parts
 
-        return kernelcache.jit(kernel, "fused_segment_merge")
+        return merge
+
+    # -- a kept run in run launches (scan-cache hit) ---------------------
+    def _run_plan(self) -> Optional["_RunPlan"]:
+        """What covers the kept run of the scan-cache hit that feeds this
+        segment, decided once, before its first batch; None where the
+        hit's batches come one by one as ever."""
+        if self._scan_hit is not None:
+            hit, self._scan_hit = self._scan_hit, None
+            self._run = self._plan_run(hit)
+        return self._run
+
+    def _plan_run(self, hit) -> Optional["_RunPlan"]:
+        """Run launches cover the kept run where the segment pre-reduces
+        into a bounded partial (the global form, or the direct path as
+        the per-batch program traces it), absorbed no join probe (a
+        probe's overflow retry needs each batch's total), and the run has
+        two batches or more under one dictionary binding and one
+        nullability.  Each launch maps the per-batch program over as
+        many batches as the per-batch path holds before
+        ``partial_agg_max_bytes`` flushes them (all of them, under the
+        limit), so the rows handed over are the per-batch path's."""
+        agg = self.agg_spec
+        batches = hit.batches
+        if agg is None or self._probe_idx or len(batches) < 2:
+            return None
+        snap = self._df_snapshot()
+        if snap is None:
+            return None
+        df_shapes, df_args = snap
+        capacity = max(b.capacity for b in batches)
+        part_n = self.partition_spec[1] if self.partition_spec else 0
+        with activity("kernel_lookup"):
+            binding = (dictionary_binding_key(batches[0].columns),
+                       tuple(c.valid is not None for c in batches[0].columns))
+            if any((dictionary_binding_key(b.columns),
+                    tuple(c.valid is not None for c in b.columns)) != binding
+                   for b in batches[1:]):
+                return None
+            key = (self._expr_key, capacity, binding, df_shapes, part_n)
+            form = cache_get(_SEG_KERNELS, ("run_form", key))
+        if form is None:
+            form = self._trace_form(batches[0], capacity, df_shapes, df_args)
+            cache_put(_SEG_KERNELS, ("run_form", key), form)
+        out_meta, doms, partial_bytes = form
+        if not agg.global_ and doms is None:
+            return None     # the sort path: a partial as large as a batch
+        chunk = -(-self.ctx.config.partial_agg_max_bytes // partial_bytes)
+        if chunk < 2:
+            return None     # each batch flushes alone: nothing to gather
+        view = hit.stacked()
+        if view is None:
+            return None     # no room in the scan cache's budget
+        columns, num_rows, launches = view
+        self.ctx.stats.jit_dispatches += launches
+        n_pad = num_rows.shape[0]
+        chunk = min(chunk, n_pad)
+        width = n_pad if chunk == n_pad else next_bucket(chunk, 8)
+        run_key = ("run", key, n_pad, width, chunk)
+        with activity("kernel_lookup"):
+            fn = cache_get(_SEG_KERNELS, run_key)
+        if fn is None:
+            from presto_tpu.kernelcache import timed_first_call
+
+            fn = timed_first_call(
+                self._compile_run(batches[0], df_shapes, out_meta, doms,
+                                  width, chunk),
+                self.ctx.stats, _SEG_KERNELS)
+            cache_put(_SEG_KERNELS, run_key, fn)
+            self.ctx.stats.jit_compiles += 1
+        rows = [b.num_rows for b in batches]
+        self.ctx.stats.input_batches += len(rows)
+        self.ctx.stats.input_rows += sum(rows)
+        return _RunPlan(fn, columns, num_rows, df_args, out_meta, chunk,
+                        rows, collections.deque(range(0, len(rows), chunk)))
+
+    def _trace_form(self, batch, capacity, df_shapes, df_args):
+        """Trace, and not compile, the per-batch program over a batch of
+        ``batch``'s columns at ``capacity`` rows: its output's (type,
+        dictionary) a column, the direct path's key domains (None on the
+        sort path and in the global form) and the bytes of a partial."""
+        import jax
+
+        kernel, _name, out_meta, traced = self._segment_kernel(batch,
+                                                               df_shapes)
+        cols = tuple(
+            (jax.ShapeDtypeStruct((capacity,), c.values.dtype),
+             None if c.valid is None
+             else jax.ShapeDtypeStruct((capacity,), np.bool_))
+            for c in batch.columns)
+        jax.eval_shape(kernel, cols, jax.ShapeDtypeStruct((), np.int32),
+                       df_args, ())
+        return out_meta, traced.get("doms"), traced["partial_bytes"]
+
+    def _launch_run(self) -> Optional[Batch]:
+        """One run launch, over ``chunk`` batches of the kept run from the
+        next start: what it hands over is what the per-batch path hands
+        over for those batches, the direct form's one merged partial or
+        the global form's partial rows in batch order."""
+        run = self._run
+        start = run.starts.popleft()
+        rows = run.rows[start:start + run.chunk]
+        stats = self.ctx.stats
+        stats.jit_dispatches += 1
+        stats.run_launches += 1
+        stats.run_batches += len(rows)
+        stats.prereduce_rows += sum(rows)
+        with activity("dispatch"):
+            out = run.fn(run.columns, run.num_rows, start, run.df_args)
+        if self.agg_spec.global_:
+            import jax
+
+            with activity("device_wait"):
+                outs, parts = jax.device_get(out)
+            k = len(rows)
+            outs = tuple((v.reshape(-1)[:k],
+                          None if valid is None else valid.reshape(-1)[:k])
+                         for v, valid in outs)
+            parts = None if parts is None else parts.reshape(-1)[:k]
+            return self._emit(self._partial_batch(run.out_meta, outs, parts,
+                                                  k))
+        outs, count, parts = out
+        with activity("device_wait"):
+            n = int(count)
+        if n == 0:
+            return None
+        return self._emit(self._partial_batch(run.out_meta, outs, parts, n))
+
+    def _compile_run(self, batch, df_shapes, out_meta, doms, width, chunk):
+        """The run program: ``lax.map`` of the per-batch program (the
+        very function ``_compile`` jits, each batch's row count a traced
+        scalar) over ``width`` batches of the stacked run from ``start``,
+        those past ``chunk`` or the run's padding counting 0 rows; then
+        the direct form's merge of the mapped partials, or the global
+        form's partial rows as they are, a row a batch: the final step
+        sums them in the per-batch path's order, and a sum here would
+        change the order of the DOUBLE additions."""
+        kernel, _name, _meta, _traced = self._segment_kernel(batch,
+                                                             df_shapes)
+        merge = None if self.agg_spec.global_ else self._merge_fn(out_meta,
+                                                                   doms)
+
+        def run(columns, num_rows, start, df_args):
+            import jax.numpy as jnp
+            from jax import lax
+
+            last = num_rows.shape[0] - 1
+
+            def one(i):
+                b = jnp.minimum(start + i, last)
+                n = jnp.where((i < chunk) & (start + i <= last),
+                              num_rows[b], 0)
+                cols = tuple(
+                    (lax.dynamic_index_in_dim(v, b, keepdims=False),
+                     None if valid is None
+                     else lax.dynamic_index_in_dim(valid, b, keepdims=False))
+                    for v, valid in columns)
+                outs, count, parts, _totals = kernel(cols, n, df_args, ())
+                return outs, jnp.asarray(count), parts
+
+            outs, counts, parts = lax.map(one, jnp.arange(width))
+            if merge is None:
+                return outs, parts
+            return merge(outs, counts)
+
+        return kernelcache.jit(run, "fused_segment_run")
 
     def _observe_reduction(self, rows_in: int, groups_out: int) -> None:
         """Runtime half of the cost-based pre-reduce decision: when a
@@ -1148,6 +1361,13 @@ class FusedSegmentOperator(Operator):
             self._raw_emit = True
 
     def _compile(self, batch: Batch, df_shapes, probe_metas=()):
+        kernel, name, final_meta, traced = self._segment_kernel(
+            batch, df_shapes, probe_metas)
+        return kernelcache.jit(kernel, name), final_meta, traced
+
+    def _segment_kernel(self, batch: Batch, df_shapes, probe_metas=()):
+        """The per-batch program's function, its name, its output's
+        (type, dictionary) a column, and what its trace records."""
         # stage-by-stage expression compilation: each stage's dictionary
         # bindings are the previous stage's projection output
         # dictionaries (stage 0 binds the batch's columns)
@@ -1184,7 +1404,8 @@ class FusedSegmentOperator(Operator):
         raw_emit = self._raw_emit
         # filled while the program traces.  "doms": the key domains
         # where the pre-reduce took the direct path (its partial is
-        # bounded then).  "compaction": how a program that ends with a
+        # bounded then).  "partial_bytes": the bytes of a pre-reduced
+        # partial.  "compaction": how a program that ends with a
         # row mask and emits rows left them: "done" (compacted through
         # ops/filter.py), "prefix" or "sink" (not moved; the two cases
         # above the branches that set them)
@@ -1386,6 +1607,9 @@ class FusedSegmentOperator(Operator):
                         keys, agg_ins, out_dtypes, num_rows, mask,
                         doms if use_direct else None, cap)
                     outs = tuple(key_outs) + tuple(agg_outs)
+                traced["partial_bytes"] = sum(
+                    a.size * a.dtype.itemsize for pair in outs
+                    for a in pair if a is not None)
             elif mask is None:
                 outs = cur
                 count = num_rows
@@ -1422,7 +1646,7 @@ class FusedSegmentOperator(Operator):
 
         name = _SEGMENT_PROGRAM[bool(self._probe_idx),
                                 agg is not None and not raw_emit]
-        return kernelcache.jit(kernel, name), list(final_meta), traced
+        return kernel, name, list(final_meta), traced
 
 
 class FusedSegmentOperatorFactory(OperatorFactory):
@@ -1430,31 +1654,37 @@ class FusedSegmentOperatorFactory(OperatorFactory):
 
     def __init__(self, stages: Sequence, coalesce_rows: int = 0,
                  partition_spec=None, min_batch_capacity: int = 1024,
-                 agg_spec: Optional[PreReduceSpec] = None, scan_fill=None):
+                 agg_spec: Optional[PreReduceSpec] = None, scan_fill=None,
+                 scan_hit=None):
         self.stages = list(stages)
         self.coalesce_rows = coalesce_rows
         self.partition_spec = partition_spec
         self.min_batch_capacity = min_batch_capacity
         self.agg_spec = agg_spec
         self.scan_fill = scan_fill
+        self.scan_hit = scan_hit
 
     def create(self, ctx: OperatorContext) -> FusedSegmentOperator:
         return FusedSegmentOperator(ctx, self.stages, self.coalesce_rows,
                                     self.partition_spec,
                                     self.min_batch_capacity,
                                     agg_spec=self.agg_spec,
-                                    scan_fill=self.scan_fill)
+                                    scan_fill=self.scan_fill,
+                                    scan_hit=self.scan_hit)
 
-    def for_cached_scan(self, fill=None) -> "FusedSegmentOperatorFactory":
+    def for_cached_scan(self, fill=None,
+                        hit=None) -> "FusedSegmentOperatorFactory":
         """This segment for one execution of a scan the scan cache
         knows (exec/scancache.py).  A miss: the same segment, recording
-        what it stages into ``fill``.  A hit (no fill): it is fed the
-        kept device batches, so it coalesces nothing and dispatches each
-        as it comes (the ``_pending`` path)."""
+        what it stages into ``fill``.  A hit: it is fed the kept device
+        batches, so it coalesces nothing; it covers the whole run
+        ``hit`` in run launches where ``_plan_run`` says it can, and
+        otherwise dispatches each batch as it comes (the ``_pending``
+        path)."""
         return FusedSegmentOperatorFactory(
             self.stages, self.coalesce_rows if fill is not None else 0,
             self.partition_spec, self.min_batch_capacity, self.agg_spec,
-            scan_fill=fill)
+            scan_fill=fill, scan_hit=hit)
 
     def describe(self) -> str:
         """Human-readable stage summary."""

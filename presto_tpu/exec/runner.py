@@ -99,7 +99,7 @@ def _through_scan_cache(p: Pipeline):
     if isinstance(found, ScanHit):
         head = [CachedScanOperatorFactory(found)]
         if segment is not None:
-            head.append(segment.for_cached_scan())
+            head.append(segment.for_cached_scan(hit=found))
         return Pipeline(head + rest, name=p.name), None
     head = [scan.filling(found)]
     if segment is not None:

@@ -21,6 +21,21 @@ columns are among a kept run's takes them from it; one that needs a
 column the run lacks fills a run of its own, which replaces the runs it
 covers.
 
+**The stacked view.**  A segment that pre-reduces a hit into bounded
+partial states launches one program over the whole run
+(``exec/fusion.py``), and such a program takes the run as one array a
+column: ``[n_pad, capacity]``, the batches in their order, ``n_pad``
+the batch count bucketed (``next_bucket(n, 8)``), and each batch's live
+rows as ``int32[n_pad]``, 0 in the padding batches.  ``ScanHit.stacked``
+builds a column's stack once per run, through one program
+(``scan_run_stack``) at the first hit that asks for it, and keeps it
+beside the run: a later hit of any statement that reads the column
+takes it as it is (Q6's columns are among Q1's).  The stacks count in
+the run's ``nbytes`` and so in the budget; a run whose stacks would not
+fit gets none (the hit goes per batch), and they are evicted and
+dropped with the run.  The per-batch list stays as it is for every
+other consumer.
+
 **Bounded.**  One budget for the process, because the process has one
 device: half of what the device reports as ``bytes_limit``, a fixed
 figure where the backend reports none (the CPU's).  Least-recently-
@@ -48,7 +63,11 @@ import weakref
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from presto_tpu.batch import Batch
+import numpy as np
+
+from presto_tpu import kernelcache
+from presto_tpu.batch import Batch, next_bucket
+from presto_tpu.spans import activity
 
 #: the budget where the backend reports no ``bytes_limit`` (the CPU)
 FALLBACK_BUDGET_BYTES = 512 << 20
@@ -59,26 +78,75 @@ DEVICE_BUDGET_SHARE = 0.5
 _OWNER_TOKENS = itertools.count(1)
 
 
-@dataclasses.dataclass(frozen=True)
+@dataclasses.dataclass(eq=False)
 class KeptRun:
     """One task's scan of one table, resident: ``batches`` hold
-    ``columns`` (connector column names) in that order."""
+    ``columns`` (connector column names) in that order.  ``stacked``
+    holds the columns a hit asked to have stacked, ``num_rows`` their
+    batches' live rows (the module's "stacked view"); ``nbytes`` counts
+    both, and changes under the cache's lock only."""
 
     owner: int
     key: tuple
     columns: Tuple[str, ...]
     batches: List[Batch]
     nbytes: int
+    stacked: Dict[str, tuple] = dataclasses.field(default_factory=dict)
+    num_rows: object = None
+
+
+#: the stack programs, one a (batch count, capacity) shape
+_STACK_KERNELS = kernelcache.new_cache("scan_run_stack")
+
+
+def _stack_program(n_pad: int, capacity: int):
+    """One launch that stacks columns of a run's batches (per batch, per
+    column, a ``(values, valid)`` pair, a column's valid present in every
+    batch or in none) into ``[n_pad, capacity]`` arrays, short batches and
+    the padding batches zero-filled."""
+    fn = kernelcache.cache_get(_STACK_KERNELS, (n_pad, capacity))
+    if fn is not None:
+        return fn
+
+    def kernel(grid):
+        import jax.numpy as jnp
+
+        def stack(arrays, dtype):
+            rows = [jnp.pad(a, (0, capacity - a.shape[0])) for a in arrays]
+            rows += [jnp.zeros(capacity, dtype)] * (n_pad - len(rows))
+            return jnp.stack(rows)
+
+        return tuple(
+            (stack([row[ci][0] for row in grid], grid[0][ci][0].dtype),
+             None if grid[0][ci][1] is None
+             else stack([row[ci][1] for row in grid], bool))
+            for ci in range(len(grid[0])))
+
+    fn = kernelcache.jit(kernel, "scan_run_stack")
+    kernelcache.cache_put(_STACK_KERNELS, (n_pad, capacity), fn)
+    return fn
+
 
 
 class ScanHit:
     """What a hit hands to the pipeline: the kept batches cut to the
     requested columns, and the bytes of those columns."""
 
-    def __init__(self, run: KeptRun, columns: Sequence[str]):
+    def __init__(self, run: KeptRun, columns: Sequence[str],
+                 cache: "ScanCache"):
+        self._run = run
+        self._cache = cache
+        self.columns = tuple(columns)
         channels = [run.columns.index(c) for c in columns]
         self.batches = [b.select_channels(channels) for b in run.batches]
         self.nbytes = sum(b.size_bytes for b in self.batches)
+
+    def stacked(self):
+        """The hit's columns as the run's stacked view: ``(columns,
+        num_rows, launches)``, a ``(values, valid)`` pair a column in the
+        hit's order, ``int32[n_pad]``, and the launches it took to build
+        what was missing (0 or 1).  None where the budget has no room."""
+        return self._cache.stack(self._run, self.columns)
 
 
 class ScanFill:
@@ -216,7 +284,7 @@ class ScanCache:
                 if (run.owner == owner and run.key == key
                         and set(columns) <= set(run.columns)):
                     self._runs.move_to_end(rid)
-                    hit = ScanHit(run, columns)
+                    hit = ScanHit(run, columns, self)
                     self._counters["hits"] += 1
                     self._counters["hit_bytes"] += hit.nbytes
                     return hit
@@ -238,13 +306,66 @@ class ScanCache:
                         if old.owner == owner and old.key == key
                         and set(old.columns) <= set(run.columns)]:
                 del self._runs[rid]     # the new run holds all of it
-            while self._runs and self._resident() + run.nbytes > budget:
-                self._runs.popitem(last=False)
-                self._counters["evictions"] += 1
             self._runs[next(self._run_ids)] = run
+            self._evict_for(run)
 
     def _resident(self) -> int:
         return sum(run.nbytes for run in self._runs.values())
+
+    def stack(self, run: KeptRun, columns: Sequence[str]):
+        """``ScanHit.stacked`` of ``run``: stacks the columns it lacks in
+        one launch, keeps them beside it and evicts other runs while the
+        cache is over its budget."""
+        import jax
+
+        with self._lock:
+            missing = [c for c in columns if c not in run.stacked]
+        launches = 0
+        if missing:
+            n_pad = next_bucket(len(run.batches), 8)
+            capacity = max(b.capacity for b in run.batches)
+            channels = [run.columns.index(c) for c in missing]
+            grid = tuple(tuple((b.columns[i].values, b.columns[i].valid)
+                               for i in channels) for b in run.batches)
+            extra = sum(n_pad * capacity * (values.dtype.itemsize
+                                             + (valid is not None))
+                        for values, valid in grid[0])
+            if run.num_rows is None:
+                extra += 4 * n_pad
+            if run.nbytes + extra > self.budget():
+                return None
+            with activity("dispatch"):
+                stacks = _stack_program(n_pad, capacity)(grid)
+                num_rows = run.num_rows
+                if num_rows is None:
+                    num_rows = jax.device_put(np.array(
+                        [b.num_rows for b in run.batches]
+                        + [0] * (n_pad - len(run.batches)), np.int32))
+            launches = 1
+            with self._lock:
+                for name, pair in zip(missing, stacks):
+                    if name not in run.stacked:
+                        run.stacked[name] = pair
+                        run.nbytes += sum(a.nbytes for a in pair
+                                          if a is not None)
+                if run.num_rows is None:
+                    run.num_rows = num_rows
+                    run.nbytes += num_rows.nbytes
+                self._evict_for(run)
+        with self._lock:
+            return (tuple(run.stacked[c] for c in columns), run.num_rows,
+                    launches)
+
+    def _evict_for(self, run: KeptRun) -> None:
+        """Least-recently-scanned runs other than ``run`` go while the
+        resident bytes exceed the budget (under the lock)."""
+        budget = self.budget()
+        for rid in list(self._runs):
+            if self._resident() <= budget:
+                return
+            if self._runs[rid] is not run:
+                del self._runs[rid]
+                self._counters["evictions"] += 1
 
     # -- accounts ----------------------------------------------------------
     def stats(self, connectors=None) -> Dict[str, int]:

@@ -48,6 +48,8 @@ PROGRAM_NAMES = (
     "fused_segment_agg",    #   a partial aggregation,
     "fused_segment_probe_agg",  # or both
     "fused_segment_merge",  #   a task's held partials merged at finish
+    "fused_segment_run",    #   a kept run pre-reduced in one launch
+    "scan_run_stack",       # exec/scancache.py: a kept run's columns stacked
     "filter_project",       # exec/operators.py
     "dynamic_filter",       # exec/dynamicfilter.py
     "join_build_index",     # exec/joinop.py: sorted build-side key index

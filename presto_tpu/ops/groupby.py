@@ -437,32 +437,31 @@ def segment_pre_reduce(
     return key_outs, agg_outs, num_groups
 
 
-def merge_pre_reduced(held, key_types: Sequence[T.Type],
+def merge_pre_reduced(cols, counts, key_types: Sequence[T.Type],
                       doms: Sequence[int], merge_prims: Sequence[str],
                       out_dtypes: Sequence):
     """Merge partials that ``segment_pre_reduce``'s direct path emitted
     for several batches into one partial of the same form, inside one
-    program (exec/fusion.py holds the partials on the device and calls
-    this once a task).
+    program (exec/fusion.py: once a task over the partials a segment
+    held on the device, or inside the program that pre-reduces a whole
+    kept run).
 
-    ``held``: per partial ``(cols, num_groups)``, ``cols`` the key pairs
-    then the state pairs as that path returned them, every array
-    ``domain`` rows long with the live groups first.  All partials come
-    from one key binding (same ``doms``, same nullability), so the key
-    codes mean the same in each.  A partial whose ``num_groups`` is 0
-    adds nothing: the caller pads the list with such to a bucketed
-    length.  ``merge_prims`` re-aggregate the states (count states sum).
+    ``cols``: the key pairs then the state pairs, each array ``[k,
+    domain]``, a partial a row as that path returned it (the live
+    groups first); ``counts``: each partial's ``num_groups``, ``[k]``.
+    All partials come from one key binding (same ``doms``, same
+    nullability), so the key codes mean the same in each.  A partial
+    whose count is 0 adds nothing: the callers pad to a bucketed ``k``
+    with such.  ``merge_prims`` re-aggregate the states (count states
+    sum).
 
     Returns ``(key_outs, agg_outs, num_groups)`` as segment_pre_reduce
     does: ``domain`` rows, the merged groups compacted to the front.
     """
-    domain = held[0][0][0][0].shape[0]
-    live = jnp.concatenate([jnp.arange(domain) < n for _cols, n in held])
-    cols = [
-        (jnp.concatenate([c[ci][0] for c, _n in held]),
-         None if held[0][0][ci][1] is None
-         else jnp.concatenate([c[ci][1] for c, _n in held]))
-        for ci in range(len(held[0][0]))]
+    domain = cols[0][0].shape[1]
+    live = (jnp.arange(domain)[None, :] < counts[:, None]).reshape(-1)
+    cols = [(v.reshape(-1), None if valid is None else valid.reshape(-1))
+            for v, valid in cols]
     k = len(key_types)
     keys = [(v, valid, t) for (v, valid), t in zip(cols[:k], key_types)]
     aggs = [(prim, v, valid)

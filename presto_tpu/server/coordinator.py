@@ -4835,7 +4835,12 @@ class CoordinatorServer:
                         # execution split, plan-cache disposition
                         "resourceGroup": q.resource_group_name,
                         "queuedS": round(q.queued_s, 6),
-                        "executionS": round(q.execution_s, 6),
+                        # the dispatcher writes execution_s just after
+                        # the state turns terminal: until then, the
+                        # time since admission
+                        "executionS": round(q.execution_s or (
+                            max(ev.now() - q.admit_time, 0.0)
+                            if q.admit_time is not None else 0.0), 6),
                         "planCached": q.plan_cached,
                         # result-cache disposition: true = this run was
                         # served from spool pages with zero execution

@@ -328,6 +328,9 @@ def _operator_entry(stats: Dict) -> Dict:
             "kernelTier": stats.get("kernel_tier", ""),
             # batches whose partial states a segment kept on the device
             "prereduceHeld": stats.get("prereduce_batches_held", 0),
+            # a segment's launches over a kept run, and its batches
+            "runLaunches": stats.get("run_launches", 0),
+            "runBatches": stats.get("run_batches", 0),
             # a segment's dispatches that compacted their rows, and
             # those that ended with a row mask and moved nothing
             "compactions": stats.get("compactions", 0),

@@ -302,19 +302,55 @@ def test_merge_of_held_partials(chip):
     from presto_tpu.ops.groupby import merge_pre_reduced
 
     f64, i64 = np.dtype("float64"), np.dtype("int64")
-    domain = 6
-    partial = (
-        ((chip.spec(domain, jnp.int32), None),
-         (chip.spec(domain, jnp.int32), None),
-         (chip.spec(domain, jnp.float64), chip.spec(domain, bool)),
-         (chip.spec(domain, jnp.int64), None),
-         (chip.spec(domain, jnp.float64), chip.spec(domain, bool))),
-        chip.spec((), jnp.int64))
+    k, domain = 64, 6
+    stacked = (
+        (chip.spec((k, domain), jnp.int32), None),
+        (chip.spec((k, domain), jnp.int32), None),
+        (chip.spec((k, domain), jnp.float64), chip.spec((k, domain), bool)),
+        (chip.spec((k, domain), jnp.int64), None),
+        (chip.spec((k, domain), jnp.float64), chip.spec((k, domain), bool)))
     chip.compile(
-        lambda held: merge_pre_reduced(
-            held, [T.VARCHAR, T.VARCHAR], [3, 2], ["sum", "sum", "min"],
-            [f64, i64, f64]),
-        (partial,) * 64)
+        lambda cols, counts: merge_pre_reduced(
+            cols, counts, [T.VARCHAR, T.VARCHAR], [3, 2],
+            ["sum", "sum", "min"], [f64, i64, f64]),
+        stacked, chip.spec(k, jnp.int64))
+
+
+def test_run_program_over_a_kept_run(chip):
+    """The program that pre-reduces a whole kept run in one launch
+    (exec/fusion.py ``fused_segment_run``) at TPC-H Q1's SF1 shape: the
+    direct pre-reduce of one 64K batch mapped (``lax.map``) over a run of
+    46 batches stacked to 64 (exec/scancache.py), each batch read at a
+    traced index and its row count from the run's ``int32[64]``, then the
+    merge of the 64 partials in the same program."""
+    from jax import lax
+
+    from presto_tpu.ops.groupby import merge_pre_reduced, segment_pre_reduce
+
+    f64, i64 = np.dtype("float64"), np.dtype("int64")
+    n_pad = 64
+
+    def run(k0, k1, v, live, num_rows, start):
+        def one(i):
+            b = jnp.minimum(start + i, n_pad - 1)
+            at = lambda a: lax.dynamic_index_in_dim(a, b, keepdims=False)
+            key_outs, agg_outs, count = segment_pre_reduce(
+                [(at(k0), None, T.VARCHAR), (at(k1), None, T.VARCHAR)],
+                _q1_aggs(at(v)), [f64, f64, i64], num_rows[b], at(live),
+                [3, 2], ROWS)
+            return tuple(key_outs) + tuple(agg_outs), count
+
+        outs, counts = lax.map(one, jnp.arange(n_pad))
+        return merge_pre_reduced(outs, counts, [T.VARCHAR, T.VARCHAR],
+                                 [3, 2], ["sum", "sum", "sum"],
+                                 [f64, f64, i64])
+
+    chip.compile(
+        run, chip.spec((n_pad, ROWS), jnp.int32),
+        chip.spec((n_pad, ROWS), jnp.int32),
+        chip.spec((n_pad, ROWS), jnp.float64),
+        chip.spec((n_pad, ROWS), bool), chip.spec(n_pad, jnp.int32),
+        chip.spec((), jnp.int64))
 
 
 def test_grouped_aggregate_sort_tier(chip):

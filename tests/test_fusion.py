@@ -403,30 +403,78 @@ def test_bounded_prereduce_holds_partials_on_the_device(case, held,
 @pytest.mark.parametrize("name", ["q1", "q6"])
 def test_held_partials_through_a_coalescing_scan(want, name):
     """Q1 (direct domain) and Q6 (global) over a scan cut into 30
-    batches: the segment that stages the scan holds all 30 and flushes
-    once, on the miss path and on the scan cache's hit path, with the
-    reference's answer.  Beside the segment's launches: Q1's merge
-    program, and one launch for each operator's finish (two in Q1, one
-    in Q6)."""
+    batches: on the miss path the segment that stages the scan holds all
+    30 and flushes once; on the scan cache's hit path it pre-reduces the
+    kept run in one run launch and holds nothing; the reference's answer
+    either way.  Beside the segment's launches: Q1's merge program on the
+    miss path, the stacking of the run's columns at the first hit, and
+    one launch for each operator's finish (two in Q1, one in Q6)."""
     r = LocalQueryRunner.tpch(scale=0.01, config=_cfg(
         scan_batch_rows=2048, task_concurrency=1))
-    for _pass in ("miss", "hit"):
-        jc = _run_statement(r, want, name)
-        ts = r._last_task.task_stats()
-        assert ts.prereduce_batches_held == 30, (_pass, ts)
-        assert ts.prereduce_flushes == 1
-        assert jc["dispatches"] == 30 + (3 if name == "q1" else 1)
+    finish = 2 if name == "q1" else 1
+    jc = _run_statement(r, want, name)
+    ts = r._last_task.task_stats()
+    assert (ts.prereduce_batches_held, ts.prereduce_flushes) == (30, 1)
+    assert (ts.run_launches, ts.run_batches) == (0, 0)
+    assert jc["dispatches"] == 30 + (name == "q1") + finish
+    jc = _run_statement(r, want, name)
+    ts = r._last_task.task_stats()
+    assert (ts.prereduce_batches_held, ts.prereduce_flushes) == (0, 0)
+    assert (ts.run_launches, ts.run_batches) == (1, 30)
+    assert jc["dispatches"] == 2 + finish
     text = "\n".join(row[0] for row in r.execute(
         "explain analyze " + tpch_reference.statement(name)).rows)
-    assert "prereduce held: 30 batches kept on the device, 1 flushes" \
-        in text
+    assert ("prereduce held: 0 batches kept on the device, 0 flushes; "
+            "run launches: 1 over 30 batches") in text
+    assert r._last_task.jit_counters()["dispatches"] == 1 + finish
 
 
-def test_held_partials_of_two_key_bindings_do_not_mix():
-    """Partials merge only where their key codes mean the same: when a
-    batch arrives under a grown dictionary (another domain, another
-    program), what is held is merged out first and the answer stays the
-    unfused chain's."""
+@pytest.mark.parametrize("name, max_bytes, engages", [
+    ("q1", None, True), ("q6", None, True),
+    # partials over the limit: a launch a flush of the miss path
+    ("q1", 2000, True), ("q6", 100, True),
+    # an absorbed join probe; a table with no immutable_data (no hit)
+    ("q3", None, False), ("q1.memory", None, False)])
+def test_a_warm_scan_pre_reduces_its_kept_run_in_run_launches(
+        want, name, max_bytes, engages):
+    """The second execution of a statement whose leaf segment pre-reduces
+    a cached scan into a bounded partial covers each task's kept run (30
+    batches of 2,048 rows, padded to 32) in run launches, as many as the
+    first execution's flushes of held partials, and answers what the
+    first execution answered, bit for bit, and the reference.  A segment
+    with an absorbed probe, and a scan of a table that can change, go
+    batch by batch as ever."""
+    cfg = _cfg(scan_batch_rows=2048, task_concurrency=1)
+    if max_bytes:
+        cfg = dc.replace(cfg, partial_agg_max_bytes=max_bytes)
+    r = LocalQueryRunner.tpch(scale=0.01, config=cfg)
+    statement, _, table = name.partition(".")
+    sql = tpch_reference.statement(statement)
+    if table:
+        r.execute("create table memory.lineitem as select * from lineitem")
+        sql = sql.replace("from lineitem", "from memory.lineitem")
+    first = r.execute(sql).rows
+    miss = r._last_task.task_stats()
+    second = r.execute(sql).rows
+    hit = r._last_task.task_stats()
+    tpch_reference.compare(tpch_reference.as_served(second), want[statement],
+                           tpch_reference.RTOL)
+    assert second == first
+    assert hit.scan_cache_hits == (0 if table else 3 if statement == "q3"
+                                   else 1)
+    if not engages:
+        assert (hit.run_launches, hit.run_batches) == (0, 0)
+        assert hit.jit_dispatches == miss.jit_dispatches
+        return
+    assert miss.prereduce_batches_held == 30 and miss.run_launches == 0
+    assert 1 <= miss.prereduce_flushes == hit.run_launches
+    assert (max_bytes is None) == (hit.run_launches == 1)
+    assert (hit.run_batches, hit.prereduce_batches_held) == (30, 0)
+
+
+def _two_bindings_chain():
+    """values (three batches under one dictionary, two under a grown
+    copy of it) -> project -> GROUP BY the dictionary key."""
     from presto_tpu.batch import Batch, Column
     from presto_tpu.exec.aggregation import (
         AggChannel, HashAggregationOperatorFactory,
@@ -449,12 +497,86 @@ def test_held_partials_of_two_key_bindings_do_not_mix():
     agg = HashAggregationOperatorFactory(
         [0], [AggChannel("sum", 1, T.BIGINT),
               AggChannel("count", None, T.BIGINT)], types)
-    factories = [ValuesOperatorFactory(batches), fp, agg]
+    return [ValuesOperatorFactory(batches), fp, agg], _cfg()
+
+
+def test_held_partials_of_two_key_bindings_do_not_mix():
+    """Partials merge only where their key codes mean the same: when a
+    batch arrives under a grown dictionary (another domain, another
+    program), what is held is merged out first and the answer stays the
+    unfused chain's."""
+    factories, _cfg_ = _two_bindings_chain()
     want, _ = _run_held(factories, _cfg(), fuse=False)
     got, seg = _run_held(factories, _cfg(), fuse=True)
     assert sorted(got) == sorted(want) and len(got) == 5
     assert (seg.prereduce_batches_held, seg.prereduce_flushes,
             seg.output_batches) == (5, 2, 2)
+
+
+def _run_kept(factories, cfg):
+    """``factories``' batches as a kept run of the scan cache, handed to
+    the fused segment by a hit as ``exec/runner.py`` hands it: the
+    rows, the segment's stats and the hit's stacking launches."""
+    from presto_tpu.exec.operators import CachedScanOperatorFactory
+    from presto_tpu.exec.scancache import ScanCache
+
+    values, segment, *rest = fuse_chain(list(factories), cfg)
+    batches = values.batches
+    names = [f"c{i}" for i in range(batches[0].num_columns)]
+
+    class Connector:
+        immutable_data = True
+
+    cache, conn = ScanCache(1 << 30), Connector()
+    fill = cache.open(conn, "k", names)
+    fill.scan_opened()
+    for b in batches:
+        fill.add(b)
+    fill.scan_closed(sum(b.num_rows for b in batches), True)
+    fill.close(True)
+    hit = cache.open(conn, "k", names)
+    collector = OutputCollectorFactory()
+    chain = ([CachedScanOperatorFactory(hit),
+              segment.for_cached_scan(hit=hit)] + rest + [collector])
+    task = execute_pipelines([Pipeline(chain, name="t")], cfg)
+    seg = [s for s in task.operator_stats if "FusedSegment" in s.operator]
+    return collector.rows(), seg[0]
+
+
+@pytest.mark.parametrize("case, flush_bytes, engages", [
+    ("grouped-direct", 0, True), ("grouped-direct", 1000, True),
+    ("global", 0, True), ("global", 150, True),
+    ("sort-path", 0, False), ("raw-emit", 0, False),
+    ("two-bindings", 0, False)])
+def test_a_hit_covers_its_kept_run_where_the_partial_is_bounded(
+        case, flush_bytes, engages):
+    """The same batches fed to the segment one by one (the held path) and
+    as a scan-cache hit: where the pre-reduce is bounded and the run is
+    under one dictionary binding, the hit covers the run in run launches,
+    one a flush of the held path (``partial_agg_max_bytes``), holds
+    nothing and hands over the held path's rows bit for bit; the sort
+    path, raw emission and two bindings go batch by batch as ever."""
+    factories, cfg = (_two_bindings_chain() if case == "two-bindings"
+                      else _held_chain(case))
+    if flush_bytes:
+        cfg = dc.replace(cfg, partial_agg_max_bytes=flush_bytes)
+    held_rows, held = _run_held(factories, cfg, fuse=True)
+    kept_rows, seg = _run_kept(factories, cfg)
+    assert sorted(kept_rows, key=repr) == sorted(held_rows, key=repr)
+    n = len(factories[0].batches)
+    assert (seg.input_batches, seg.prereduce_rows) \
+        == (n, held.prereduce_rows)
+    if not engages:
+        assert (seg.run_launches, seg.run_batches) == (0, 0)
+        assert seg.jit_dispatches == held.jit_dispatches
+        assert seg.prereduce_batches_held == held.prereduce_batches_held
+        return
+    assert (seg.run_batches, seg.prereduce_batches_held) == (n, 0)
+    assert seg.run_launches == held.prereduce_flushes
+    assert (seg.run_launches > 1) == bool(flush_bytes)
+    # the run launches and the one that stacked the run's columns
+    assert seg.jit_dispatches == seg.run_launches + 1
+    assert seg.output_batches == held.output_batches
 
 
 def test_prereduce_global_empty_scan(runner_on):

@@ -8,7 +8,9 @@ immutable table stays on the device after its first scan.
 - a table that can change is never kept; a budget too small keeps
   nothing; eviction frees bytes; two scans of one absent run keep one
   copy; a LIMIT that stops a scan early keeps nothing; a runner's exit
-  leaves no entry.
+  leaves no entry;
+- a run's stacked view (what a run launch of exec/fusion.py reads) is
+  built once a column, counts in the budget and goes with its run.
 """
 
 import dataclasses as dc
@@ -455,3 +457,62 @@ def test_drop_owner_frees_only_that_connectors_runs():
 
 def test_the_budget_is_a_fixed_figure_where_the_backend_reports_none():
     assert ScanCache().budget() == scancache.FALLBACK_BUDGET_BYTES
+
+
+# -- the stacked view of a kept run ------------------------------------------
+
+RUN_ROWS = (8, 4, 8)            # a short batch in the middle
+RUN_BYTES = sum(RUN_ROWS) * 8   # a BIGINT column of the run
+STACK_BYTES = 8 * 8 * 8         # a column stacked: 3 batches padded to 8
+NUM_ROWS_BYTES = 8 * 4          # int32 a padded batch
+
+
+def _stacked(cache, conn, key, names):
+    hit = cache.open(conn, key, names)
+    assert isinstance(hit, ScanHit)
+    return hit.stacked()
+
+
+def test_a_runs_stacked_view_is_built_once_a_column():
+    cache, conn = ScanCache(1 << 20), _Connector()
+    _fill(cache, conn, "k", ["a", "b"], rows=RUN_ROWS)
+    cols, num_rows, launches = _stacked(cache, conn, "k", ["b"])
+    ((stacked, valid),) = cols
+    assert launches == 1 and valid is None
+    assert stacked.shape == (8, 8)
+    np.testing.assert_array_equal(np.asarray(num_rows),
+                                  [8, 4, 8, 0, 0, 0, 0, 0])
+    np.testing.assert_array_equal(np.asarray(stacked[1]),
+                                  [0, 1, 2, 3, 0, 0, 0, 0])
+    np.testing.assert_array_equal(np.asarray(stacked[2]), np.arange(8))
+    assert not np.asarray(stacked[3:]).any()
+    resident = 2 * RUN_BYTES + STACK_BYTES + NUM_ROWS_BYTES
+    assert cache.stats()["resident_bytes"] == resident
+    # asked again, alone or beside another column: only what is missing
+    # is stacked, and a column's stack is the one kept
+    assert _stacked(cache, conn, "k", ["b"])[2] == 0
+    both, again, launches = _stacked(cache, conn, "k", ["a", "b"])
+    assert launches == 1 and both[1][0] is stacked and again is num_rows
+    assert cache.stats()["resident_bytes"] == resident + STACK_BYTES
+
+
+@pytest.mark.parametrize("budget, stacked, entries", [
+    (1 << 20, True, 2),                     # room for both runs and it
+    (RUN_BYTES + STACK_BYTES + NUM_ROWS_BYTES, True, 1),   # evicts "old"
+    (RUN_BYTES + 100, False, 1)])           # no room: none, no eviction
+def test_a_stacked_view_counts_in_the_budget_and_goes_with_its_run(
+        budget, stacked, entries):
+    cache, conn = ScanCache(budget), _Connector()
+    for key in ("old", "k"):
+        _fill(cache, conn, key, ["a"], rows=RUN_ROWS)
+    evictions = cache.stats()["evictions"]
+    view = _stacked(cache, conn, "k", ["a"])
+    assert (view is not None) == stacked
+    stats = cache.stats()
+    assert stats["entries"] == entries
+    assert stats["evictions"] - evictions == (stacked and entries == 1)
+    assert stats["resident_bytes"] == (
+        RUN_BYTES * entries + stacked * (STACK_BYTES + NUM_ROWS_BYTES))
+    assert isinstance(cache.open(conn, "k", ["a"]), ScanHit)
+    cache.drop_owner(conn)
+    assert cache.stats()["resident_bytes"] == 0
