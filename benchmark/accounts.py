@@ -33,5 +33,11 @@ def phase_seconds(tree: dict, names) -> float | None:
     return sum(found) if found else None
 
 
+def served_by(run: dict) -> str:
+    """The plane that serves the cell: 'http' (the exchange through the
+    host) or 'device' (the collective plane)."""
+    return run["cell"]["config"]["served_by"]
+
+
 def query_stat(name: str):
     return lambda detail: (detail.get("queryStats") or {}).get(name)

@@ -4,10 +4,11 @@ span tree (``run['spans']``; recorded by ``presto_tpu/spans.py``, kinds in
 ``spans.ACTIVITY_KINDS``).  A query of the collective plane has no task
 threads: its query thread records for the length of the ``execute`` phase
 (``lock_wait``, ``dispatch``, ``device_wait``), and the intervals hang under
-the root fragment's task span, so they are read here like any task's (the
-``mesh.*`` readers of ``mesh4w.repeat-q1``).  A tree from a program that
-records none (a commit before the recorder; a mesh query before PR 31) has
-no such child, and every function here then finds nothing to read."""
+the root fragment's task span, so they are read here like any task's, by
+the same readers as on the HTTP plane.  A kind that a query with activity
+never entered reads 0; a tree from a program that records none (a commit
+before the recorder, on either plane) has no such child, and every function
+here then finds nothing to read."""
 
 from __future__ import annotations
 

@@ -8,9 +8,12 @@ LAYER = "collective plane"
 UNIT = "s"
 SOURCE = "program_span"
 MOVES = "query_geomean_s"
+PLANE = "device"
 
 
 def read(run: dict):
+    if accounts.served_by(run) != PLANE:
+        return None
     return accounts.median_per_query(
         run, "spans",
         lambda tree: accounts.phase_seconds(tree, ("execute",)))

@@ -3,14 +3,17 @@
 time, so with several clients this is the queue behind the others' locked
 sections), median over the window's queries."""
 
-from benchmark import activity
+from benchmark import accounts, activity
 
 LAYER = "collective plane"
 UNIT = "s"
 SOURCE = "program_span"
 MOVES = "query_geomean_s"
 KINDS = ("lock_wait",)
+PLANE = "device"
 
 
 def read(run: dict):
+    if accounts.served_by(run) != PLANE:
+        return None
     return activity.median_kind_seconds(run, KINDS)

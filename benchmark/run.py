@@ -74,15 +74,38 @@ class References(threading.Thread):
             self.error = e
 
 
+UNTIL = "until"    # a slice that ends when the window says so
+
+
+def slice_plan(warm_walls, seconds: float) -> tuple:
+    """(delay_s, length_s or ``UNTIL``, opens_before_window) of the traced
+    slice, from the statements' warm walls alone.
+
+    Short cycle (three cycles and half a second fit in 0.8 of the window):
+    the profiler starts ``delay_s`` after the window opens and runs
+    ``length_s``, enough for three cycles, so every statement lies whole in
+    it at least once.  Long cycle: such a slice could hold no whole
+    statement, so the profiler starts before the window's first statement
+    is sent and runs until the window's last statement has ended
+    (``run_window``)."""
+    cycle = sum(warm_walls)
+    if 3.0 * cycle + 0.5 <= 0.8 * seconds:
+        return (min(1.0, 0.1 * seconds),
+                min(max(3.0, 3.0 * cycle + 0.5), 0.8 * seconds), False)
+    return 0.0, UNTIL, True
+
+
 class SliceTracer(threading.Thread):
     """Profiles a steady slice of the window and marks the host's clock in
     the trace, so that device events can be set beside the coordinator's
-    spans."""
+    spans.  ``started`` is set once the profiler runs (or has failed to
+    start); a slice of length ``UNTIL`` ends when ``stop`` is set."""
 
-    def __init__(self, trace_dir: str, delay_s: float, length_s: float):
+    def __init__(self, trace_dir: str, delay_s: float, length_s):
         super().__init__(daemon=True)
         self.dir, self.delay_s, self.length_s = trace_dir, delay_s, length_s
-        self.start_epoch = self.end_epoch = None
+        self.started, self.stop = threading.Event(), threading.Event()
+        self.start_epoch = self.end_epoch = self.stop_s = None
         self.error: BaseException | None = None
 
     def run(self) -> None:
@@ -101,17 +124,43 @@ class SliceTracer(threading.Thread):
             jax.profiler.start_trace(self.dir, profiler_options=options)
             try:
                 self.start_epoch = time.time()
-                stop_at = time.perf_counter() + self.length_s
-                while time.perf_counter() < stop_at:
+                self.started.set()
+                if self.length_s == UNTIL:
                     mark()
-                    time.sleep(min(0.5, max(stop_at - time.perf_counter(),
-                                            0.0)))
+                    while not self.stop.wait(0.5):
+                        mark()
+                else:
+                    stop_at = time.perf_counter() + self.length_s
+                    while time.perf_counter() < stop_at:
+                        mark()
+                        time.sleep(min(0.5, max(stop_at - time.perf_counter(),
+                                                0.0)))
                 mark()
                 self.end_epoch = time.time()
             finally:
+                t0 = time.perf_counter()
                 jax.profiler.stop_trace()
+                self.stop_s = time.perf_counter() - t0
         except BaseException as e:
             self.error = e
+        finally:
+            self.started.set()   # a window that waits for it opens anyway
+
+
+def run_window(runner, cell: dict, seed: int, seconds: float,
+               tracer: SliceTracer | None, until: bool) -> tuple:
+    """``load.closed_loop`` beside the tracer.  A slice of length ``UNTIL``
+    starts before the window's first statement is sent and stops once the
+    last has ended, so every statement of the window lies whole in it."""
+    if until:
+        tracer.started.wait()
+    try:
+        return load.closed_loop(runner.new_client, cell["statements"],
+                                cell["traffic"], seed, seconds)
+    finally:
+        if tracer is not None:
+            tracer.stop.set()
+            tracer.join()
 
 
 def warm_up(cell: dict, runner, xla: observe.XlaCompiles) -> dict:
@@ -189,6 +238,7 @@ def reduce_trace(tracer: SliceTracer, samples: list, spans: dict,
     if keep_trace:
         os.makedirs(keep_trace, exist_ok=True)
         shutil.copy(file, keep_trace)
+    t0 = time.perf_counter()
     trace = trace_reduce.load(file)
     trees: dict = {}
     for op in samples:
@@ -198,9 +248,17 @@ def reduce_trace(tracer: SliceTracer, samples: list, spans: dict,
     flat = trace_reduce.flatten_spans(trees)
     reduced = trace_reduce.reduce(trace, tracer.start_epoch,
                                   tracer.end_epoch, flat)
+    lo, hi = tracer.start_epoch, tracer.end_epoch
+    whole: dict = {}
+    for op in samples:
+        if op["start"] >= lo and op["end"] <= hi:
+            whole[op["statement"]] = whole.get(op["statement"], 0) + 1
     emit({"phase": "trace", "file_bytes": os.path.getsize(file),
+          "stop_s": tracer.stop_s, "reduce_s": time.perf_counter() - t0,
           "planes": [p["name"] for p in trace["planes"]],
-          "reduced": reduced is not None})
+          "slice": {"delay_s": tracer.delay_s, "length_s": tracer.length_s,
+                    "seconds": hi - lo},
+          "whole_samples": whole, "reduced": reduced is not None})
     if keep_trace:
         with open(os.path.join(keep_trace, "reduce_input.json"), "w") as f:
             json.dump({"start": tracer.start_epoch, "end": tracer.end_epoch,
@@ -248,19 +306,15 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
                   "rows": refs.rows,
                   "statement_must_read_bytes": refs.statement_bytes})
             setup_events, setup_hits = xla.since(0), xla.cache_hits
-            tracer = None
+            tracer, until = None, False
             if trace:
-                cycle = sum(w["warm_wall_s"] for w in warm.values())
-                tracer = SliceTracer(
-                    trace_dir, min(1.0, 0.1 * seconds),
-                    min(max(3.0, 3.0 * cycle + 0.5), 0.8 * seconds))
+                delay_s, length_s, until = slice_plan(
+                    [w["warm_wall_s"] for w in warm.values()], seconds)
+                tracer = SliceTracer(trace_dir, delay_s, length_s)
                 tracer.start()
             setup_s = time.time() - process_start
-            window_start, samples = load.closed_loop(
-                runner.new_client, cell["statements"], cell["traffic"],
-                seed, seconds)
-            if tracer is not None:
-                tracer.join()
+            window_start, samples = run_window(runner, cell, seed, seconds,
+                                               tracer, until)
             window_events = xla.since(len(setup_events))
             warm_ops = [op for w in warm.values() for op in w["ops"]]
             details, spans = fetch_accounts(uri, warm_ops + samples, trace)

@@ -23,12 +23,16 @@ def reader():
 @pytest.fixture(scope="module")
 def recorded():
     with open(os.path.join(HERE, "fixtures", "kernel_lookup_run.json")) as f:
-        return json.load(f)
+        return dict(json.load(f), cell=HTTP)
 
 
-def run_of(trees=()):
+HTTP = {"config": {"served_by": "http"}}
+MESH = {"config": {"served_by": "device"}}
+
+
+def run_of(trees=(), cell=HTTP):
     return {"samples": [{"query_id": f"q{i}"} for i in range(len(trees))],
-            "details": {},
+            "details": {}, "cell": cell,
             "spans": {f"q{i}": t for i, t in enumerate(trees)}}
 
 
@@ -38,7 +42,8 @@ def test_reader_repeats_its_manifest_entry():
     module = reader()
     assert (module.LAYER, module.UNIT, module.SOURCE, module.MOVES) == (
         entry["layer"], entry["unit"], entry["source"], entry["moves"])
-    assert entry["workloads"] == ["http2w.scan-agg", "http2w.join"]
+    for name in entry["workloads"]:
+        assert manifest.cell(name)["config"]["served_by"] == module.PLANE
 
 
 def lookup(start, end):
@@ -82,6 +87,16 @@ def test_kernel_lookup_s_is_none_for_a_program_before_the_kind():
 
 def test_reader_on_the_recorded_run(recorded):
     assert reader().read(recorded) > 0
+
+
+def test_kept_off_the_collective_plane():
+    """A mesh query makes its one kernel-cache lookup in no bracket, yet its
+    root task's totals name every kind (the query thread's recorder): the
+    reader reads nothing there, where the tree alone would read a false 0."""
+    mesh = tree([[dict(lookup(1.0, 2.0), name="dispatch")]],
+                kinds=("generate", "kernel_lookup", "dispatch", "lock_wait"))
+    assert reader().read(run_of(trees=[mesh], cell=MESH)) is None
+    assert reader().read(run_of(trees=[mesh])) == 0.0
 
 
 def test_the_recorded_run_as_a_parent_would_give_it(recorded):

@@ -10,7 +10,18 @@ import pytest
 from benchmark import manifest
 
 BENCH = manifest.benchmark_json()
+# the manifest as a later PR grows it: a cell on each plane, one entry each
+# and no edit to another
+NEW_CELLS = {"new.http": ("tpch-sf1-http2w", "repeat-q1", 1),
+             "new.device": ("tpch-sf10-mesh4w", "join", 4)}
+GROWN = dict(BENCH, workloads=BENCH["workloads"] + [
+    {"name": name, "config": config, "traffic": traffic, "chips": chips,
+     "why": "a cell added later"}
+    for name, (config, traffic, chips) in NEW_CELLS.items()])
+BENCHES, BENCH_IDS = [BENCH, GROWN], ["accepted", "grown"]
 CELLS = [w["name"] for w in BENCH["workloads"]]
+CELL_CASES = [pytest.param(b, w["name"], id=f"{i}-{w['name']}")
+              for b, i in zip(BENCHES, BENCH_IDS) for w in b["workloads"]]
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
@@ -22,9 +33,9 @@ def test_manifest_keys_are_the_contract():
     assert len(json.dumps(BENCH)) < 64 * 1024
 
 
-@pytest.mark.parametrize("name", CELLS)
-def test_cell_resolves_by_name(name):
-    cell = manifest.cell(name, BENCH)
+@pytest.mark.parametrize("bench, name", CELL_CASES)
+def test_cell_resolves_by_name(bench, name):
+    cell = manifest.cell(name, bench)
     assert cell["statements"] and len(cell["why"]) <= 200
     assert cell["chips"] in (1, 4)
     for s, ref in cell["references"].items():
@@ -55,10 +66,11 @@ def test_every_config_has_a_cell_and_its_own_file():
         assert len(c["why"]) <= 200
 
 
-def test_four_chip_cells_are_at_most_half_or_one():
-    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
-    assert len(four) <= max(1, len(BENCH["workloads"]) // 2)
-    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+@pytest.mark.parametrize("bench", BENCHES, ids=BENCH_IDS)
+def test_four_chip_cells_are_at_most_half_or_one(bench):
+    four = [w for w in bench["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(bench["workloads"]) // 2)
+    pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]
     assert len(set(pairs)) == len(pairs)
 
 
@@ -92,10 +104,37 @@ def test_metrics_have_readers_and_legal_names(group, folder):
                 assert m["unit"] == "%"
 
 
-def test_names_of_cells_configs_and_files():
-    for entry in BENCH["workloads"] + BENCH["configs"]:
+def plane_of(metric: dict) -> str | None:
+    """The plane a per-layer metric's reader reads on alone (its ``PLANE``),
+    or None where it reads on every plane."""
+    return getattr(manifest.load_module("layer_metrics", metric["name"]),
+                   "PLANE", None)
+
+
+@pytest.mark.parametrize("bench", BENCHES, ids=BENCH_IDS)
+def test_only_one_plane_metrics_list_their_cells(bench):
+    for m in bench["per_layer"]:
+        plane = plane_of(m)
+        assert ("workloads" in m) == (plane is not None), m["name"]
+        for name in m.get("workloads", []):
+            assert manifest.cell(name, bench)["config"]["served_by"] == \
+                plane, m["name"]
+
+
+@pytest.mark.parametrize("name", NEW_CELLS)
+def test_a_new_cell_gets_every_metric_but_the_one_plane_ones(name):
+    """A cell that a later PR adds, on either plane, as one manifest entry
+    and no edit to another."""
+    got = {m["name"] for m in manifest.cell(name, GROWN)["per_layer"]}
+    assert got == {m["name"] for m in BENCH["per_layer"]
+                   if plane_of(m) is None}
+
+
+@pytest.mark.parametrize("bench", BENCHES, ids=BENCH_IDS)
+def test_names_of_cells_configs_and_files(bench):
+    for entry in bench["workloads"] + bench["configs"]:
         assert manifest.NAME.match(entry["name"]), entry["name"]
-    for w in BENCH["workloads"]:
+    for w in bench["workloads"]:
         assert manifest.NAME.match(w["traffic"])
     for folder, _dirs, files in os.walk(manifest.HERE):
         if "__pycache__" in folder:

@@ -1,18 +1,30 @@
-"""The readers of the collective plane's spans (``mesh.execute_s`` and the
-three over the host activity that the query thread records inside the
-``execute`` phase), on hand-built span trees of a mesh query: phases, one
-synthetic stage a fragment, and the ``activity`` children on the root
-fragment's task."""
+"""The readers of the collective plane's spans (``mesh.execute_s``,
+``mesh.lock_wait_s``, and the readers every cell has of the host activity
+that the query thread records inside the ``execute`` phase), on hand-built
+span trees of a mesh query: phases, one synthetic stage a fragment, and the
+``activity`` children on the root fragment's task."""
 
 import pytest
-from test_activity_metrics import act, run_of
+from test_activity_metrics import act
+from test_activity_metrics import run_of as any_run_of
 
 from benchmark import manifest
 
 CELL = "mesh4w.repeat-q1"
 KIND_METRICS = {"mesh.lock_wait_s": "lock_wait",
-                "mesh.dispatch_s": "dispatch",
-                "mesh.device_wait_s": "device_wait"}
+                "ops.dispatch_s": "dispatch",
+                "ops.device_wait_s": "device_wait"}
+# kinds the plane records only in a first build, or never
+ZERO_IN_A_REPEAT = ("scan.generate_s", "scan.stage_h2d_s", "exchange.serde_s",
+                    "exchange.wait_s")
+
+
+# the readers of a lock and a phase that the HTTP plane lacks
+PLANE_METRICS = ("mesh.execute_s", "mesh.lock_wait_s")
+
+
+def run_of(trees, served_by="device"):
+    return any_run_of(trees, cell={"config": {"served_by": served_by}})
 
 
 def reader(name):
@@ -50,19 +62,27 @@ def mesh_tree(activities, execute=(10.0, 11.0), truncated=False):
 REPEAT = [act("lock_wait", 10.0, 10.6), act("dispatch", 10.6, 10.62),
           act("device_wait", 10.62, 10.8), act("dispatch", 10.8, 10.81),
           act("device_wait", 10.81, 10.85)]
-WANT = {"mesh.lock_wait_s": 0.6, "mesh.dispatch_s": 0.03,
-        "mesh.device_wait_s": 0.22}
+WANT = {"mesh.lock_wait_s": 0.6, "ops.dispatch_s": 0.03,
+        "ops.device_wait_s": 0.22}
 
 
-@pytest.mark.parametrize("name", ["mesh.execute_s"] + sorted(KIND_METRICS))
+@pytest.mark.parametrize("name", PLANE_METRICS)
 def test_reader_repeats_its_manifest_entry(name):
     entry = [m for m in manifest.benchmark_json()["per_layer"]
              if m["name"] == name][0]
     module = reader(name)
     assert (module.LAYER, module.UNIT, module.SOURCE, module.MOVES) == (
         entry["layer"], entry["unit"], entry["source"], entry["moves"])
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]
+    for name in entry["workloads"]:
+        assert manifest.cell(name)["config"]["served_by"] == module.PLANE
     assert module.LAYER == "collective plane"
+
+
+@pytest.mark.parametrize("name", PLANE_METRICS)
+def test_none_off_the_collective_plane(name):
+    """A tree of the same shape served through the host reads nothing."""
+    assert reader(name).read(run_of([mesh_tree(REPEAT)], "http")) is None
 
 
 @pytest.mark.parametrize("name", sorted(KIND_METRICS))
@@ -92,6 +112,14 @@ def test_a_kind_the_query_never_entered_reads_zero(name):
     activity: that reads 0, not nothing."""
     t = mesh_tree([act("generate", 10.0, 10.5)])
     assert reader(name).read(run_of([t])) == 0.0
+
+
+@pytest.mark.parametrize("name", ZERO_IN_A_REPEAT)
+def test_a_repeat_reads_zero_for_what_only_a_first_build_does(name):
+    """A repeat generates and stages nothing (the inputs stay sharded on
+    the devices) and has no exchange to serialise or wait on."""
+    assert reader(name).read(run_of([mesh_tree(REPEAT)])) == 0.0
+    assert reader(name).read(run_of([mesh_tree([])])) is None
 
 
 @pytest.mark.parametrize("name", sorted(KIND_METRICS))

@@ -14,35 +14,13 @@ from benchmark import manifest, run
 FAKE_PEAKS = {"hbm_bytes_per_s": 1e9}   # no device metric is read here
 SEED = 2 ** 31 + 12345                  # the driver's seeds are large
 
-# The four-chip cell, as the manifest entries that add it (all its files are
-# on disk; see PERF.md, Open questions, for why it may not be in
-# BENCHMARK.json yet).  Adding a cell is this much and no code.
 MESH_CELL = "mesh4w.repeat-q1"
-MESH_ENTRIES = {
-    "configs": [{"name": "tpch-sf1-mesh4w", "reduced": ["scale"],
-                 "file": "benchmark/configs/tpch-sf1-mesh4w.json"}],
-    "workloads": [{"name": MESH_CELL, "config": "tpch-sf1-mesh4w",
-                   "traffic": "repeat-q1", "chips": 4, "why": "see PERF.md"}],
-    "end_to_end": [
-        {"name": "query_p95_s", "unit": "s", "workloads": [MESH_CELL]},
-        {"name": "qph", "unit": "queries/h", "workloads": [MESH_CELL]}],
-    "per_layer": [{"name": "mesh.execute_s", "unit": "s",
-                   "workloads": [MESH_CELL]}],
-}
-
-
-def bench_with_mesh_cell():
-    bench = manifest.benchmark_json()
-    if MESH_CELL not in [w["name"] for w in bench["workloads"]]:
-        for group, entries in MESH_ENTRIES.items():
-            bench[group] = bench[group] + entries
-    return bench
 
 
 def rehearse(name, trace, seconds=2.0):
     import jax
 
-    cell = manifest.cell(name, bench_with_mesh_cell())
+    cell = manifest.cell(name)
     return cell, run.run_cell(cell, SEED, seconds, trace,
                               jax.devices()[:cell["chips"]], FAKE_PEAKS,
                               scale=0.01)
@@ -85,8 +63,17 @@ def test_join_cell_end_to_end(capsys):
                                      "memory_peak_bytes"}
 
 
-def test_join_cell_traced_reads_spans_and_counters():
-    _cell, result = rehearse("http2w.join", trace=True)
+def test_join_cell_traced_reads_spans_and_counters(capsys):
+    """A window of 1 s, too short for three q3s (0.4 s on the CPU), so the
+    slice takes the long branch and holds every q3 of the window."""
+    _cell, result = rehearse("http2w.join", trace=True, seconds=1.0)
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()
+             if x.startswith("{")]
+    traced = [x for x in lines if x.get("phase") == "trace"][0]
+    window = [x for x in lines if x.get("phase") == "window"][0]
+    assert traced["slice"]["length_s"] == run.UNTIL
+    assert traced["whole_samples"] == window["samples"]
+    assert traced["stop_s"] >= 0.0 and traced["reduce_s"] >= 0.0
     got = result["metrics"]
     for name in ("coord.plan_s", "worker.leaf_stage_s", "ops.jit_dispatches",
                  "exchange.wire_bytes", "xla.compiles.window",
@@ -154,12 +141,24 @@ def test_an_answer_altered_where_it_is_produced_fails_the_run(monkeypatch):
 
 def test_mesh_cell_on_four_virtual_devices():
     import jax
+    from test_mesh_metrics import ZERO_IN_A_REPEAT
 
     if len(jax.devices()) < 4:
         pytest.skip("needs four (virtual) devices")
     cell, result = rehearse(MESH_CELL, trace=True)
     assert result["correct"] and result["attempted"] >= 4
     assert result["device"]["count"] == 4
-    assert result["metrics"]["mesh.execute_s"]["value"] > 0
+    got = result["metrics"]
+    assert got["mesh.execute_s"]["value"] > 0
+    # the query thread's brackets, read by the readers every cell has
+    assert got["ops.dispatch_s"]["value"] > 0
+    assert got["ops.device_wait_s"]["value"] > 0
+    assert got["xla.trace_lower_s.setup"]["value"] > 0
+    # a repeat generates, stages, serialises and waits on no exchange
+    for name in ZERO_IN_A_REPEAT:
+        assert got[name]["value"] == 0.0, name
+    assert "mesh.dispatch_s" not in got and "mesh.device_wait_s" not in got
+    # one kernel-cache lookup a query, in no bracket: not read here
+    assert "ops.kernel_lookup_s" not in got
     _cell, e2e = rehearse(MESH_CELL, trace=False)
     assert set(e2e["metrics"]) == {m["name"] for m in cell["end_to_end"]}
